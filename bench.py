@@ -1,6 +1,8 @@
 """North-star benchmark: ResNet-50 synthetic-ImageNet training throughput.
 
-Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline"}.
+Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline", "device"}.
+Runs on a TPU only: a rate measured on any other backend would be printed
+under a per-chip name, so the script refuses before building the model.
 
 Metric (BASELINE.json): ResNet-50 ImageNet images/sec/chip. The reference's
 own MKL-DNN CPU number could not be read this round (empty mount,
@@ -13,6 +15,7 @@ lands in BASELINE.json.
 from __future__ import annotations
 
 import json
+import sys
 import time
 
 import numpy as np
@@ -24,12 +27,21 @@ def main() -> None:
     import jax
     import jax.numpy as jnp
 
+    devices = jax.devices()
+    device = {"platform": devices[0].platform,
+              "kind": devices[0].device_kind, "count": len(devices)}
+    if jax.default_backend() != "tpu":
+        sys.exit(f"bench.py measures images/sec/chip and needs a TPU; jax "
+                 f"found {json.dumps(device)}")
+
     from bigdl_tpu.models.resnet import ResNet
     from bigdl_tpu.nn.criterion import CrossEntropyCriterion
     from bigdl_tpu.optim.optim_method import SGD
     from bigdl_tpu.optim.train_step import make_train_step
+    from bigdl_tpu.utils.compile_cache import enable_compile_cache
     from bigdl_tpu.utils.random_gen import RNG
 
+    enable_compile_cache()
     RNG.set_seed(7)
     # bf16 mixed precision (fp32 master weights/loss) at batch 256 — the
     # measured sweet spot on v5e: ~2.1x the fp32 step rate, loss parity
@@ -66,27 +78,19 @@ def main() -> None:
     y = jax.device_put(np.random.default_rng(1)
                        .integers(1, 1001, size=(batch,)).astype(np.int32))  # 1-based labels
 
-    # compile + warmup; the trailing float() matters — on this PJRT
-    # transport block_until_ready can resolve before device work drains
-    params, opt_state, model_state, loss = step(
-        params, opt_state, model_state, rng, x, y)
-    float(loss)
-    for _ in range(2):
+    # compile + warmup
+    for _ in range(3):
         params, opt_state, model_state, loss = step(
             params, opt_state, model_state, rng, x, y)
-    float(loss)
+    jax.block_until_ready(loss)
 
-    # 40 iterations amortize the transport's ~135 ms fixed host-readback
-    # cost (measured, benchmarks/PERF_ANALYSIS_r2.md); at 10 iterations the
-    # readback alone depressed the round-1 number by ~9%
     iters = 40
     t0 = time.perf_counter()
     for _ in range(iters):
         params, opt_state, model_state, loss = step(
             params, opt_state, model_state, rng, x, y)
-    # host readback: on some PJRT transports block_until_ready alone
-    # resolves before the device work drains; float() cannot
-    float(loss)
+    # the one sync: the last loss depends on every step before it
+    jax.block_until_ready(loss)
     dt = time.perf_counter() - t0
 
     img_per_sec = batch * iters / dt
@@ -95,6 +99,7 @@ def main() -> None:
         "value": round(img_per_sec, 2),
         "unit": "images/sec/chip",
         "vs_baseline": round(img_per_sec / REFERENCE_IMG_PER_SEC_PER_NODE, 3),
+        "device": device,
     }))
 
 

@@ -21,7 +21,7 @@ rig the multihost tests use):
    everyone still waits for the slow rank's weight partition
    (``docs/parallelism.md``; true of the reference too).
 
-Run:  PYTHONPATH=/root/repo python benchmarks/blockstore_bench.py
+Run:  python -m benchmarks.blockstore_bench
 Emits one JSON line per scenario; the summary table lives in
 ``docs/parallelism.md``.
 """
@@ -54,11 +54,12 @@ def _model(n_hidden: int = 768, n_layers: int = 3):
 
 def worker(pid: int, port: int, n: int, mode: str, put_delay: float,
            compute_delay: float, drop: float, out_dir: str) -> None:
+    # every rank is pinned to the CPU, explicitly, before jax starts. A
+    # chip belongs to one process: these n ranks are started side by
+    # side, so this launcher is only safe while that pin stays here.
     os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
     import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
     from bigdl_tpu.dataset.dataset import DataSet
     from bigdl_tpu.dataset.sample import Sample
@@ -157,7 +158,8 @@ def run_scenario(tag: str, n: int, mode: str, put_delay: float = 0.0,
     port = _free_port()
     env = {k: v for k, v in os.environ.items()
            if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
-    env["PYTHONPATH"] = "/root/repo"
+    env["PYTHONPATH"] = os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))
     procs = [subprocess.Popen(
         [sys.executable, os.path.abspath(__file__), "--worker",
          str(pid), str(port), str(n), mode, str(put_delay),

@@ -117,6 +117,10 @@ def main():
     ap.add_argument("--batch", type=int, default=256)
     args = ap.parse_args()
 
+    from bigdl_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     build = lambda: ResNet(class_num=1000,
                            opt={"depth": 50, "shortcutType": "B"})
     bf16 = bench_infer(build, args.batch, args.iters, dtype=jnp.bfloat16)

@@ -9,10 +9,12 @@ to score ONE query per row. This module owns that inner loop:
   over each row's own cache prefix ``0..pos[r]``, fp32 score/softmax
   accumulation;
 * :func:`pooled_decode_attention` — the Pallas kernel (grid
-  ``(n_rows, heads, kv_blocks)``, online softmax in VMEM scratch, one
-  ``(block_l, head_dim)`` K/V tile resident per step) with the same
-  ``interpret``-mode CPU fallback pattern as ``ops.flash_attention``
-  (the dispatch probe is shared: ``utils.compat.auto_interpret``).
+  ``(n_rows, kv_blocks)``, online softmax in VMEM scratch, one
+  ``(block_l, heads*head_dim)`` K/V tile resident per step) with the
+  same ``interpret``-mode pattern off-TPU as ``ops.flash_attention``
+  (the dispatch probe is shared: ``utils.compat.auto_interpret``). On
+  a TPU it compiles or raises; it never drops to the interpreter or
+  the reference.
 
 Quantized KV (the int8 serving path — see docs/serving.md "Quantized KV
 cache"): K/V arrive as int8 with ONE fp32 scale per (row, head)
@@ -123,27 +125,51 @@ def decode_attention_reference(q, k, v, pos, k_scale=None, v_scale=None,
 # ------------------------------------------------------------------ kernel
 
 
-def _decode_kernel(*refs, scale, quantized, skip):
-    """Grid (N, H, n_l) — the KV-position axis is the INNER grid
-    dimension, so one (block_l, D) K tile and one V tile are
+def _decode_kernel(*refs, scale, quantized, skip, heads, head_dim):
+    """Grid (N, n_l) — one pooled row per outer step, the KV-position
+    axis INNER, so one ``(block_l, H*D)`` K tile and one V tile are
     VMEM-resident per step and the online-softmax state carries across
-    the position blocks in scratch (the flash-forward recipe, with a
-    single query row per (n, h) program).
+    the position blocks in scratch (the flash-forward recipe).
+
+    Every head of a row is computed from the SAME lane-dense tile: the
+    row's query is spread into a block-diagonal ``(H, H*D)`` matrix
+    (row ``h`` holds ``q[h]`` in lanes ``h*D..(h+1)*D`` and zeros
+    elsewhere), so ``q_bd . k_tile^T`` IS the per-head score matrix
+    ``(H, block_l)`` and the diagonal ``D``-wide blocks of
+    ``p . v_tile`` are the per-head contexts — two plain 2-D MXU
+    matmuls, no in-kernel reshape or per-head strided load. Mosaic
+    wants blocks whose last two dims are tile-aligned or whole;
+    ``(block_l, H*D)`` is, where the per-head ``(block_l, 1, D)`` tile
+    it replaces was refused by the compiler. The off-diagonal products
+    are redundant MXU work; whether the MXU or the HBM read bounds the
+    step has not been measured (ROADMAP S2).
+
+    ``pos`` and each row's last needed block index arrive by scalar
+    prefetch (SMEM): the first gates the block skip here, the second
+    clamps the K/V index maps, so blocks past a row's ``pos`` are
+    neither computed nor fetched.
 
     Quantized layout: int8 K/V tiles are loaded RAW; the (row, head)
-    scales enter as scalar factors — k_scale folds into the score
-    scaling, v_scale multiplies the accumulated context once at the
-    end (exact: both are constant over the contracted axes)."""
+    scales enter as ``(H, 1)`` column factors — k_scale folds into the
+    score scaling, v_scale multiplies the accumulated context once at
+    the end (exact: both are constant over the contracted axes)."""
     if quantized:
-        (q_ref, k_ref, v_ref, pos_ref, ks_ref, vs_ref, o_ref,
+        (pos_ref, _, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
          m_scr, l_scr, acc_scr) = refs
     else:
-        (q_ref, k_ref, v_ref, pos_ref, o_ref,
+        (pos_ref, _, q_ref, k_ref, v_ref, o_ref,
          m_scr, l_scr, acc_scr) = refs
-    j = pl.program_id(2)
-    n_l = pl.num_programs(2)
+    j = pl.program_id(1)
+    n_l = pl.num_programs(1)
     bl = k_ref.shape[1]
-    pos = jnp.reshape(pos_ref[...], ())
+    hd = heads * head_dim
+    pos = pos_ref[pl.program_id(0)]
+
+    def _head_diag():
+        # (H, H*D) mask of each head's own D-wide lane block
+        lo = jax.lax.broadcasted_iota(jnp.int32, (heads, hd), 0) * head_dim
+        col = jax.lax.broadcasted_iota(jnp.int32, (heads, hd), 1)
+        return jnp.logical_and(col >= lo, col < lo + head_dim)
 
     @pl.when(j == 0)
     def _init():
@@ -152,24 +178,22 @@ def _decode_kernel(*refs, scale, quantized, skip):
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
     def _step():
-        q = q_ref[0]                                    # (1, D)
-        k = k_ref[0, :, 0, :]                           # (BL, D)
-        v = v_ref[0, :, 0, :]
+        k = k_ref[0]                                    # (BL, H*D)
+        v = v_ref[0]
+        q_bd = jnp.where(_head_diag(), q_ref[0].astype(jnp.float32), 0.0)
         if quantized:
-            ks = jnp.reshape(ks_ref[...], ())
             s = jax.lax.dot_general(
-                q.astype(jnp.float32), k.astype(jnp.float32),
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * (scale * ks)
+                q_bd, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * (scale * ks_ref[0])
         else:
             s = jax.lax.dot_general(
-                q.astype(k.dtype), k, (((1,), (1,)), ((), ())),
+                q_bd.astype(k.dtype), k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
         cols = j * bl + jax.lax.broadcasted_iota(jnp.int32, (1, bl), 1)
-        s = jnp.where(cols <= pos, s, _NEG_INF)
+        s = jnp.where(cols <= pos, s, _NEG_INF)         # (H, BL)
         m = m_scr[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)                          # (1, BL) f32
+        p = jnp.exp(s - m_new)                          # (H, BL) f32
         alpha = jnp.exp(m - m_new)
         m_scr[...] = m_new
         l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1,
@@ -180,7 +204,7 @@ def _decode_kernel(*refs, scale, quantized, skip):
         else:
             pv = jnp.dot(p.astype(v.dtype), v,
                          preferred_element_type=jnp.float32)
-        acc_scr[...] = acc_scr[...] * alpha + pv
+        acc_scr[...] = acc_scr[...] * alpha + pv        # (H, H*D)
 
     if skip:
         # compiled path: key blocks entirely past the row's pos
@@ -197,25 +221,35 @@ def _decode_kernel(*refs, scale, quantized, skip):
         l_safe = jnp.maximum(l_scr[...], 1e-30)
         out = acc_scr[...] / l_safe
         if quantized:
-            out = out * jnp.reshape(vs_ref[...], ())
+            out = out * vs_ref[0]
+        # row h's own lane block is head h's context; the rest of the
+        # row is the redundant cross-head product
+        out = jnp.sum(jnp.where(_head_diag(), out, 0.0), axis=0,
+                      keepdims=True)
         o_ref[0] = out.astype(o_ref.dtype)
 
 
-def _auto_block_l(L: int) -> int:
+#: VMEM the K and V tiles may take together, double-buffered (4 tiles)
+#: — a quarter of the 16 MiB scoped default, leaving room for the f32
+#: casts of the int8 path and the score rows
+_KV_TILE_BUDGET = 4 * 1024 * 1024
+
+
+def _auto_block_l(L: int, row_bytes: int) -> int:
     """KV-position tile length: the LARGEST of 512/384/256/128 that
-    divides the 128-padded cache window (VMEM holds 2 int8/bf16
-    (block, D) tiles + the (1, block) f32 score row — far under budget;
-    bigger tiles amortize grid-step overhead on the short-query decode
-    grid). Divisibility is the load-bearing part: a non-dividing block
-    forces :func:`pooled_decode_attention` to ``jnp.pad`` the K/V
-    operands, and on the per-step decode hot path that pad is a full
-    copy of the entire pooled cache — the exact HBM traffic this kernel
-    exists to avoid. Any 128-multiple window (every real serving
-    ``max_len``) gets pad 0 here; only sub-128 or ragged windows pay
-    the (small-cache) pad."""
+    divides the 128-padded cache window and keeps the four resident
+    ``(block, H*D)`` K/V tiles inside ``_KV_TILE_BUDGET`` (bigger tiles
+    amortize grid-step overhead on the short-query decode grid).
+    Divisibility is the load-bearing part: a non-dividing block forces
+    :func:`pooled_decode_attention` to ``jnp.pad`` the K/V operands,
+    and on the per-step decode hot path that pad is a full copy of the
+    entire pooled cache — the exact HBM traffic this kernel exists to
+    avoid. Any 128-multiple window (every real serving ``max_len``)
+    gets pad 0 here; only sub-128 or ragged windows pay the
+    (small-cache) pad."""
     padded = ((max(L, 1) + 127) // 128) * 128
     for b in (512, 384, 256, 128):
-        if padded % b == 0:
+        if padded % b == 0 and 4 * b * row_bytes <= _KV_TILE_BUDGET:
             return b
     return 128
 
@@ -235,7 +269,9 @@ def pooled_decode_attention(q, k, v, pos, k_scale=None, v_scale=None,
     the shared ``utils.compat.auto_interpret`` probe. The cache window
     is right-padded to a block multiple when needed — padded columns
     sit beyond every row's ``pos`` and are masked like any other
-    out-of-window position."""
+    out-of-window position. The kernel reads the cache through its
+    ``(N, L, H*D)`` view (heads folded into the lane axis — see
+    :func:`_decode_kernel`)."""
     from jax.experimental.pallas import tpu as pltpu
 
     from bigdl_tpu.utils.compat import pallas_tpu_compiler_params
@@ -250,42 +286,51 @@ def pooled_decode_attention(q, k, v, pos, k_scale=None, v_scale=None,
     if interpret is None:
         interpret = _auto_interpret()
     if block is None:
-        block = _auto_block_l(L)
+        block = _auto_block_l(L, h * d * k.dtype.itemsize)
     quantized = k_scale is not None
+    k = k.reshape(n, L, h * d)
+    v = v.reshape(n, L, h * d)
     pad = (-L) % block
     if pad:
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    lp = L + pad
-    pos2 = jnp.asarray(pos, jnp.int32).reshape(n, 1)
-    grid = (n, h, lp // block)
-    qblk = pl.BlockSpec((1, 1, d), lambda n_, h_, j: (n_, h_, 0))
-    kblk = pl.BlockSpec((1, block, 1, d), lambda n_, h_, j: (n_, j, h_, 0))
-    posblk = pl.BlockSpec((1, 1), lambda n_, h_, j: (n_, 0))
-    sblk = pl.BlockSpec((1, 1), lambda n_, h_, j: (n_, h_))
-    operands = [q, k, v, pos2]
-    in_specs = [qblk, kblk, kblk, posblk]
+        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0)))
+    pos1 = jnp.asarray(pos, jnp.int32).reshape(n)
+    last_blk = pos1 // block
+    # every block's last two dims are whole array dims or (block_l:
+    # a 128-multiple, H*D: whole) — what the Mosaic lowering accepts
+    qblk = pl.BlockSpec((1, 1, h * d), lambda n_, j, pos_, last_: (n_, 0, 0))
+    # blocks past the row's pos re-address the last needed one: the
+    # pipeline sees an unchanged block index and issues no DMA
+    kblk = pl.BlockSpec(
+        (1, block, h * d),
+        lambda n_, j, pos_, last_: (n_, jnp.minimum(j, last_[n_]), 0))
+    sblk = pl.BlockSpec((1, h, 1), lambda n_, j, pos_, last_: (n_, 0, 0))
+    operands = [q.reshape(n, 1, h * d), k, v]
+    in_specs = [qblk, kblk, kblk]
     if quantized:
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
+        operands += [k_scale.astype(jnp.float32).reshape(n, h, 1),
+                     v_scale.astype(jnp.float32).reshape(n, h, 1)]
         in_specs += [sblk, sblk]
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=float(scale),
-                          quantized=quantized, skip=not interpret),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=qblk,
-        out_shape=_out_struct((n, h, d), out_dtype, *operands),
-        scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
-        ],
+                          quantized=quantized, skip=not interpret,
+                          heads=h, head_dim=d),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n, (L + pad) // block),
+            in_specs=in_specs,
+            out_specs=qblk,
+            scratch_shapes=[
+                pltpu.VMEM((h, 1), jnp.float32),
+                pltpu.VMEM((h, 1), jnp.float32),
+                pltpu.VMEM((h, h * d), jnp.float32),
+            ]),
+        out_shape=_out_struct((n, 1, h * d), out_dtype, pos1, *operands),
         compiler_params=None if interpret else pallas_tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(*operands)
-    return out
+    )(pos1, last_blk, *operands)
+    return out.reshape(n, h, d)
 
 
 def decode_attention(q, k, v, pos, k_scale=None, v_scale=None,

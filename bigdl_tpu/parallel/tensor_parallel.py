@@ -33,9 +33,8 @@ decode/prefill steps (``models/transformer.py``, ``mesh=`` on
 projections under ``utils.compat.shard_map`` — column-parallel QKV/fc1
 arrive pre-sliced via ``tp_param_specs``'s in_specs, so each block costs
 exactly the two closing psums, with the per-layer K/V cache sharded on
-its head axis (``bigdl_tpu.serving.sharded``). Use ``compat.shard_map``
-(not ``jax.shard_map``) around these functions when the code must run on
-jax 0.4.x as well.
+its head axis (``bigdl_tpu.serving.sharded``). Product code wraps these
+functions in ``compat.shard_map``, not ``jax.shard_map`` (SPMD101).
 """
 
 from __future__ import annotations

@@ -91,7 +91,7 @@ from bigdl_tpu.serving.prefix_cache import PrefixCache
 from bigdl_tpu.serving.sampling import SamplingParams
 from bigdl_tpu.serving.scheduler import Request, Scheduler
 from bigdl_tpu.serving.sharded import (
-    ShardedEngine, ShardedKVPool, emulate_cpu_devices, make_mesh,
+    ShardedEngine, ShardedKVPool, make_mesh,
 )
 from bigdl_tpu.serving.speculative import SpeculativeConfig
 
@@ -100,7 +100,7 @@ __all__ = ["ServingEngine", "KVPool", "ServingMetrics", "Request",
            "ChunkedAdmissionController", "PrefixCache",
            "SamplingParams", "SpeculativeConfig", "bucket_len",
            "ShardedEngine", "ShardedKVPool", "make_mesh",
-           "emulate_cpu_devices", "Degrade", "FaultError",
+           "Degrade", "FaultError",
            "FaultInjector", "VirtualClock", "WatchdogConfig",
            "FENCE_SITES", "fence", "fence_wait",
            "DisaggregatedEngine", "PrefillWorker", "DecodeWorker",

@@ -26,18 +26,18 @@ is that step. Two composable axes over one
 * **tensor parallelism** (``model`` axis) — attention heads + MLP
   hidden shard Megatron-style through
   :mod:`bigdl_tpu.parallel.tensor_parallel`'s column/row-parallel
-  layout, lowered under ``utils.compat.shard_map`` (so it runs on jax
-  0.4.37 and on jax.shard_map-era releases alike) with the paper-
+  layout, lowered under ``utils.compat.shard_map`` with the paper-
   canonical TWO collectives per block: one psum closing the attention
   output projection, one closing the MLP. The per-layer K/V cache
   shards on its HEAD axis; embeddings, LayerNorms, the LM head, and
   the sampling epilogue stay replicated. See
   ``models/transformer.py`` (``mesh=`` on the step builders).
 
-The subsystem owns mesh construction (:func:`make_mesh`, including the
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` CPU emulation
-recipe via :func:`emulate_cpu_devices`, so everything here is testable
-on a single-host box), the sharded pool
+The subsystem owns mesh construction (:func:`make_mesh`, over the
+devices jax has — a host with too few raises; on a CPU box start python
+with ``JAX_PLATFORMS=cpu
+XLA_FLAGS=--xla_force_host_platform_device_count=8`` in the environment
+and everything here is testable on one host), the sharded pool
 (:class:`ShardedKVPool` — slot→(shard, row) mapping, balanced
 cross-shard allocation, mesh-pinned admission scatter), and the
 :class:`ShardedEngine` front end. The stock
@@ -48,9 +48,8 @@ its ``mesh=``/``parallelism=`` knobs; admission
 their output rows route to the owning shard through the pool's
 mesh-aware scatter.
 
-    from bigdl_tpu.serving.sharded import ShardedEngine, emulate_cpu_devices
+    from bigdl_tpu.serving.sharded import ShardedEngine
 
-    emulate_cpu_devices(8)               # CPU box: 8 virtual devices
     eng = ShardedEngine(lm, parallelism={"data": 4, "model": 2},
                         n_slots=8)
     rid = eng.submit([3, 7, 2], max_new_tokens=32)
@@ -59,7 +58,6 @@ mesh-aware scatter.
 
 from __future__ import annotations
 
-import os
 from typing import Dict, List, Optional, Tuple
 
 from bigdl_tpu.serving.kv_pool import KVPool
@@ -68,32 +66,6 @@ from bigdl_tpu.serving.kv_pool import KVPool
 #: ``data`` (slot rows), weights over ``model`` (heads / MLP hidden).
 DATA_AXIS = "data"
 MODEL_AXIS = "model"
-
-
-def emulate_cpu_devices(n: int = 8) -> int:
-    """Make this host expose ``n`` virtual CPU devices (the
-    distributed-in-one-process pattern the test suite uses): sets
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=n`` and pins the
-    platform to CPU. Must run BEFORE jax initializes its backend — if
-    the backend is already up with fewer devices, raises with the
-    recipe (re-exec with the flag in the environment). Returns the
-    device count. No-op when enough devices already exist."""
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count={n}").strip()
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    n_dev = jax.device_count()           # initializes the backend
-    if n_dev < n:
-        raise RuntimeError(
-            f"only {n_dev} device(s) visible but {n} requested — the "
-            "jax backend initialized before emulate_cpu_devices() could "
-            "set XLA_FLAGS. Set XLA_FLAGS="
-            f"--xla_force_host_platform_device_count={n} in the "
-            "environment (before python starts) and retry.")
-    return n_dev
 
 
 def make_mesh(data: int = 1, model: int = 1, devices=None):
@@ -113,18 +85,15 @@ def make_mesh(data: int = 1, model: int = 1, devices=None):
     if len(devs) < need:
         raise ValueError(
             f"mesh ({data} data x {model} model) needs {need} devices, "
-            f"host has {len(devs)} — on a CPU box call "
-            f"emulate_cpu_devices({need}) before any jax computation "
-            "(or set XLA_FLAGS=--xla_force_host_platform_device_count="
-            f"{need})")
+            f"host has {len(devs)} — to emulate them on a CPU box, start "
+            "python with JAX_PLATFORMS=cpu XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={need}")
     return Mesh(np.asarray(devs[:need]).reshape(data, model),
                 (DATA_AXIS, MODEL_AXIS))
 
 
 def _axis_size(mesh, name: str) -> int:
-    """Size of a mesh axis by name, 1 when the mesh lacks the axis
-    (``Mesh.shape`` is a name→size mapping on every jax this repo
-    supports)."""
+    """Size of a mesh axis by name, 1 when the mesh lacks the axis."""
     return int(dict(mesh.shape).get(name, 1))
 
 

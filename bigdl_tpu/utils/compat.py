@@ -1,86 +1,37 @@
-"""Version-compat shims for jax APIs that moved between releases.
+"""The one place the SPMD plane spells jax APIs that have moved before.
 
-The SPMD plane targets three generations of jax at once:
-
-* ``shard_map`` lived in ``jax.experimental.shard_map`` through the
-  0.4.x line, then graduated to ``jax.shard_map``;
-* varying-type marking went ``lax.pvary`` (0.5/0.6 era) and then
-  ``lax.pcast(..., to="varying")`` (0.9+, which auto-psums cotangents
-  of unvaried inputs — the marker is what keeps gradients LOCAL so the
-  step's one explicit ``pmean`` stays the only all-reduce). Pre-pvary
-  shard_map has no varying-type tracking at all, so cotangents come
-  back local already and the correct marker is the identity.
-
-Product code must not pin any one spelling — these helpers resolve the
-best available implementation at call time (cheap getattr probes, no
-import-time jax dependency), so the same file runs on the 0.4.37
-container, the 0.9 dev box, and whatever ships next.
+Written for the single installation this repo runs on (jax 0.9.0):
+``jax.shard_map`` with ``check_vma``, ``lax.axis_size``,
+``lax.pcast(..., to="varying")``, ``jax.typeof(x).vma`` and
+``pltpu.CompilerParams``. Product code calls these helpers instead of
+the jax names (the analyzer's SPMD101 enforces it), so the next move
+is an edit to this file only. jax is imported inside each function:
+the analyzer imports this package without paying jax's start-up.
 """
 
 from __future__ import annotations
 
 
 def resolve_shard_map():
-    """The best available ``shard_map`` callable: ``jax.shard_map``
-    when it exists, else ``jax.experimental.shard_map.shard_map``.
-    Raises ``NotImplementedError`` only if neither exists (pre-0.4.3
-    jax, below this repo's floor)."""
+    """The ``shard_map`` callable (``jax.shard_map``)."""
     import jax
 
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm
-    try:
-        from jax.experimental.shard_map import shard_map as sm
-    except ImportError as e:                      # pragma: no cover
-        raise NotImplementedError(
-            f"this jax ({jax.__version__}) has neither jax.shard_map "
-            "nor jax.experimental.shard_map — too old for the SPMD "
-            "plane") from e
-    return sm
+    return jax.shard_map
 
 
 def shard_map(f, **kwargs):
-    """``jax.shard_map``-or-``jax.experimental.shard_map`` (resolved per
-    call — cheap, and keeps this module import-safe without jax).
-    Callers pass ``mesh``/``in_specs``/``out_specs`` as keywords, the
-    signature both generations share.
+    """``jax.shard_map(f, mesh=..., in_specs=..., out_specs=...,
+    check_vma=...)`` — callers pass everything by keyword."""
+    import jax
 
-    The replication-check toggle RENAMED between generations —
-    ``check_rep`` (0.4.x experimental) became ``check_vma`` (jax with
-    the varying-type system). Callers may pass either spelling; it is
-    forwarded under whichever name this jax accepts (and dropped if the
-    resolved shard_map has neither — the check simply stays at its
-    default there)."""
-    import inspect
-
-    sm = resolve_shard_map()
-    if "check_vma" in kwargs or "check_rep" in kwargs:
-        val = kwargs.pop("check_vma", None)
-        if "check_rep" in kwargs:
-            val = kwargs.pop("check_rep")
-        try:
-            accepted = inspect.signature(sm).parameters
-        except (TypeError, ValueError):     # pragma: no cover
-            accepted = {}
-        if "check_vma" in accepted:
-            kwargs["check_vma"] = val
-        elif "check_rep" in accepted:
-            kwargs["check_rep"] = val
-    return sm(f, **kwargs)
+    return jax.shard_map(f, **kwargs)
 
 
 def axis_size(axis_name: str):
-    """Static size of a mapped axis inside a ``shard_map``/``pmap`` body:
-    ``lax.axis_size`` where it exists, else ``lax.psum(1, axis)`` — the
-    pre-axis_size spelling (a static constant either way: the axis size
-    is known at trace time)."""
+    """Static size of a mapped axis inside a ``shard_map`` body."""
     from jax import lax
 
-    sz = getattr(lax, "axis_size", None)
-    if sz is not None:
-        return sz(axis_name)
-    return lax.psum(1, axis_name)
+    return lax.axis_size(axis_name)
 
 
 def auto_interpret() -> bool:
@@ -89,81 +40,43 @@ def auto_interpret() -> bool:
     CPU-vs-TPU kernel dispatch decision — both ``ops.flash_attention``
     and ``ops.decode_attention`` resolve their ``interpret=None``
     default through here, so the two kernels can never drift on when
-    the compiled Mosaic path engages (tier-1 CI runs everything in
-    interpret mode on CPU; the compiled path is exercised by the
-    TPU/multichip dryrun flow)."""
+    the compiled Mosaic path engages (tier-1 runs everything in
+    interpret mode on CPU and cross-lowers the compiled path in
+    tests/test_chip_lowering.py; ``chip_smoke.py`` runs it on the
+    chip). On a TPU the answer is always False: a kernel the compiler
+    refuses raises, it never drops back to the interpreter."""
     import jax
 
     return jax.default_backend() != "tpu"
 
 
 def pallas_tpu_compiler_params(**kwargs):
-    """A Mosaic compiler-params object for ``pl.pallas_call`` — the
-    class RENAMED between jax generations (``pltpu.TPUCompilerParams``
-    on the 0.4.x line, ``pltpu.CompilerParams`` later). Callers pass
-    the fields both generations share (``dimension_semantics=...``);
-    this resolves whichever spelling the installed jax has, so the
-    compiled (non-interpret) kernel path traces on every supported
-    generation — interpret-mode CI never touches compiler params, which
-    is exactly how a pinned spelling would rot undetected."""
+    """A Mosaic compiler-params object for ``pl.pallas_call``
+    (``pltpu.CompilerParams``)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams", None)
-    if cls is None:                               # pragma: no cover
-        import jax
-
-        raise NotImplementedError(
-            f"this jax ({jax.__version__}) has neither "
-            "pltpu.CompilerParams nor pltpu.TPUCompilerParams")
-    return cls(**kwargs)
+    return pltpu.CompilerParams(**kwargs)
 
 
 def varying_axes(x):
-    """The varying-manual-axes (vma) set of ``x``'s type on jax
-    generations with the varying-type system (``jax.typeof`` + ``.vma``),
-    else an empty frozenset — pre-vma jax (e.g. 0.4.37) tracks no
-    replication types, so nothing varies as far as type checking goes."""
+    """The varying-manual-axes (vma) set of ``x``'s type: the mesh axes
+    over which a value inside a ``shard_map`` body differs per device."""
     import jax
 
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return frozenset()
-    return getattr(typeof(x), "vma", None) or frozenset()
+    return jax.typeof(x).vma
 
 
 def varying_marker_kind() -> str:
-    """Which marker :func:`device_varying_marker` resolves to on this
-    jax: ``"pcast"`` (0.9+), ``"pvary"`` (0.5/0.6 era), or
-    ``"identity"`` (pre-pvary, e.g. 0.4.37 — no varying-type system, so
-    there is nothing to mark).  Lets callers that *test* the marking
-    construction skip where it cannot be built, without probing
-    ``lax.pcast``/``lax.pvary`` themselves (that probe is exactly the
-    compat drift SPMD101 flags)."""
-    from jax import lax
-
-    if getattr(lax, "pcast", None) is not None:
-        return "pcast"
-    if getattr(lax, "pvary", None) is not None:
-        return "pvary"
-    return "identity"
+    """Which primitive :func:`device_varying_marker` uses: ``"pcast"``."""
+    return "pcast"
 
 
 def device_varying_marker(axis_name: str):
     """A function marking an array device-varying over ``axis_name``
-    inside a ``shard_map`` body — the knob that keeps cotangents of
-    replicated inputs LOCAL (per-shard) instead of auto-psummed:
-
-    * jax >= 0.9: ``lax.pcast(x, axis, to="varying")``;
-    * pvary-era jax: ``lax.pvary(x, axis)``;
-    * pre-pvary jax (e.g. 0.4.37): identity — old shard_map has no
-      varying-type system, cotangents are already local.
-    """
+    inside a ``shard_map`` body — ``lax.pcast(x, axis, to="varying")``.
+    jax auto-psums cotangents of unvaried inputs; the marker is what
+    keeps gradients of replicated inputs LOCAL (per-shard), so the
+    step's one explicit ``pmean`` stays the only all-reduce."""
     from jax import lax
 
-    kind = varying_marker_kind()
-    if kind == "pcast":
-        return lambda x: lax.pcast(x, axis_name, to="varying")
-    if kind == "pvary":
-        return lambda x: lax.pvary(x, axis_name)
-    return lambda x: x
+    return lambda x: lax.pcast(x, axis_name, to="varying")

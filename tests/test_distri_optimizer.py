@@ -277,7 +277,7 @@ def test_pod_set_validation_pyspark_order():
 
 def test_allreduce_construction_single_collective_on_wire():
     """The allreduce-mode spmd construction (mark params VARYING with
-    pvary/pcast, then one explicit pmean — distri_optimizer.py:286-295)
+    pcast, then one explicit pmean — distri_optimizer.py:286-295)
     must compile to exactly ONE all-reduce carrying the gradient bytes.
     Without the varying mark, jax auto-psums the cotangent of the
     replicated input AND the user pmean reduces again — 2x wire traffic
@@ -291,17 +291,9 @@ def test_allreduce_construction_single_collective_on_wire():
     from jax import lax
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from bigdl_tpu.utils.compat import (
-        device_varying_marker, shard_map, varying_marker_kind)
+    from bigdl_tpu.utils.compat import device_varying_marker, shard_map
 
     mesh = Mesh(np.asarray(jax.devices()[:8]).reshape(8), ("data",))
-    if varying_marker_kind() == "identity":
-        # NOTE: on such a jax the varying-mark construction (and the
-        # distri_optimizer hot path that uses it) cannot be BUILT at all,
-        # so there is no behavior to pin here — the skip loses coverage
-        # only on toolchains where the feature itself is absent
-        pytest.skip("this jax predates lax.pcast/lax.pvary — the "
-                    "varying-mark construction under test cannot be built")
     mark = device_varying_marker("data")
 
     def make(marked):

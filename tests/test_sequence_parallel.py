@@ -1,10 +1,8 @@
 """Ring/Ulysses sequence parallelism vs dense attention, on the 8-device
 CPU mesh (the distributed-in-one-process pattern of SURVEY.md §4).
 
-Uses ``utils.compat.shard_map`` (not ``jax.shard_map``) so the suite
-runs on every jax generation this repo supports — 0.4.x spells it
-``jax.experimental.shard_map`` and calls the replication check
-``check_rep``; the shim resolves both."""
+Uses ``utils.compat.shard_map`` (not ``jax.shard_map``), as product
+code must: the one module that spells the jax name (SPMD101)."""
 
 import numpy as np
 import pytest

@@ -420,9 +420,7 @@ def test_chunked_sharded_dp_parity(rng):
     the owning shard through the pool's mesh-pinned scatter, outputs
     token-identical to the unsharded chunked engine."""
     from bigdl_tpu.serving import ServingEngine
-    from bigdl_tpu.serving.sharded import emulate_cpu_devices
 
-    emulate_cpu_devices(8)
     lm = _make_lm()
     reqs = _ragged_reqs(rng, n=9)
 
