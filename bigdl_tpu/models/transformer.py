@@ -827,17 +827,27 @@ def make_prefill_step(model: Sequential, compute_dtype=None,
     def prefill_checked(params, tokens, carry):
         import numpy as np
 
-        pos = carry["pos"]
-        # fresh-carry contract (see docstring): cheap concrete-value check
-        # outside jit; under an outer trace pos is abstract and the check
-        # is skipped (the (B,) int32 host readback costs microseconds)
-        if not isinstance(pos, jax.core.Tracer) and np.asarray(pos).any():
-            raise ValueError(
-                "make_prefill_step requires a fresh carry (carry['pos'] "
-                "must be all zeros): prefill writes K/V at positions "
-                "0..P-1 and resets pos, which would corrupt a partially-"
-                f"filled cache (got pos={np.asarray(pos).tolist()})")
-        return jitted(params, tokens, carry)
+        from bigdl_tpu.serving.metrics import span
+
+        # the span wraps the BODY (fences.SPAN_NAMES): host guards, a
+        # small readback and the program's LAUNCH — never its device
+        # time, which is the jit_prefill program's in the trace
+        with span("prefill.launch", rows=tokens.shape[0],
+                  padded=tokens.shape[0], bucket=tokens.shape[-1]):
+            pos = carry["pos"]
+            # fresh-carry contract (see docstring): cheap concrete-value
+            # check outside jit; under an outer trace pos is abstract and
+            # the check is skipped (the (B,) int32 host readback costs
+            # microseconds)
+            if not isinstance(pos, jax.core.Tracer) \
+                    and np.asarray(pos).any():
+                raise ValueError(
+                    "make_prefill_step requires a fresh carry "
+                    "(carry['pos'] must be all zeros): prefill writes K/V "
+                    "at positions 0..P-1 and resets pos, which would "
+                    "corrupt a partially-filled cache (got "
+                    f"pos={np.asarray(pos).tolist()})")
+            return jitted(params, tokens, carry)
 
     # exposed so benchmarks/tests can count compiled (B, P) buckets
     prefill_checked._jitted = jitted
@@ -1048,6 +1058,9 @@ def make_batch_prefill_step(model: Sequential, compute_dtype=None,
         def run(params, tokens, lengths, carry, adapter_ids, bank):
             return prefill(params, tokens, lengths, carry, adapter_ids,
                            bank)
+        # one program, one name in a profile (jit_prefill), whatever
+        # the engine was built with
+        run.__name__ = run.__qualname__ = "prefill"
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
 
@@ -1083,37 +1096,45 @@ def make_batch_prefill_step(model: Sequential, compute_dtype=None,
     def prefill_checked(params, tokens, lengths, carry, *adapter_args):
         import numpy as np
 
-        lengths = jnp.asarray(lengths, jnp.int32)
-        if tokens.ndim != 2 or lengths.shape != tokens.shape[:1]:
-            raise ValueError(
-                f"tokens must be (B, L) with lengths (B,): got "
-                f"{tokens.shape} / {lengths.shape}")
-        if carry["pos"].shape[0] != tokens.shape[0]:
-            raise ValueError(
-                f"carry has {carry['pos'].shape[0]} rows but tokens has "
-                f"{tokens.shape[0]} — the carry must come from "
-                "make_batch_decode_step's init_carry(B)")
-        pos = carry["pos"]
-        # cheap concrete-value guards outside jit (abstract under an
-        # outer trace, where they are skipped): a row writing past the
-        # cache would be silently DROPPED by the masked scatter
-        if not isinstance(lengths, jax.core.Tracer) \
-                and not isinstance(pos, jax.core.Tracer):
-            ln, ps = np.asarray(lengths), np.asarray(pos)
-            if (ln < 0).any() or (ln > tokens.shape[1]).any():
+        from bigdl_tpu.serving.metrics import span
+
+        # the span wraps the BODY (fences.SPAN_NAMES): host guards, their
+        # small readback and the program's LAUNCH — never its device
+        # time, which is the jit_prefill program's in the trace
+        with span("prefill.launch", padded=tokens.shape[0],
+                  bucket=tokens.shape[-1]) as sp:
+            lengths = jnp.asarray(lengths, jnp.int32)
+            if tokens.ndim != 2 or lengths.shape != tokens.shape[:1]:
                 raise ValueError(
-                    f"lengths must lie in 0..L={tokens.shape[1]} "
-                    f"(got {ln.tolist()})")
-            if (ps + ln > max_len).any():
+                    f"tokens must be (B, L) with lengths (B,): got "
+                    f"{tokens.shape} / {lengths.shape}")
+            if carry["pos"].shape[0] != tokens.shape[0]:
                 raise ValueError(
-                    f"rows would write past max_len {max_len}: "
-                    f"pos={ps.tolist()} + lengths={ln.tolist()}")
-        if adapter is not None and len(adapter_args) != 2:
-            raise ValueError(
-                "this prefill step was built with an adapter spec — "
-                "call it as prefill(params, tokens, lengths, carry, "
-                "adapter_ids, bank)")
-        return jitted(params, tokens, lengths, carry, *adapter_args)
+                    f"carry has {carry['pos'].shape[0]} rows but tokens has "
+                    f"{tokens.shape[0]} — the carry must come from "
+                    "make_batch_decode_step's init_carry(B)")
+            pos = carry["pos"]
+            # cheap concrete-value guards outside jit (abstract under an
+            # outer trace, where they are skipped): a row writing past the
+            # cache would be silently DROPPED by the masked scatter
+            if not isinstance(lengths, jax.core.Tracer) \
+                    and not isinstance(pos, jax.core.Tracer):
+                ln, ps = np.asarray(lengths), np.asarray(pos)
+                sp.note(rows=int(np.count_nonzero(ln)))
+                if (ln < 0).any() or (ln > tokens.shape[1]).any():
+                    raise ValueError(
+                        f"lengths must lie in 0..L={tokens.shape[1]} "
+                        f"(got {ln.tolist()})")
+                if (ps + ln > max_len).any():
+                    raise ValueError(
+                        f"rows would write past max_len {max_len}: "
+                        f"pos={ps.tolist()} + lengths={ln.tolist()}")
+            if adapter is not None and len(adapter_args) != 2:
+                raise ValueError(
+                    "this prefill step was built with an adapter spec — "
+                    "call it as prefill(params, tokens, lengths, carry, "
+                    "adapter_ids, bank)")
+            return jitted(params, tokens, lengths, carry, *adapter_args)
 
     # exposed so benchmarks/tests can count compiled (B, L) buckets
     prefill_checked._jitted = jitted

@@ -329,6 +329,7 @@ def pooled_decode_attention(q, k, v, pos, k_scale=None, v_scale=None,
         compiler_params=None if interpret else pallas_tpu_compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="pooled_decode_attention",
     )(pos1, last_blk, *operands)
     return out.reshape(n, h, d)
 

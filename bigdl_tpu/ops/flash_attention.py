@@ -341,6 +341,7 @@ def _flash_fwd(q3, k3, v3, scale, causal, block, interpret,
         compiler_params=None if interpret else pallas_tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(qp, kp, vp, off)
     return o[:, :t], lse[:, :t]
 
@@ -377,6 +378,7 @@ def _flash_bwd(q3, k3, v3, o3, lse, do3, scale, causal, block, interpret,
         compiler_params=None if interpret else pallas_tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qp, kp, vp, dop, lsep, deltap, off)
 
     # dk/dv: key axis is the carried (outer-block) dim, queries innermost
@@ -396,6 +398,7 @@ def _flash_bwd(q3, k3, v3, o3, lse, do3, scale, causal, block, interpret,
         compiler_params=None if interpret else pallas_tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qp, kp, vp, dop, lsep, deltap, off)
     return dq[:, :t], dk[:, :k3.shape[1]], dv[:, :v3.shape[1]]
 

@@ -294,7 +294,11 @@ class Optimizer:
         """Capture a ``jax.profiler`` trace for iterations
         ``[start_iteration, start_iteration + n_iterations)`` — the deep
         option on top of the reference-style Metrics counters (SURVEY.md
-        §5.1); view with TensorBoard's profile plugin or Perfetto."""
+        §5.1); view with TensorBoard's profile plugin or Perfetto. The
+        loop's phases are in it as ``train.iteration`` (a profiler step)
+        holding ``train.fetch``, ``train.dispatch`` and
+        ``train.loss_sync``, on the device events' timebase; Python
+        frames are not recorded."""
         self._profile = {"dir": trace_dir, "start": start_iteration,
                          "stop": start_iteration + n_iterations}
         return self
@@ -838,6 +842,13 @@ class Optimizer:
         next_ready = None            # (inp, tgt, bsz) placed ahead of time
         epoch_start = time.time()
 
+        def fetch():
+            """The next batch, placed: the input path's share of an
+            iteration (StopIteration passes through, leaving no sample)."""
+            with self.metrics.span("train.fetch", "data fetch time"):
+                b = next(data_iter)
+                return (*place_batch(b), b.size())
+
         while not self.end_when(state):
             if self._preempt_flag:
                 self._checkpoint(
@@ -853,110 +864,127 @@ class Optimizer:
             state["epoch_finished"] = False
             if self._profile is not None:
                 if state["neval"] == self._profile["start"]:
-                    jax.profiler.start_trace(self._profile["dir"])
+                    # the loop's spans name its phases; Python frames on
+                    # the same line would only rename them with every
+                    # edit (file:line), at several times the trace's
+                    # size and cost
+                    options = jax.profiler.ProfileOptions()
+                    options.python_tracer_level = 0
+                    jax.profiler.start_trace(self._profile["dir"],
+                                             profiler_options=options)
                     self._profile["active"] = True
                 elif state["neval"] == self._profile["stop"] and \
                         self._profile.get("active"):
                     jax.profiler.stop_trace()
                     self._profile["active"] = False
-            # input pipelining: the NEXT batch is fetched/placed while the
-            # dispatched (async) step still runs on the device; float(loss)
-            # is the only host sync point
-            if next_ready is None:
-                try:
-                    b = next(data_iter)
-                except StopIteration:
-                    logger.warning(
-                        "data iterator exhausted before end_when fired; "
-                        "stopping. (Possible causes: the iterator yields "
-                        "fewer batches than dataset.size() implies, or a "
-                        "directly-constructed stateful Trigger without a "
-                        "side-effect-free peek_fn.)")
-                    break
-                next_ready = (*place_batch(b), b.size())
-            inp, tgt, bsz = next_ready
-            t0 = time.time()
-            rng = jax.random.fold_in(base_key, state["neval"])
-            params, opt_state, model_state, loss = step(
-                params, opt_state, model_state, rng, inp, tgt,
-            )
-            # prefetch overlaps device compute — but only when the loop
-            # will actually run again, so finite/shared iterators never
-            # lose a batch to a discarded prefetch. The speculative state
-            # mirrors the counter updates below; loss-triggered stops
-            # can't be predicted pre-sync and may still prefetch once.
-            spec = dict(state)
-            spec["neval"] += 1
-            spec["epoch_finished"] = seen_this_epoch + bsz >= epoch_size
-            if spec["epoch_finished"]:
-                spec["epoch"] += 1
-            if self.end_when.peek(spec):
-                next_ready = None
-            else:
-                try:
-                    b = next(data_iter)      # overlaps device compute
-                    next_ready = (*place_batch(b), b.size())
-                except StopIteration:
-                    # finite custom iterators: end_when decides at loop top
-                    next_ready = None
-            loss_f = float(loss)
-            dt = time.time() - t0
-            self.metrics.add("computing time", dt)
-            self.metrics.add("records/second", bsz / max(dt, 1e-9))
-            state["loss"] = loss_f
-            state["neval"] += 1
-            self.optim_method.state["neval"] = state["neval"]
-            seen_this_epoch += bsz
-            state["seen"] = seen_this_epoch
-
-            if self.train_summary is not None:
-                self.train_summary.add_scalar("Loss", loss_f, state["neval"] - 1)
-                self.train_summary.add_scalar(
-                    "Throughput", bsz / max(dt, 1e-9), state["neval"] - 1
-                )
-                sched = getattr(self.optim_method, "learning_rate_schedule", None)
-                base_lr = getattr(self.optim_method, "learning_rate", None)
-                if sched is not None and base_lr is not None:
-                    # jitted optim state's neval counts from 0, host neval
-                    # from 1: the lr JUST applied was sched.lr(neval - 2)
-                    self.train_summary.add_scalar(
-                        "LearningRate",
-                        float(sched.lr(base_lr, max(0, state["neval"] - 2))),
-                        state["neval"] - 1,
+            # one iteration = one step of the profiler's step analysis;
+            # its phases below are series and profile events at once
+            # (Metrics.span). "computing time" keeps its meaning: dispatch
+            # to float(loss), with the next batch's fetch inside.
+            with self.metrics.span("train.iteration",
+                                   step_num=state["neval"]):
+                # input pipelining: the NEXT batch is fetched/placed while the
+                # dispatched (async) step still runs on the device; float(loss)
+                # is the only host sync point
+                if next_ready is None:
+                    try:
+                        next_ready = fetch()
+                    except StopIteration:
+                        logger.warning(
+                            "data iterator exhausted before end_when fired; "
+                            "stopping. (Possible causes: the iterator yields "
+                            "fewer batches than dataset.size() implies, or a "
+                            "directly-constructed stateful Trigger without a "
+                            "side-effect-free peek_fn.)")
+                        break
+                inp, tgt, bsz = next_ready
+                t0 = time.perf_counter()
+                # the LAUNCH of the step (with the batch's host-to-device
+                # copy where place_batch left it on the host): host time,
+                # the program's device time is the trace's jit_step
+                with self.metrics.span("train.dispatch", "dispatch time"):
+                    rng = jax.random.fold_in(base_key, state["neval"])
+                    params, opt_state, model_state, loss = step(
+                        params, opt_state, model_state, rng, inp, tgt,
                     )
-                if self.train_summary.should_record("Parameters", state):
-                    host = self._ckpt_params_to_host(params)
-                    for path, leaf in jax.tree_util.tree_flatten_with_path(
-                            host)[0]:
-                        tag = "Parameters/" + "/".join(
-                            getattr(k, "key", str(k)) for k in path)
-                        self.train_summary.add_histogram(
-                            tag, np.asarray(leaf), state["neval"] - 1)
+                # prefetch overlaps device compute — but only when the loop
+                # will actually run again, so finite/shared iterators never
+                # lose a batch to a discarded prefetch. The speculative state
+                # mirrors the counter updates below; loss-triggered stops
+                # can't be predicted pre-sync and may still prefetch once.
+                spec = dict(state)
+                spec["neval"] += 1
+                spec["epoch_finished"] = seen_this_epoch + bsz >= epoch_size
+                if spec["epoch_finished"]:
+                    spec["epoch"] += 1
+                if self.end_when.peek(spec):
+                    next_ready = None
+                else:
+                    try:
+                        next_ready = fetch()     # overlaps device compute
+                    except StopIteration:
+                        # finite custom iterators: end_when decides at loop top
+                        next_ready = None
+                # the loop's one sync: the host BLOCKED on the step
+                with self.metrics.span("train.loss_sync", "loss sync time"):
+                    loss_f = float(loss)
+                dt = time.perf_counter() - t0
+                self.metrics.add("computing time", dt)
+                self.metrics.add("records/second", bsz / max(dt, 1e-9))
+                state["loss"] = loss_f
+                state["neval"] += 1
+                self.optim_method.state["neval"] = state["neval"]
+                seen_this_epoch += bsz
+                state["seen"] = seen_this_epoch
 
-            if seen_this_epoch >= epoch_size:
-                state["epoch_finished"] = True
-                logger.info(
-                    "epoch %d done: %d records in %.1fs, last loss %.4f",
-                    state["epoch"], seen_this_epoch, time.time() - epoch_start, loss_f,
-                )
-                state["epoch"] += 1
-                self.optim_method.state["epoch"] = state["epoch"]
-                seen_this_epoch = 0
-                state["seen"] = 0
-                epoch_start = time.time()
+                if self.train_summary is not None:
+                    self.train_summary.add_scalar("Loss", loss_f, state["neval"] - 1)
+                    self.train_summary.add_scalar(
+                        "Throughput", bsz / max(dt, 1e-9), state["neval"] - 1
+                    )
+                    sched = getattr(self.optim_method, "learning_rate_schedule", None)
+                    base_lr = getattr(self.optim_method, "learning_rate", None)
+                    if sched is not None and base_lr is not None:
+                        # jitted optim state's neval counts from 0, host neval
+                        # from 1: the lr JUST applied was sched.lr(neval - 2)
+                        self.train_summary.add_scalar(
+                            "LearningRate",
+                            float(sched.lr(base_lr, max(0, state["neval"] - 2))),
+                            state["neval"] - 1,
+                        )
+                    if self.train_summary.should_record("Parameters", state):
+                        host = self._ckpt_params_to_host(params)
+                        for path, leaf in jax.tree_util.tree_flatten_with_path(
+                                host)[0]:
+                            tag = "Parameters/" + "/".join(
+                                getattr(k, "key", str(k)) for k in path)
+                            self.train_summary.add_histogram(
+                                tag, np.asarray(leaf), state["neval"] - 1)
 
-            if self.validation_trigger is not None and self.validation_trigger(state):
-                # device-layout params: DistriOptimizer overrides
-                # _eval_forward to evaluate SHARDED over the mesh instead of
-                # gathering to host and wasting N-1 chips (SURVEY §3.3)
-                score = self._run_validation(params, model_state, state)
-                if score is not None:
-                    state["score"] = score
-            if self.checkpoint_trigger is not None and self.checkpoint_trigger(state):
-                self._checkpoint(
-                    state, self._ckpt_params_to_host(params), model_state,
-                    self._ckpt_opt_state_to_host(opt_state),
-                )
+                if seen_this_epoch >= epoch_size:
+                    state["epoch_finished"] = True
+                    logger.info(
+                        "epoch %d done: %d records in %.1fs, last loss %.4f",
+                        state["epoch"], seen_this_epoch, time.time() - epoch_start, loss_f,
+                    )
+                    state["epoch"] += 1
+                    self.optim_method.state["epoch"] = state["epoch"]
+                    seen_this_epoch = 0
+                    state["seen"] = 0
+                    epoch_start = time.time()
+
+                if self.validation_trigger is not None and self.validation_trigger(state):
+                    # device-layout params: DistriOptimizer overrides
+                    # _eval_forward to evaluate SHARDED over the mesh instead of
+                    # gathering to host and wasting N-1 chips (SURVEY §3.3)
+                    score = self._run_validation(params, model_state, state)
+                    if score is not None:
+                        state["score"] = score
+                if self.checkpoint_trigger is not None and self.checkpoint_trigger(state):
+                    self._checkpoint(
+                        state, self._ckpt_params_to_host(params), model_state,
+                        self._ckpt_opt_state_to_host(opt_state),
+                    )
 
         if self._profile is not None and self._profile.get("active"):
             jax.profiler.stop_trace()  # loop ended inside the trace window

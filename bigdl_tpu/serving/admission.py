@@ -160,7 +160,7 @@ class AdmissionController:
         eng = self.engine
         slot = eng.pool.alloc()
         assert slot is not None                # admissible() checked
-        req = eng.scheduler.admit(slot, partial=partial)
+        req = eng._bind(slot, partial=partial)
         # the last fed token is the first decode input — exactly
         # generate()'s convention, so outputs match token-for-token.
         # Called BEFORE the resume check on purpose: its side effects
@@ -280,9 +280,11 @@ class AdmissionController:
             # prefill continues over the cached prefix, writing only
             # positions matched..len(pf)-1. NO completion fence (and no
             # phase timer — it would measure the launch, the ASY305
-            # lie): the suffix prefill overlaps the decode step under
-            # async dispatch, and the step's decode fence absorbs its
-            # completion (docs/async_readiness.md cashed-in entry).
+            # lie; the prefill step's own ``prefill.launch`` span is
+            # named for exactly that and feeds no series): the suffix
+            # prefill overlaps the decode step under async dispatch,
+            # and the step's decode fence absorbs its completion
+            # (docs/async_readiness.md cashed-in entry).
             _, out = eng._dispatch(
                 "prefill", eng._batch_prefill_fn, eng.params,
                 jnp.asarray(toks), np.asarray([S], np.int32), carry,
@@ -314,9 +316,11 @@ class AdmissionController:
         # NO completion fence, no phase timer: the bucket prefill is
         # the work async dispatch-ahead overlaps with the decode step —
         # the step's decode fence absorbs its completion, and a timer
-        # here would measure only the launch (the ASY305 lie). The
-        # PR 12 worksheet marked this site deletable
-        # (docs/async_readiness.md).
+        # here would measure only the launch (the ASY305 lie): the
+        # prefill step's own ``prefill.launch`` span says so in its
+        # name and feeds no series — the wave's device time is the
+        # jit_prefill program in the trace. The PR 12 worksheet marked
+        # this site deletable (docs/async_readiness.md).
         _, out = eng._dispatch("prefill", eng._batch_prefill_fn,
                                eng.params, jnp.asarray(toks), lengths,
                                self._zero_carry(),

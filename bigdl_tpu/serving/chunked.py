@@ -219,8 +219,9 @@ class ChunkedAdmissionController(AdmissionController):
         # dispatches and RETURNS — it overlaps the decode step (the
         # very overlap chunked admission exists to create) and the
         # step's decode fence absorbs its completion. A timer here
-        # would measure only the launch (the ASY305 lie); the PR 12
-        # worksheet marked this site deletable
+        # would measure only the launch (the ASY305 lie — the prefill
+        # step's own ``prefill.launch`` span is named for it and feeds
+        # no series); the PR 12 worksheet marked this site deletable
         # (docs/async_readiness.md).
         _, out = eng._dispatch("prefill", eng._batch_prefill_fn,
                                eng.params, jnp.asarray(toks),

@@ -553,9 +553,7 @@ class PrefillWorker:
                                   if e[0] > now]
                 for _, req in due:
                     eng.scheduler.submit(req)
-        eng._admit()
-        if eng.admitter is not None:
-            eng.admitter.pump()
+        eng._admit_and_pump()
         # sample occupancy at its per-pump PEAK — after admission,
         # BEFORE the completed rows release their slots (post-release
         # the batched pool is empty by construction, and a pool-sizing

@@ -64,6 +64,7 @@ cached prefill's own jit cache.
 
 from __future__ import annotations
 
+import contextlib
 from collections import deque
 from typing import Dict, List, Optional, Sequence
 
@@ -440,6 +441,12 @@ class ServingEngine:
         self.tier = tier or None
         self.scheduler = Scheduler(policy, tier=self.tier)
         self.metrics = metrics if metrics is not None else ServingMetrics()
+        # spans time on the engine's ONE clock, whoever built the metrics
+        self.metrics.metrics.clock = self._clock
+        # step counter and the request ids the current step bound: the
+        # serving.step / serving.admit spans' arguments
+        self._n_steps = 0
+        self._bound: List[int] = []
         if self.tier is not None:
             self.tier.attach_metrics(self.metrics, clock=self._clock)
         if self._plane is not None:
@@ -775,6 +782,22 @@ class ServingEngine:
         mismatched placements would recompile or silently gather."""
         return x if self._plane is None else self._plane.place_rows(x)
 
+    def _admit_and_pump(self) -> None:
+        """One super-step's admission — :meth:`_admit`, then the chunk
+        pump — under the ``admit`` span. Its series
+        (``serving/admit_host_s``) is HOST time, the launches of the
+        prefills and scatters and never their device time (that is in
+        the trace), and gets a sample only where a request was bound."""
+        self._bound.clear()
+        with self.metrics.span("admit", phase="admit_host") as sp:
+            self._admit()
+            if self.admitter is not None:
+                self.admitter.pump()
+            if self._bound:
+                sp.note(rids=" ".join(map(str, self._bound)))
+            else:
+                sp.drop()
+
     def _admit(self) -> None:
         import jax.numpy as jnp
 
@@ -896,7 +919,7 @@ class ServingEngine:
         for _ in range(n):
             slot = self.pool.alloc()
             assert slot is not None          # admissible() checked
-            req = self.scheduler.admit(slot)
+            req = self._bind(slot)
             # the last fed token is the first decode input — exactly
             # generate()'s convention, so outputs match token-for-token
             # (called before the resume check: next_token/degrade are
@@ -926,9 +949,10 @@ class ServingEngine:
             # work async dispatch-ahead overlaps with the decode step —
             # the step's one decode fence absorbs its completion, and
             # the per-phase prefill timer went with the wait (a timer
-            # here would measure the launch — the ASY305 lie). The
-            # PR 12 worksheet marked this site deletable
-            # (docs/async_readiness.md).
+            # here would measure the launch — the ASY305 lie; the
+            # prefill step's own ``prefill.launch`` span is named for
+            # exactly that, and feeds no series). The PR 12 worksheet
+            # marked this site deletable (docs/async_readiness.md).
             self.pool.write_prefill(slot, pc, len(pf))
         self._note_shard_balance()
 
@@ -1021,6 +1045,28 @@ class ServingEngine:
         req.degraded = False
         self.metrics.on_degrade_restored()
         return True
+
+    def _bind(self, slot: int, partial: bool = False) -> Request:
+        """THE slot binding, shared by every admission path: pop the
+        best waiting request into ``slot`` and record what it waited
+        for one (``serving/queue_wait_s``, on the engine clock from
+        ``submit_time`` — a re-admitted row counts again)."""
+        req = self.scheduler.admit(slot, partial=partial)
+        self.metrics.on_queue_wait(self._clock() - req.submit_time)
+        self._bound.append(req.req_id)
+        return req
+
+    def _kv_used_share(self) -> float:
+        """Resident K/V positions over the ``n_slots x max_len`` the
+        pool reserves, from host state alone (no readback): a decoding
+        row holds its prompt and everything emitted but the token it
+        feeds next; a mid-prefill row what the chunk pump has landed."""
+        sched = self.scheduler
+        used = sum(len(r.prompt) + len(r.output)
+                   for r in sched.running.values())
+        used += sum(int(self.pool.chunk_done[slot])
+                    for slot in sched.partial)
+        return used / (self.pool.n_slots * self.pool.max_len)
 
     def _admitted_prefill_tokens(self, req: Request) -> List[int]:
         """0-based tokens whose K/V must be resident before ``req``
@@ -1431,11 +1477,18 @@ class ServingEngine:
         emitted this step (the LAST emitted token per request when a
         super-step lands several; empty when the engine is idle or
         every slot-holding row is still mid-prefill)."""
+        return self._step_impl()
+
+    @contextlib.contextmanager
+    def _paired_host_step(self):
+        """The step's host/device split, as a context INSIDE the
+        ``step`` span (so that ``step()``'s own frame outlasts the span
+        by a call, not by this bookkeeping)."""
         t_step = self._clock()
         dev0 = self.metrics.device_seconds
         ndec0 = self.metrics.decode_step_count
         try:
-            return self._step_impl()
+            yield
         finally:
             # exactly one host/device split sample per decode/verify
             # dispatch sample — recovery paths included (a recovered
@@ -1515,63 +1568,64 @@ class ServingEngine:
         loss-free replay, and the REST of the window is discarded too
         (every newer dispatch chained through the poisoned carry)."""
         entry = self._window.popleft()
-        t_f = self._clock()
         # ONE batched fence readback per dispatch (THE declared
         # delayed-consumer site — fences.DELAYED_CONSUMER_SITES; the
         # (N, V) distribution never crosses to host, only token ids +
-        # chosen log-probs do). The t_f/now bracket is the fenced-wait
+        # chosen log-probs do). The span's bracket is the fenced-wait
         # sample: the time the host was genuinely BLOCKED here, the
         # DEVICE_PHASES half of the host_step split.
-        nxt, lps = fence("decode", entry.tok, entry.chosen)
-        now = self._clock()
-        self.metrics.add_phase("fence_wait", now - t_f)
-        # the watchdog's elapsed spans dispatch → readback landed; at
-        # W>0 that window covers host work on other in-flight steps
-        # too, and a stall fault's clock advance at dispatch time is
-        # inside it either way, so step_timeout_s keeps firing
-        elapsed = now - entry.t0
-        self.metrics.add_phase("decode_step", elapsed)
-        running = self.scheduler.running
-        rows = {slot: req for slot, req in entry.rows.items()
-                if running.get(slot) is req}
-        bad = self._step_unhealthy(nxt, lps, entry.active)
-        if bad is None and self._timed_out(elapsed):
-            bad = "timeout"
-        if bad is not None:
-            # outputs discarded; the pooled carry was committed at each
-            # dispatch only so the pool keeps valid (post-donation)
-            # buffers — every implicated row is evicted, so its bytes
-            # die with the slot. Newer in-flight dispatches chained
-            # through the poisoned carry: discard them unfenced. No gap
-            # sample either: a discarded step served no tokens, and the
-            # evicted batch anchors no future gap
-            self._window.clear()
-            self._recover_step(rows, bad)
-            self._last_decode_end = None
-            return False
-        self._warm = True                  # arms the watchdog timeout
-        # HEALTHY steps only: the decode-stall histogram measures gaps
-        # between dispatches that actually served the batch
-        self._note_decode_gap(entry.had_running)
-        # recency stamps feed the tier's cold-first victim selection:
-        # a row decoded this step is never the LRU preemption victim
-        self.scheduler.note_decoded(list(rows))
-        self.metrics.on_step(self.scheduler.queue_depth,
-                             self.pool.occupancy(),
-                             int(entry.active.sum()))
-        self.metrics.on_sample_rows(entry.n_sampled,
-                                    len(entry.rows) - entry.n_sampled)
-        for slot, req in list(rows.items()):
-            tok0 = int(nxt[slot])
-            reason = self._account_token(slot, req, tok0,
-                                         float(lps[slot]), now, emitted)
-            if reason is not None:
-                self._finish_row(req, reason, now)
-            else:
-                req.next_token = tok0
-                self._maybe_flip_ban(slot, req)
-                self._advance_constraint(slot, req)
-        return True
+        with self.metrics.span("consume"):
+            with self.metrics.span("fence", phase="fence_wait"):
+                nxt, lps = fence("decode", entry.tok, entry.chosen)
+            now = self._clock()
+            # the watchdog's elapsed spans dispatch → readback landed; at
+            # W>0 that window covers host work on other in-flight steps
+            # too, and a stall fault's clock advance at dispatch time is
+            # inside it either way, so step_timeout_s keeps firing
+            elapsed = now - entry.t0
+            self.metrics.add_phase("decode_step", elapsed)
+            running = self.scheduler.running
+            rows = {slot: req for slot, req in entry.rows.items()
+                    if running.get(slot) is req}
+            bad = self._step_unhealthy(nxt, lps, entry.active)
+            if bad is None and self._timed_out(elapsed):
+                bad = "timeout"
+            if bad is not None:
+                # outputs discarded; the pooled carry was committed at each
+                # dispatch only so the pool keeps valid (post-donation)
+                # buffers — every implicated row is evicted, so its bytes
+                # die with the slot. Newer in-flight dispatches chained
+                # through the poisoned carry: discard them unfenced. No gap
+                # sample either: a discarded step served no tokens, and the
+                # evicted batch anchors no future gap
+                self._window.clear()
+                self._recover_step(rows, bad)
+                self._last_decode_end = None
+                return False
+            self._warm = True                  # arms the watchdog timeout
+            # HEALTHY steps only: the decode-stall histogram measures gaps
+            # between dispatches that actually served the batch
+            self._note_decode_gap(entry.had_running)
+            # recency stamps feed the tier's cold-first victim selection:
+            # a row decoded this step is never the LRU preemption victim
+            self.scheduler.note_decoded(list(rows))
+            self.metrics.on_step(self.scheduler.queue_depth,
+                                 self.pool.occupancy(),
+                                 int(entry.active.sum()),
+                                 kv_used_share=self._kv_used_share())
+            self.metrics.on_sample_rows(entry.n_sampled,
+                                        len(entry.rows) - entry.n_sampled)
+            for slot, req in list(rows.items()):
+                tok0 = int(nxt[slot])
+                reason = self._account_token(slot, req, tok0,
+                                             float(lps[slot]), now, emitted)
+                if reason is not None:
+                    self._finish_row(req, reason, now)
+                else:
+                    req.next_token = tok0
+                    self._maybe_flip_ban(slot, req)
+                    self._advance_constraint(slot, req)
+            return True
 
     def _drain_window(self, emitted: Dict[int, int]) -> bool:
         """Flush every in-flight dispatch through the delayed consumer,
@@ -1602,127 +1656,140 @@ class ServingEngine:
     def _step_impl(self) -> Dict[int, int]:
         import jax.numpy as jnp
 
-        emitted: Dict[int, int] = {}
-        had_running = bool(self.scheduler.running)
-        self._admit()
-        if self.admitter is not None:
-            self.admitter.pump()
-        running = self.scheduler.running
-        if not running:
-            # nothing to dispatch: flush any leftover in-flight work
-            # first (rows that finished out from under the window —
-            # the consumer's row filter discards their readbacks),
-            # then report idle. No decode dispatch this step: a gap
-            # measured across an empty batch would be idle time, not
-            # a stall
-            self._drain_window(emitted)
-            self._last_decode_end = None
-            return emitted
-        if self._spec is not None:
-            slots = list(running)
-            out = self._spec.step(running)
-            # a healthy super-step emits for every running row; an
-            # empty dict here means the step faulted and recovery
-            # evicted the batch — no dispatch completed, so there is
-            # no gap sample and no live batch to anchor the next one
-            if out:
-                self._note_decode_gap(had_running)
-                self.scheduler.note_decoded(slots)
-            else:
-                self._last_decode_end = None
-            return out
-        if self._window_open(running):
-            # STEADY-STATE window extension: nothing the in-flight
-            # dispatches assumed changed, so the next dispatch chains
-            # directly on the newest dispatch's device token handle —
-            # exactly the value its delayed consumer will set
-            # req.next_token to — and reuses its placed active mask.
-            # No host→device token upload, no fence, no readback: the
-            # device stays fed while step N-W's readback is in flight.
-            prev = self._window[-1]
-            tokens_dev = prev.tok
-            active = prev.active
-            active_dev = prev.active_dev
-            rows = dict(prev.rows)
-            n_sampled = prev.n_sampled
-        else:
-            # the window's assumptions broke (admission, finish, evict,
-            # knob change) or it is empty: flush everything in flight
-            # through the delayed consumer, then dispatch classically
-            # from host-built token rows
-            if not self._drain_window(emitted):
-                # a flushed entry was unhealthy — recovery evicted the
-                # batch and discarded the window; nothing to dispatch
-                return emitted
-            running = self.scheduler.running   # a flush may finish rows
+        # the span wraps the BODY, not the call: on the profile's python3
+        # line this function's own frame is then the longer of the two,
+        # and a device idle gap is put down to the span, a name that
+        # survives an edit, not to a file-and-line frame
+        self._n_steps += 1
+        with self.metrics.span("step", step=self._n_steps), \
+                self._paired_host_step():
+            emitted: Dict[int, int] = {}
+            had_running = bool(self.scheduler.running)
+            self._admit_and_pump()
+            running = self.scheduler.running
             if not running:
+                # nothing to dispatch: flush any leftover in-flight work
+                # first (rows that finished out from under the window —
+                # the consumer's row filter discards their readbacks),
+                # then report idle. No decode dispatch this step: a gap
+                # measured across an empty batch would be idle time, not
+                # a stall
+                self._drain_window(emitted)
                 self._last_decode_end = None
                 return emitted
-            N = self.pool.n_slots
-            tokens = np.zeros((N,), np.int32)
-            active = np.zeros((N,), bool)
-            n_sampled = 0
-            for slot, req in list(running.items()):
-                if slot not in self._configured:
-                    try:
-                        self._configure_slot(slot, req)
-                    except FaultError:
-                        # slot configuration dispatches device work (the
-                        # speculative draft prefill) — a fault there
-                        # evicts exactly this row for loss-free replay;
-                        # the rest of the batch decodes without it
-                        self._recover_admission([(slot, req)])
-                        continue
-                tokens[slot] = req.next_token
-                active[slot] = True
-                n_sampled += not req.sampling.is_greedy
-            if not active.any():
+            if self._spec is not None:
+                slots = list(running)
+                out = self._spec.step(running)
+                # a healthy super-step emits for every running row; an
+                # empty dict here means the step faulted and recovery
+                # evicted the batch — no dispatch completed, so there is
+                # no gap sample and no live batch to anchor the next one
+                if out:
+                    self._note_decode_gap(had_running)
+                    self.scheduler.note_decoded(slots)
+                else:
+                    self._last_decode_end = None
+                return out
+            if self._window_open(running):
+                # STEADY-STATE window extension: nothing the in-flight
+                # dispatches assumed changed, so the next dispatch chains
+                # directly on the newest dispatch's device token handle —
+                # exactly the value its delayed consumer will set
+                # req.next_token to — and reuses its placed active mask.
+                # No host→device token upload, no fence, no readback: the
+                # device stays fed while step N-W's readback is in flight.
+                prev = self._window[-1]
+                tokens_dev = prev.tok
+                active = prev.active
+                active_dev = prev.active_dev
+                rows = dict(prev.rows)
+                n_sampled = prev.n_sampled
+            else:
+                # the window's assumptions broke (admission, finish, evict,
+                # knob change) or it is empty: flush everything in flight
+                # through the delayed consumer, then dispatch classically
+                # from host-built token rows
+                if not self._drain_window(emitted):
+                    # a flushed entry was unhealthy — recovery evicted the
+                    # batch and discarded the window; nothing to dispatch
+                    return emitted
+                running = self.scheduler.running   # a flush may finish rows
+                if not running:
+                    self._last_decode_end = None
+                    return emitted
+                # host-built token rows, slot configuration (the sampling
+                # lanes' pool writes) and their uploads
+                with self.metrics.span("decode.build"):
+                    N = self.pool.n_slots
+                    tokens = np.zeros((N,), np.int32)
+                    active = np.zeros((N,), bool)
+                    n_sampled = 0
+                    for slot, req in list(running.items()):
+                        if slot not in self._configured:
+                            try:
+                                self._configure_slot(slot, req)
+                            except FaultError:
+                                # slot configuration dispatches device work
+                                # (the speculative draft prefill) — a fault
+                                # there evicts exactly this row for loss-free
+                                # replay; the rest of the batch decodes
+                                # without it
+                                self._recover_admission([(slot, req)])
+                                continue
+                        tokens[slot] = req.next_token
+                        active[slot] = True
+                        n_sampled += not req.sampling.is_greedy
+                    if not active.any():
+                        self._last_decode_end = None
+                        return emitted
+                    tokens_dev = self._place_rows(jnp.asarray(tokens))
+                    active_dev = self._place_rows(jnp.asarray(active))
+                    rows = {slot: req for slot, req in running.items()
+                            if active[slot]}
+            t0 = self._clock()
+            try:
+                # the LAUNCH of the knob upload and the decode dispatch; the
+                # program's device time is the trace's (and, fenced, t0's)
+                with self.metrics.span("decode.launch"):
+                    if self._knobs_device is None:
+                        self._knobs_device = {
+                            k: self._place_rows(jnp.asarray(v))
+                            for k, v in self._knobs.items()}
+                    knobs = self._knobs_device
+                    tok, chosen, carry = self._dispatch(
+                        "decode", self._step_fn,
+                        self.params, tokens_dev, active_dev,
+                        self.pool.carry, knobs, *self._adapter_args())
+            except FaultError:
+                # the dispatch failed BEFORE running: the pooled carry was
+                # never donated and stays valid. Everything already in the
+                # window was dispatched BEFORE the fault and is healthy —
+                # flush it through the delayed consumer (its tokens are
+                # real), THEN evict + replay whatever rows remain (no gap
+                # sample for the failed dispatch: nothing dispatched, and
+                # the evicted batch anchors no future gap)
+                self._drain_window(emitted)
+                self._recover_step(self.scheduler.running, "fail")
                 self._last_decode_end = None
                 return emitted
-            tokens_dev = self._place_rows(jnp.asarray(tokens))
-            active_dev = self._place_rows(jnp.asarray(active))
-            rows = {slot: req for slot, req in running.items()
-                    if active[slot]}
-        t0 = self._clock()
-        if self._knobs_device is None:
-            self._knobs_device = {k: self._place_rows(jnp.asarray(v))
-                                  for k, v in self._knobs.items()}
-        knobs = self._knobs_device
-        try:
-            tok, chosen, carry = self._dispatch(
-                "decode", self._step_fn,
-                self.params, tokens_dev, active_dev,
-                self.pool.carry, knobs, *self._adapter_args())
-        except FaultError:
-            # the dispatch failed BEFORE running: the pooled carry was
-            # never donated and stays valid. Everything already in the
-            # window was dispatched BEFORE the fault and is healthy —
-            # flush it through the delayed consumer (its tokens are
-            # real), THEN evict + replay whatever rows remain (no gap
-            # sample for the failed dispatch: nothing dispatched, and
-            # the evicted batch anchors no future gap)
-            self._drain_window(emitted)
-            self._recover_step(self.scheduler.running, "fail")
-            self._last_decode_end = None
+            self.pool.carry = carry
+            # the (N, V) distribution never crosses to host — sampling is
+            # fused into the step; only token ids + chosen log-probs will,
+            # through ONE batched fence readback at this entry's DELAYED
+            # consumption (_consume_window — THE declared delayed-consumer
+            # site, serving/fences.py). t0 rides the entry so the
+            # watchdog's elapsed covers the device work, not the launch
+            self._window.append(_InFlight(tok, chosen, active, active_dev,
+                                          rows, t0, n_sampled, had_running))
+            # delayed consumer: fence the oldest entry once the window
+            # exceeds its DECLARED depth knob (fences.WINDOW_KNOBS —
+            # ASY308 rejects any other bound). dispatch_ahead=0 consumes
+            # the entry just appended: dispatch-then-fence within one
+            # step, byte-for-byte the pre-window engine
+            while len(self._window) > self.dispatch_ahead:
+                if not self._consume_window(emitted):
+                    break
             return emitted
-        self.pool.carry = carry
-        # the (N, V) distribution never crosses to host — sampling is
-        # fused into the step; only token ids + chosen log-probs will,
-        # through ONE batched fence readback at this entry's DELAYED
-        # consumption (_consume_window — THE declared delayed-consumer
-        # site, serving/fences.py). t0 rides the entry so the
-        # watchdog's elapsed covers the device work, not the launch
-        self._window.append(_InFlight(tok, chosen, active, active_dev,
-                                      rows, t0, n_sampled, had_running))
-        # delayed consumer: fence the oldest entry once the window
-        # exceeds its DECLARED depth knob (fences.WINDOW_KNOBS —
-        # ASY308 rejects any other bound). dispatch_ahead=0 consumes
-        # the entry just appended: dispatch-then-fence within one
-        # step, byte-for-byte the pre-window engine
-        while len(self._window) > self.dispatch_ahead:
-            if not self._consume_window(emitted):
-                break
-        return emitted
 
     def drain(self) -> Dict[int, np.ndarray]:
         """Step until every submitted request has finished; returns

@@ -96,6 +96,45 @@ DELAYED_CONSUMER_SITES = frozenset({
 })
 
 
+#: THE closed span vocabulary of the serving plane (``ServingMetrics.
+#: span`` / ``metrics.span`` raise on anything else, the FENCE_SITES
+#: pattern): the names a profile of a serve carries on the dispatching
+#: thread's line, each ``serving.<name>``. The benchmark attributes every
+#: device idle gap to the innermost of these covering it, so a name here
+#: is part of the yardstick — renaming one breaks the comparison of one
+#: PR's ``breakdown`` with the next.
+#:
+#: A span is HOST time. One that brackets an un-fenced dispatch ends in
+#: ``.launch`` (or its series in ``_host_s``) and measures the enqueue,
+#: by design: ASY305's point stands, device time comes from the trace.
+#: Only ``fence`` blocks on the device (its series: ``fence_wait_s``).
+#: A span wraps the BODY of the function it names, never the call to
+#: it: the function's own frame on the profile's python3 line is then
+#: the longer event, and a reader that attributes an idle gap to the
+#: shortest event covering it lands on the span.
+SPAN_NAMES = frozenset({
+    "step",            # one ServingEngine.step(): the body of
+                       # _step_impl (step=<n>)
+    "admit",           # _admit + the chunk pump (rids= of the requests
+                       # it bound; series admit_host_s on steps that
+                       # bound >= 1)
+    "prefill.launch",  # one prefill dispatch — bucket, prefix suffix,
+                       # chunk, per-request: the body of the prefill
+                       # steps' host wrappers (models/transformer.py
+                       # prefill_checked; rows=, padded=, bucket=)
+    "pool.write",      # KVPool.write_prefill / write_sampling /
+                       # restore_row / free: host rows + the launches
+                       # of the scatters and resets
+    "decode.build",    # host-built token/active rows, slot
+                       # configuration, their uploads
+    "decode.launch",   # the knob upload + the decode dispatch
+    "consume",         # the delayed consumer (_consume_window): its
+                       # fence, then the per-token bookkeeping
+    "fence",           # THE blocked wait inside it: the decode/verify
+                       # readback (series fence_wait_s)
+})
+
+
 def _check_site(site: str) -> None:
     if site not in FENCE_SITES:
         raise ValueError(

@@ -17,6 +17,8 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional
 
+from bigdl_tpu.serving.metrics import span
+
 _FREE_RESET = None
 
 
@@ -228,20 +230,22 @@ class KVPool:
         # covers pos + all scale rows (see _make_free_reset).
         import jax.numpy as jnp
 
-        self.carry.update(self._free_reset(
-            {k: self.carry[k] for k in self._reset_keys},
-            jnp.int32(slot)))
-        # chunk-progress fields reset with the slot (recycled-slot
-        # contract): a leaked done/target pair would make the next
-        # occupant look mid-prefill
-        self.chunk_done[slot] = 0
-        self.chunk_target[slot] = 0
-        self.adapter_ids[slot] = 0
-        if self.draft_carry is not None:
-            # the draft carry frees WITH its slot: same pos-reset rule
-            # (stale draft K/V behind pos are masked, like the target's)
-            self.draft_carry.update(self._draft_reset(
-                {"pos": self.draft_carry["pos"]}, jnp.int32(slot)))
+        with span("pool.write"):
+            self.carry.update(self._free_reset(
+                {k: self.carry[k] for k in self._reset_keys},
+                jnp.int32(slot)))
+            # chunk-progress fields reset with the slot (recycled-slot
+            # contract): a leaked done/target pair would make the next
+            # occupant look mid-prefill
+            self.chunk_done[slot] = 0
+            self.chunk_target[slot] = 0
+            self.adapter_ids[slot] = 0
+            if self.draft_carry is not None:
+                # the draft carry frees WITH its slot: same pos-reset
+                # rule (stale draft K/V behind pos are masked, like the
+                # target's)
+                self.draft_carry.update(self._draft_reset(
+                    {"pos": self.draft_carry["pos"]}, jnp.int32(slot)))
 
     @property
     def free_slots(self) -> int:
@@ -296,9 +300,12 @@ class KVPool:
             raise ValueError(
                 f"row {row} outside the prefill carry's "
                 f"{prefill_carry['pos'].shape[0]} rows")
-        self.carry = self._scatter(self.carry, prefill_carry,
-                                   jnp.int32(slot), jnp.int32(prompt_len),
-                                   jnp.int32(row))
+        # the donated scatter's LAUNCH (host time; its device time is
+        # the jit__scatter_impl program in the trace)
+        with span("pool.write"):
+            self.carry = self._scatter(
+                self.carry, prefill_carry, jnp.int32(slot),
+                jnp.int32(prompt_len), jnp.int32(row))
         # host mirror of the slot's device pos: the chunk pump plans
         # the next chunk from this without a device readback
         self.chunk_done[slot] = prompt_len
@@ -390,31 +397,32 @@ class KVPool:
 
         if slot not in self._in_use:
             raise ValueError(f"slot {slot} is not allocated")
-        carry = payload["carry"]
-        # one donated scatter restores K/V + scales and sets pos from
-        # the payload's own (traced) value
-        self.carry = self._scatter(
-            self.carry, carry, jnp.int32(slot),
-            jnp.asarray(carry["pos"])[0], jnp.int32(0))
-        # sampling lanes ride the payload (write_sampling's leaves):
-        # restored verbatim, not rebuilt — the handoff receiver must
-        # reproduce the sender's lane state without knowing its seed
-        for key in ("rng", "tok_counts", "prompt_mask"):
-            if key in carry and key in self.carry:
-                self.carry[key] = self.carry[key].at[slot].set(
-                    jnp.asarray(carry[key])[0])
-        # host mirrors from the payload's own values (SRV203 lockstep):
-        # a completed prefill hands off done == pos, target == 0 or pos
-        self.chunk_done[slot] = int(payload["chunk_done"])
-        self.chunk_target[slot] = int(payload["chunk_target"])
-        # adapter id rides the payload (absent in pre-adapter payloads
-        # → null adapter, today's behavior)
-        self.adapter_ids[slot] = int(payload.get("adapter", 0))
-        draft = payload.get("draft")
-        if draft is not None and self.draft_carry is not None:
-            self.draft_carry = self._draft_scatter(
-                self.draft_carry, draft, jnp.int32(slot),
-                jnp.asarray(draft["pos"])[0], jnp.int32(0))
+        with span("pool.write"):
+            carry = payload["carry"]
+            # one donated scatter restores K/V + scales and sets pos from
+            # the payload's own (traced) value
+            self.carry = self._scatter(
+                self.carry, carry, jnp.int32(slot),
+                jnp.asarray(carry["pos"])[0], jnp.int32(0))
+            # sampling lanes ride the payload (write_sampling's leaves):
+            # restored verbatim, not rebuilt — the handoff receiver must
+            # reproduce the sender's lane state without knowing its seed
+            for key in ("rng", "tok_counts", "prompt_mask"):
+                if key in carry and key in self.carry:
+                    self.carry[key] = self.carry[key].at[slot].set(
+                        jnp.asarray(carry[key])[0])
+            # host mirrors from the payload's own values (SRV203 lockstep):
+            # a completed prefill hands off done == pos, target == 0 or pos
+            self.chunk_done[slot] = int(payload["chunk_done"])
+            self.chunk_target[slot] = int(payload["chunk_target"])
+            # adapter id rides the payload (absent in pre-adapter payloads
+            # → null adapter, today's behavior)
+            self.adapter_ids[slot] = int(payload.get("adapter", 0))
+            draft = payload.get("draft")
+            if draft is not None and self.draft_carry is not None:
+                self.draft_carry = self._draft_scatter(
+                    self.draft_carry, draft, jnp.int32(slot),
+                    jnp.asarray(draft["pos"])[0], jnp.int32(0))
 
     # -- chunk progress (chunked streaming admission) ----------------------
 
@@ -468,23 +476,25 @@ class KVPool:
                 "from make_batch_decode_step(..., sampling=True)")
         if slot not in self._in_use:
             raise ValueError(f"slot {slot} is not allocated")
-        V = self.carry["tok_counts"].shape[1]
-        mask = np.zeros((V,), bool)
-        if len(prompt_ids):
-            mask[np.clip(np.asarray(prompt_ids, np.int64) - 1,
-                         0, V - 1)] = True
-        counts = np.zeros((V,), np.int32)
-        if len(output_ids):
-            ids, reps = np.unique(
-                np.clip(np.asarray(output_ids, np.int64) - 1, 0, V - 1),
-                return_counts=True)
-            counts[ids] = reps
-        self.carry["rng"] = self.carry["rng"].at[slot].set(
-            jnp.asarray(key, jnp.uint32))
-        self.carry["tok_counts"] = self.carry["tok_counts"].at[slot].set(
-            jnp.asarray(counts))
-        self.carry["prompt_mask"] = self.carry["prompt_mask"].at[slot].set(
-            jnp.asarray(mask))
+        # the (V,) host rows and their three row-set launches
+        with span("pool.write"):
+            V = self.carry["tok_counts"].shape[1]
+            mask = np.zeros((V,), bool)
+            if len(prompt_ids):
+                mask[np.clip(np.asarray(prompt_ids, np.int64) - 1,
+                             0, V - 1)] = True
+            counts = np.zeros((V,), np.int32)
+            if len(output_ids):
+                ids, reps = np.unique(
+                    np.clip(np.asarray(output_ids, np.int64) - 1, 0, V - 1),
+                    return_counts=True)
+                counts[ids] = reps
+            self.carry["rng"] = self.carry["rng"].at[slot].set(
+                jnp.asarray(key, jnp.uint32))
+            self.carry["tok_counts"] = self.carry["tok_counts"].at[slot].set(
+                jnp.asarray(counts))
+            self.carry["prompt_mask"] = self.carry["prompt_mask"].at[slot].set(
+                jnp.asarray(mask))
 
     # -- draft carry (speculative decoding) --------------------------------
 

@@ -19,8 +19,44 @@ Counters (all under the ``serving/`` prefix in the backing Metrics):
   (prefill dispatches are no longer completion-fenced — they overlap
   the decode step and their device time lands inside this window; the
   former ``prefill_s``/``draft_prefill_s`` phase timers went with the
-  fences, see docs/async_readiness.md)
+  fences, see docs/async_readiness.md — a prefill's HOST side is the
+  ``prefill.launch`` span below, its device time is the ``jit_prefill``
+  program in the trace)
 * ``cancelled``         — requests cancelled while WAITING
+* ``queue_wait_s``      — per slot binding, the engine clock at the
+  binding minus ``submit_time`` (re-admissions included): what a request
+  waited for a slot, apart from the prefill that follows
+* ``kv_used_share``     — sampled every engine step: resident K/V
+  positions (sum of ``pos`` over in-use slots, from host state — no
+  readback) over the ``n_slots x max_len`` the pool reserves
+* ``fence_wait_s``      — the time the host was BLOCKED in the step's
+  one fence readback (span ``fence``; the ``DEVICE_PHASES`` half of the
+  ``host_step_s`` split)
+* ``admit_host_s``      — HOST time of admission (span ``admit``: the
+  scheduler, slot binding, prefill and scatter LAUNCHES, the chunk
+  pump), recorded only for steps that bound >= 1 request. Launch time by
+  design: the prefill's device time is in the trace
+
+Spans (:meth:`ServingMetrics.span`; the closed vocabulary is
+``fences.SPAN_NAMES``). Each is a ``jax.profiler.TraceAnnotation`` named
+``serving.<name>`` on the dispatching thread's line of a running profile
+— near free otherwise — and the ones with a series above also record
+it, from the same bracket, on the engine's clock:
+
+    serving.step (step=)
+      serving.admit (rids=)            -> admit_host_s
+        serving.prefill.launch (rows=, padded=, bucket=)
+        serving.pool.write
+      serving.decode.build             (also holds serving.pool.write:
+                                        write_sampling at configuration)
+      serving.decode.launch
+      serving.consume                  (the delayed consumer; a finished
+        serving.fence                  -> fence_wait_s   row's slot reset is
+                                        a serving.pool.write in it)
+
+The profile ``stop_trace`` writes is the span record; there is no
+in-memory span log. ``benchmark/span_reduce.py`` puts every device idle
+gap down to the innermost of these covering it.
 
 Chunked-admission counters (``serving/chunked.py``):
 
@@ -160,7 +196,20 @@ from __future__ import annotations
 import time
 from typing import Dict, List, Optional
 
-from bigdl_tpu.optim.metrics import Metrics
+from bigdl_tpu.optim.metrics import Metrics, Span
+from bigdl_tpu.serving.fences import SPAN_NAMES
+
+
+def span(name: str, clock=None, record=None, key=None, **ids) -> Span:
+    """A span of the closed serving vocabulary (``fences.SPAN_NAMES``),
+    ``serving.<name>`` in the profile. Bare, as the KV pool uses it, it
+    is a profile event only; :meth:`ServingMetrics.span` adds the phase
+    series."""
+    if name not in SPAN_NAMES:
+        raise ValueError(
+            f"unknown span {name!r} — add it to fences.SPAN_NAMES "
+            f"first; known: {sorted(SPAN_NAMES)}")
+    return Span(f"serving.{name}", clock, record, key, ids)
 
 
 class ServingMetrics:
@@ -224,8 +273,14 @@ class ServingMetrics:
     def on_submit(self) -> None:
         self.metrics.add("serving/submitted", 1.0)
 
+    def on_queue_wait(self, seconds: float) -> None:
+        """One slot binding: the engine clock at the binding minus the
+        request's ``submit_time`` — re-admissions (preempted or
+        fault-evicted rows seated again) count again."""
+        self.metrics.add("serving/queue_wait_s", float(seconds))
+
     def on_step(self, queue_depth: int, occupancy: float,
-                batch_active: int) -> None:
+                batch_active: int, kv_used_share: float) -> None:
         # a declared CLOCK_SITES unit (serving/faults.py): the serve-
         # duration anchor timestamps (_t_start/_t_last span the whole
         # serve for summary()'s wall number) deliberately read the raw
@@ -239,6 +294,7 @@ class ServingMetrics:
         self.metrics.add("serving/queue_depth", float(queue_depth))
         self.metrics.add("serving/slot_occupancy", float(occupancy))
         self.metrics.add("serving/batch_active", float(batch_active))
+        self.metrics.add("serving/kv_used_share", float(kv_used_share))
 
     def on_first_token(self, ttft_s: float) -> None:
         self.metrics.add("serving/ttft_s", float(ttft_s))
@@ -586,6 +642,16 @@ class ServingMetrics:
     #: land (the service-time estimator and the step windows read
     #: them); they just stop feeding ``device_seconds``.
     DEVICE_PHASES = frozenset({"fence_wait", "draft"})
+
+    def span(self, name: str, phase: Optional[str] = None, **ids) -> Span:
+        """``with metrics.span("fence", phase="fence_wait"):`` — one
+        ``serving.<name>`` event in a running profile and, with
+        ``phase``, one :meth:`add_phase` sample of the bracket's
+        duration on the backing Metrics' clock (the engine's): the
+        series and the profile bracket the same code, and the
+        ``DEVICE_PHASES`` / host_step bookkeeping is ``add_phase``'s
+        own."""
+        return span(name, self.metrics.clock, self.add_phase, phase, **ids)
 
     def add_phase(self, name: str, seconds: float) -> None:
         self.metrics.add(f"serving/{name}_s", float(seconds))

@@ -381,13 +381,11 @@ class Speculator:
         # structurally 0 — fences.DELAYED_CONSUMER_SITES): next
         # super-step's draft budgets are a host decision made from
         # THIS readback, so there is nothing to dispatch ahead of it.
-        # The t_f bracket is the fenced-wait sample — the blocked half
-        # of the host_step split (metrics.DEVICE_PHASES)
-        t_f = eng._clock()
-        nxt, lps, nem = fence("verify", vt, vlp, n_emit)
-        now_f = eng._clock()
-        eng.metrics.add_phase("fence_wait", now_f - t_f)
-        eng.metrics.add_phase("decode_step", now_f - t0)
+        # The span's bracket is the fenced-wait sample — the blocked
+        # half of the host_step split (metrics.DEVICE_PHASES)
+        with eng.metrics.span("fence", phase="fence_wait"):
+            nxt, lps, nem = fence("verify", vt, vlp, n_emit)
+        eng.metrics.add_phase("decode_step", eng._clock() - t0)
         bad = self._chunk_unhealthy(nxt, lps, nem, lengths, active)
         if bad is None and eng._timed_out(eng._clock() - t_start):
             bad = "timeout"
@@ -413,7 +411,8 @@ class Speculator:
         eng.pool.draft_carry = dcarry
 
         eng.metrics.on_step(eng.scheduler.queue_depth,
-                            eng.pool.occupancy(), int(active.sum()))
+                            eng.pool.occupancy(), int(active.sum()),
+                            kv_used_share=eng._kv_used_share())
         eng.metrics.on_sample_rows(n_sampled, len(running) - n_sampled)
 
         # emission: the baseline per-token accounting, applied to each

@@ -322,13 +322,13 @@ def test_window_census_stale_consumer_detected(tmp_path):
     tree = _serving_tree(tmp_path)
     _mutate(
         tree,
-        "                self._advance_constraint(slot, req)\n"
-        "        return True\n",
-        "                self._advance_constraint(slot, req)\n"
-        "        self._dispatch(\"decode\", self._step_fn, self.params,\n"
-        "                       jnp.asarray(nxt), entry.active_dev,\n"
-        "                       self.pool.carry, self._knobs_device)\n"
-        "        return True\n")
+        "                    self._advance_constraint(slot, req)\n"
+        "            return True\n",
+        "                    self._advance_constraint(slot, req)\n"
+        "            self._dispatch(\"decode\", self._step_fn, self.params,\n"
+        "                           jnp.asarray(nxt), entry.active_dev,\n"
+        "                           self.pool.carry, self._knobs_device)\n"
+        "            return True\n")
     found = _scan(tmp_path)
     assert [f.code for f in found] == ["ASY306"], (
         [f.format() for f in found])
@@ -341,12 +341,12 @@ def test_window_census_literal_depth_detected(tmp_path):
     tree = _serving_tree(tmp_path)
     _mutate(
         tree,
-        "        while len(self._window) > self.dispatch_ahead:\n"
-        "            if not self._consume_window(emitted):\n"
-        "                break\n",
-        "        while len(self._window) > 2:\n"
-        "            if not self._consume_window(emitted):\n"
-        "                break\n")
+        "            while len(self._window) > self.dispatch_ahead:\n"
+        "                if not self._consume_window(emitted):\n"
+        "                    break\n",
+        "            while len(self._window) > 2:\n"
+        "                if not self._consume_window(emitted):\n"
+        "                    break\n")
     found = _scan(tmp_path)
     assert [f.code for f in found] == ["ASY308"], (
         [f.format() for f in found])
@@ -359,9 +359,9 @@ def test_window_census_inwindow_fence_detected(tmp_path):
     tree = _serving_tree(tmp_path)
     _mutate(
         tree,
-        "        self.pool.carry = carry\n",
-        "        self.pool.carry = carry\n"
-        "        nxt0, lps0 = fence(\"verify\", tok, chosen)\n")
+        "            self.pool.carry = carry\n",
+        "            self.pool.carry = carry\n"
+        "            nxt0, lps0 = fence(\"verify\", tok, chosen)\n")
     found = _scan(tmp_path)
     assert [f.code for f in found] == ["ASY309"], (
         [f.format() for f in found])
@@ -369,22 +369,19 @@ def test_window_census_inwindow_fence_detected(tmp_path):
 
 
 def test_window_census_clock_blind_consumer_detected(tmp_path):
-    """Stripping the consumer's clock bracket (constants instead of
-    engine-clock reads) blinds the timers AND the watchdog -> exactly
-    one ASY310 at the deferred fence."""
+    """Stripping the consumer's clock read (a constant instead of the
+    engine-clock read after the fence; the fence_wait bracket is the
+    ``fence`` span's) blinds the watchdog's elapsed -> exactly one
+    ASY310 at the deferred fence."""
     tree = _serving_tree(tmp_path)
     _mutate(
         tree,
-        "        entry = self._window.popleft()\n"
-        "        t_f = self._clock()\n",
-        "        entry = self._window.popleft()\n"
-        "        t_f = 0.0\n")
-    _mutate(
-        tree,
-        "        nxt, lps = fence(\"decode\", entry.tok, entry.chosen)\n"
-        "        now = self._clock()\n",
-        "        nxt, lps = fence(\"decode\", entry.tok, entry.chosen)\n"
-        "        now = 0.0\n")
+        "                nxt, lps = fence(\"decode\", entry.tok, "
+        "entry.chosen)\n"
+        "            now = self._clock()\n",
+        "                nxt, lps = fence(\"decode\", entry.tok, "
+        "entry.chosen)\n"
+        "            now = 0.0\n")
     found = _scan(tmp_path)
     assert [f.code for f in found] == ["ASY310"], (
         [f.format() for f in found])

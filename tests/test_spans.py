@@ -1,0 +1,376 @@
+"""The one span facility (``optim.Metrics.span`` / ``ServingMetrics.span``):
+a bracket is a series sample and a profile event at once, the serving
+plane's span names are a closed vocabulary, the training loop splits an
+iteration into three phases of equal count, and the Pallas kernels and
+jitted programs carry the names the benchmark's readers look for."""
+
+import json
+import pathlib
+
+import numpy as np
+import pytest
+
+ROOT = pathlib.Path(__file__).resolve().parent.parent
+
+
+class _Recorder:
+    """Stands in for ``TraceAnnotation``: keeps (name, args, depth) of
+    every span entered, in order, and what ``note`` added later."""
+
+    def __init__(self):
+        self.events, self.depth = [], 0
+
+    def __call__(self, name, **ids):
+        rec = self
+
+        class _Ann:
+            def __enter__(self):
+                self.ev = [name, dict(ids), rec.depth]
+                rec.events.append(self.ev)
+                rec.depth += 1
+
+            def set_metadata(self, **more):
+                self.ev[1].update(more)
+
+            def __exit__(self, *exc):
+                rec.depth -= 1
+
+        return _Ann()
+
+    def names(self):
+        return [e[0] for e in self.events]
+
+
+@pytest.fixture
+def recorder(monkeypatch):
+    import bigdl_tpu.optim.metrics as m
+
+    rec = _Recorder()
+    monkeypatch.setattr(m, "TraceAnnotation", rec)
+    monkeypatch.setattr(m, "StepTraceAnnotation", rec)
+    return rec
+
+
+# -- Metrics.span -------------------------------------------------------------
+
+def test_span_records_series_on_the_metrics_clock_and_nests(recorder):
+    from bigdl_tpu.optim.metrics import Metrics
+
+    t = [0.0]
+    m = Metrics(clock=lambda: t[0])
+    with m.span("outer", "outer time", step_num=7):
+        t[0] += 1.0
+        with m.span("inner", "inner time", rid=3):
+            t[0] += 0.25
+        with m.span("bare"):                 # no series: profile only
+            t[0] += 0.5
+    assert m.values("outer time") == [1.75]
+    assert m.values("inner time") == [0.25]
+    assert m.get("bare") == (0.0, 0)
+    assert recorder.events == [["outer", {"step_num": 7}, 0],
+                               ["inner", {"rid": 3}, 1],
+                               ["bare", {}, 1]]
+
+
+def test_span_leaves_no_sample_on_an_exception_or_a_drop(recorder):
+    from bigdl_tpu.optim.metrics import Metrics
+
+    m = Metrics(clock=lambda: 0.0)
+    with pytest.raises(StopIteration):
+        with m.span("fetch", "fetch time"):
+            raise StopIteration
+    with m.span("admit", "admit time") as sp:
+        sp.note(rids="4 5")
+        sp.drop()
+    assert m.get("fetch time") == (0.0, 0) == m.get("admit time")
+    assert recorder.events[1][1] == {"rids": "4 5"}
+    assert recorder.depth == 0               # both annotations were left
+
+
+def test_span_is_a_real_trace_annotation_by_default():
+    """Without a profile running the annotation is a disabled TraceMe:
+    the bracket still works and still records."""
+    from bigdl_tpu.optim.metrics import Metrics
+
+    m = Metrics()
+    with m.span("train.iteration", "t", step_num=1):
+        with m.span("train.fetch"):
+            pass
+    assert m.get("t")[1] == 1
+
+
+def test_serving_span_vocabulary_is_closed():
+    from bigdl_tpu.serving.metrics import ServingMetrics, span
+
+    with pytest.raises(ValueError, match="SPAN_NAMES"):
+        ServingMetrics().span("prefill")
+    with pytest.raises(ValueError, match="SPAN_NAMES"):
+        span("pool.read")
+
+
+def test_serving_span_keeps_add_phase_bookkeeping(recorder):
+    """A phase span IS an add_phase sample: the DEVICE_PHASES sum and
+    the decode-step window move exactly as a bare add_phase moves them."""
+    from bigdl_tpu.optim.metrics import Metrics
+    from bigdl_tpu.serving.metrics import ServingMetrics
+
+    t = [10.0]
+    sm = ServingMetrics(Metrics(clock=lambda: t[0]))
+    with sm.span("fence", phase="fence_wait"):
+        t[0] += 0.04
+    with sm.span("admit", phase="admit_host"):
+        t[0] += 0.01
+    assert sm.metrics.values("serving/fence_wait_s") == [pytest.approx(0.04)]
+    assert sm.metrics.values("serving/admit_host_s") == [pytest.approx(0.01)]
+    assert sm.device_seconds == pytest.approx(0.04)   # admit is host time
+    assert recorder.names() == ["serving.fence", "serving.admit"]
+
+
+# -- the serving engine -------------------------------------------------------
+
+def _make_lm(V=29, hidden=32, heads=4, layers=2, max_len=48, seed=9):
+    from bigdl_tpu.models.transformer import TransformerLM
+    from bigdl_tpu.utils.random_gen import RNG
+
+    RNG.set_seed(seed)
+    lm = TransformerLM(V, hidden_size=hidden, n_heads=heads,
+                       n_layers=layers, max_len=max_len)
+    lm._ensure_params()
+    lm.evaluate()
+    return lm
+
+
+#: what one step of the plain engine nests, by depth
+SERVING_PARENT = {
+    "serving.step": None, "serving.admit": "serving.step",
+    "serving.prefill.launch": "serving.admit",
+    "serving.decode.build": "serving.step",
+    "serving.decode.launch": "serving.step",
+    "serving.consume": "serving.step", "serving.fence": "serving.consume"}
+
+
+def test_engine_emits_exactly_the_span_vocabulary(recorder):
+    from bigdl_tpu.serving import SamplingParams, ServingEngine, VirtualClock
+    from bigdl_tpu.serving.fences import SPAN_NAMES
+
+    clk = VirtualClock()
+    eng = ServingEngine(_make_lm(), n_slots=3, clock=clk)
+    rng = np.random.RandomState(0)
+    rids = [eng.submit(list(rng.randint(1, 29, size=n)), max_new_tokens=4,
+                       sampling=SamplingParams(temperature=0.8, seed=i)
+                       if i % 2 else None)
+            for i, n in enumerate((5, 9, 3, 7, 6))]
+    clk.advance(2.0)
+    eng.drain()
+
+    seen = set(recorder.names())
+    assert seen == {f"serving.{n}" for n in SPAN_NAMES}
+    # nesting: every span but pool.write has ONE parent; pool.write sits
+    # under the admission (prefill scatter), the build (sampling lanes)
+    # or the consumer (a finished row's slot reset)
+    stack = []
+    for name, _, depth in recorder.events:
+        del stack[depth:]
+        parent = stack[-1] if stack else None
+        if name == "serving.pool.write":
+            assert parent in ("serving.admit", "serving.decode.build",
+                              "serving.consume")
+        else:
+            assert parent == SERVING_PARENT[name], (name, parent)
+        stack.append(name)
+    # arguments: the step number counts up; an admission names the
+    # request ids it bound; a prefill its rows, padded rows and bucket
+    steps = [ids["step"] for n, ids, _ in recorder.events
+             if n == "serving.step"]
+    assert steps == list(range(1, len(steps) + 1))
+    bound = [int(r) for n, ids, _ in recorder.events
+             if n == "serving.admit" and "rids" in ids
+             for r in ids["rids"].split()]
+    assert sorted(bound) == sorted(rids)
+    waves = [ids for n, ids, _ in recorder.events
+             if n == "serving.prefill.launch"]
+    assert waves and all(
+        set(w) == {"rows", "padded", "bucket"}
+        and 1 <= w["rows"] <= w["padded"] for w in waves)
+
+
+def test_queue_wait_one_sample_per_admission_on_the_engine_clock():
+    from bigdl_tpu.serving import ServingEngine, VirtualClock
+
+    clk = VirtualClock()
+    eng = ServingEngine(_make_lm(), n_slots=2, clock=clk)
+    for n in (4, 6, 5):                   # the third waits for a slot
+        eng.submit(list(range(1, n + 1)), max_new_tokens=3)
+    clk.advance(1.5)
+    eng.step()
+    m = eng.metrics.metrics
+    assert m.values("serving/queue_wait_s") == [1.5, 1.5]
+    clk.advance(0.5)
+    eng.drain()
+    waits = m.values("serving/queue_wait_s")
+    assert len(waits) == 3 and waits[2] >= 2.0
+    # admit_host_s: only the steps that bound a request leave a sample
+    assert m.get("serving/admit_host_s")[1] == 2
+    assert m.get("serving/fence_wait_s")[1] == \
+        m.get("serving/decode_step_s")[1] == m.get("serving/host_step_s")[1]
+
+
+def test_kv_used_share_is_sum_pos_over_reserved():
+    """Sampled from host state alone, it equals what the device holds:
+    sum of ``pos`` over the in-use slots / (slots x max_len)."""
+    from bigdl_tpu.serving import ServingEngine, VirtualClock
+
+    eng = ServingEngine(_make_lm(), n_slots=4, clock=VirtualClock())
+    for n in (5, 11, 2):
+        eng.submit(list(range(1, n + 1)), max_new_tokens=8)
+    for _ in range(3):
+        eng.step()
+        pos = np.asarray(eng.pool.carry["pos"])
+        held = sum(int(pos[s]) for s in eng.scheduler.running)
+        share = eng.metrics.metrics.values("serving/kv_used_share")[-1]
+        assert share == pytest.approx(
+            held / (eng.pool.n_slots * eng.pool.max_len))
+    # the prompts less each one's fed token, plus three steps of three rows
+    assert held == 5 + 11 + 2 - 3 + 3 * 3
+
+
+# -- the training loop --------------------------------------------------------
+
+def test_three_iterations_leave_equal_counts_in_every_phase(recorder):
+    from bigdl_tpu.dataset import DataSet
+    from bigdl_tpu.dataset.sample import Sample
+    from bigdl_tpu.nn import ClassNLLCriterion, Linear, LogSoftMax, Sequential
+    from bigdl_tpu.optim import SGD, Optimizer, Trigger
+
+    rng = np.random.RandomState(1)
+    samples = [Sample(rng.rand(8).astype(np.float32), np.int32(i % 3 + 1))
+               for i in range(16)]
+    model = Sequential().add(Linear(8, 3)).add(LogSoftMax())
+    opt = Optimizer(model=model, dataset=DataSet.array(samples),
+                    criterion=ClassNLLCriterion(), batch_size=4)
+    opt.set_optim_method(SGD(learning_rate=0.1))
+    opt.set_end_when(Trigger.max_iteration(3))
+    opt.optimize()
+
+    counts = {n: opt.metrics.get(n)[1] for n in (
+        "computing time", "data fetch time", "dispatch time",
+        "loss sync time")}
+    assert set(counts.values()) == {3}, counts
+    # an iteration's wall holds its dispatch and its sync
+    for wall, disp, sync in zip(*(opt.metrics.values(n) for n in (
+            "computing time", "dispatch time", "loss sync time"))):
+        assert wall >= disp + sync > 0.0
+    its = [(ids, d) for n, ids, d in recorder.events
+           if n == "train.iteration"]
+    assert [ids["step_num"] for ids, _ in its] == [1, 2, 3]
+    inside = {n for n, _, d in recorder.events if d == 1}
+    assert inside == {"train.fetch", "train.dispatch", "train.loss_sync"}
+
+
+# -- names the benchmark's readers look for -----------------------------------
+
+def _pallas_names(jaxpr):
+    """Names of every pallas_call in a jaxpr, nested jaxprs included."""
+    import jax
+
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += _pallas_names(sub)
+    return out
+
+
+def test_the_four_pallas_calls_carry_their_names():
+    import jax
+    import jax.numpy as jnp
+
+    from bigdl_tpu.ops.decode_attention import decode_attention
+    from bigdl_tpu.ops.flash_attention import flash_attention
+
+    q = jnp.ones((1, 2, 128, 8), jnp.float32)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, block=128,
+                               interpret=True).sum()
+
+    fwd = jax.make_jaxpr(loss)(q, q, q).jaxpr
+    assert _pallas_names(fwd) == ["flash_fwd"]
+    grad = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q).jaxpr
+    assert sorted(_pallas_names(grad)) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+
+    dec = jax.make_jaxpr(lambda q, k, v, pos: decode_attention(
+        q, k, v, pos, impl="kernel", interpret=True, block=128))(
+            jnp.ones((2, 2, 8)), jnp.ones((2, 128, 2, 8)),
+            jnp.ones((2, 128, 2, 8)), jnp.array([3, 9], jnp.int32)).jaxpr
+    assert _pallas_names(dec) == ["pooled_decode_attention"]
+
+
+def _jit_name(fn) -> str:
+    """The name a jitted callable's program carries in a profile."""
+    inner = getattr(fn, "__wrapped__", fn)
+    return "jit_" + getattr(inner, "__name__", "?")
+
+
+def test_the_jitted_programs_carry_the_names_the_configs_use():
+    """``jit_step`` / ``jit_sample_step`` are what the benchmark's
+    configuration files point their readers at; ``jit_prefill`` and
+    ``jit__scatter_impl`` are what its span readers look for — with an
+    adapter bank too."""
+    import jax
+
+    from bigdl_tpu.nn import ClassNLLCriterion, Linear, LogSoftMax, Sequential
+    from bigdl_tpu.optim import SGD, Optimizer
+    from bigdl_tpu.serving import ServingEngine
+
+    configs = ROOT / "benchmark" / "configs"
+    wanted = {json.loads(p.read_text()).get(kind, {}).get(key)
+              for p in configs.glob("*.json")
+              for kind, key in (("train", "step_program"),
+                                ("serve", "decode_program"))} - {None}
+    assert wanted == {"jit_step", "jit_sample_step"}
+
+    eng = ServingEngine(_make_lm(), n_slots=2)
+    assert _jit_name(eng._step_fn) == "jit_sample_step"
+    assert _jit_name(eng.pool._scatter) == "jit__scatter_impl"
+    rid = eng.submit([1, 2, 3, 4, 5], max_new_tokens=2)
+    eng.drain()
+    assert len(eng.request(rid).output) == 2
+
+    from bigdl_tpu.dataset import DataSet
+    from bigdl_tpu.dataset.sample import Sample
+
+    model = Sequential().add(Linear(4, 2)).add(LogSoftMax())
+    samples = [Sample(np.zeros(4, np.float32), np.int32(1))] * 2
+    opt = Optimizer(model=model, dataset=DataSet.array(samples),
+                    criterion=ClassNLLCriterion(), batch_size=2)
+    opt.set_optim_method(SGD(learning_rate=0.1))
+    step = opt._prepare()[0]
+    assert _jit_name(step) == "jit_step"
+    assert isinstance(step, type(jax.jit(lambda x: x)))
+
+
+@pytest.mark.parametrize("with_bank", [False, True])
+def test_batch_prefill_compiles_as_jit_prefill(with_bank):
+    """One program, one name: the adapter bank's arity-pinning wrapper
+    does not rename the batch prefill (it used to compile as jit_run)."""
+    import jax.numpy as jnp
+
+    from bigdl_tpu.models.transformer import (
+        make_batch_decode_step, make_batch_prefill_step, serving_params,
+    )
+    from bigdl_tpu.serving.lora import AdapterBank
+
+    lm = _make_lm()
+    bank = AdapterBank(lm, rank=2, n_slots=2) if with_bank else None
+    spec = None if bank is None else bank.spec
+    fn = make_batch_prefill_step(lm, adapter=spec)
+    _, init_carry = make_batch_decode_step(lm, adapter=spec)
+    args = [serving_params(lm, jnp.float32), jnp.zeros((2, 8), jnp.int32),
+            jnp.array([3, 5], jnp.int32), init_carry(2)]
+    if bank is not None:
+        args += [jnp.zeros((2,), jnp.int32), bank.device_arrays()]
+    assert _jit_name(fn._jitted) == "jit_prefill"
+    assert "module @jit_prefill" in fn._jitted.lower(*args).as_text()
