@@ -444,7 +444,10 @@ def serving_carry_specs(model: Sequential, sampling: bool = False,
                         kv_quant: bool = False):
     """``PartitionSpec`` tree for a :func:`make_batch_decode_step` carry:
     every leaf's slot axis over ``data_axis``, and (when ``model_axis``
-    is given) the per-layer K/V head axis over ``model_axis``. Specs
+    is given) the per-layer K/V lane axis over ``model_axis`` — the
+    stored ``(N, max_len, heads*hd)`` array is head-major in its last
+    axis, so each model chip owns ``heads_l*hd`` contiguous lanes: its
+    own whole heads. Specs
     deliberately carry NO trailing ``None`` dims — ``P("data")`` and
     ``P("data", None, ...)`` are different specs to jit's cache,
     and mixing the two spellings between placement and step output would
@@ -599,7 +602,7 @@ def _kv_quant_merge(qc, s_old, amax_new):
     THE one copy of the quantized-write rule (decode step, batched
     prefill, and per-request prefill all route through here).
 
-    ``qc``: stored int8 cache ``(R, L, H, D)``; ``s_old``: current
+    ``qc``: stored int8 cache ``(R, L, H*D)``; ``s_old``: current
     ``(R, H)`` fp32 scales; ``amax_new``: ``(R, H)`` max |new values|
     about to be written (0 for rows that write nothing — their scale
     and stored values pass through BITWISE: their scale does not grow,
@@ -620,8 +623,10 @@ def _kv_quant_merge(qc, s_old, amax_new):
     s_new = jnp.where(s_cand > s_old, s_cand * _KV_SCALE_HEADROOM, s_old)
     s_safe = jnp.where(s_new > 0, s_new, 1.0)
     ratio = jnp.where(s_new > 0, s_old / s_safe, 1.0)
-    qc2 = jnp.round(qc.astype(jnp.float32) * ratio[:, None, :, None]
-                    ).astype(jnp.int8)
+    R, L, H = qc.shape[0], qc.shape[1], s_old.shape[1]
+    qc2 = jnp.round(qc.reshape(R, L, H, -1).astype(jnp.float32)
+                    * ratio[:, None, :, None]
+                    ).astype(jnp.int8).reshape(qc.shape)
     return qc2, s_new, s_safe
 
 
@@ -788,9 +793,9 @@ def make_prefill_step(model: Sequential, compute_dtype=None,
                 # future warm-carry caller the pos guard can't see,
                 # e.g. under an outer trace)
                 new_carry[f"k{i}"] = lax.dynamic_update_slice_in_dim(
-                    kc_rq, kq, 0, 1)
+                    kc_rq, kq.reshape(B, P, heads * hd), 0, 1)
                 new_carry[f"v{i}"] = lax.dynamic_update_slice_in_dim(
-                    vc_rq, vq, 0, 1)
+                    vc_rq, vq.reshape(B, P, heads * hd), 0, 1)
                 new_carry[f"k{i}_scale"] = ks
                 new_carry[f"v{i}_scale"] = vs
                 # attend over the dequantized values decode will read
@@ -798,10 +803,14 @@ def make_prefill_step(model: Sequential, compute_dtype=None,
                 v = vq.astype(jnp.float32) * vs_safe[:, None, :, None]
                 q = q.astype(jnp.float32)
             else:
+                # the carry stores (B, max_len, heads*hd): the rows go
+                # in as projected, the 4-D view is the attention's own
                 new_carry[f"k{i}"] = lax.dynamic_update_slice_in_dim(
-                    new_carry[f"k{i}"], k.astype(cache_dtype), 0, 1)
+                    new_carry[f"k{i}"],
+                    k.astype(cache_dtype).reshape(B, P, heads * hd), 0, 1)
                 new_carry[f"v{i}"] = lax.dynamic_update_slice_in_dim(
-                    new_carry[f"v{i}"], v.astype(cache_dtype), 0, 1)
+                    new_carry[f"v{i}"],
+                    v.astype(cache_dtype).reshape(B, P, heads * hd), 0, 1)
             # dense causal attention over the prompt (P is prompt-sized;
             # scores accumulate fp32 like the decode step)
             s = jnp.einsum("bqhd,bkhd->bhqk", q * scale, k,
@@ -977,15 +986,18 @@ def make_batch_prefill_step(model: Sequential, compute_dtype=None,
             h, _ = blk.ln1.apply(bp[blk._child_key(0)], x)
             ap = bp[blk._child_key(1)]
             q = aproj(ap["wq"], h, f"wq{i}").reshape(B, L, heads_l, hd)
-            k = aproj(ap["wk"], h, f"wk{i}").reshape(B, L, heads_l, hd)
-            v = aproj(ap["wv"], h, f"wv{i}").reshape(B, L, heads_l, hd)
+            # (B, L, heads_l*hd): the stored rows, as projected — the
+            # (…, heads_l, hd) view of the carry further down is this
+            # program's own, for its multi-query einsums
+            k = aproj(ap["wk"], h, f"wk{i}")
+            v = aproj(ap["wv"], h, f"wv{i}")
             if kv_quant:
                 # int8 storage: per-(row, head) amax over the VALID
                 # columns only (pad columns must not inflate the scale),
                 # grow-only merge with the cached prefix's scale, then
                 # the same dropped-index masked scatter
-                k32 = k.astype(jnp.float32)
-                v32 = v.astype(jnp.float32)
+                k32 = k.astype(jnp.float32).reshape(B, L, heads_l, hd)
+                v32 = v.astype(jnp.float32).reshape(B, L, heads_l, hd)
                 inbf = inb[:, :, None, None]
                 k_amax = jnp.max(jnp.abs(k32) * inbf, axis=(1, 3))
                 v_amax = jnp.max(jnp.abs(v32) * inbf, axis=(1, 3))
@@ -994,18 +1006,20 @@ def make_batch_prefill_step(model: Sequential, compute_dtype=None,
                 vc_rq, vs_new, vs_safe = _kv_quant_merge(
                     new_carry[f"v{i}"], new_carry[f"v{i}_scale"], v_amax)
                 kc = kc_rq.at[rows[:, None], widx].set(
-                    _kv_quantize(k32, ks_safe[:, None, :, None]),
-                    mode="drop")
+                    _kv_quantize(k32, ks_safe[:, None, :, None]
+                                 ).reshape(k.shape), mode="drop")
                 vc = vc_rq.at[rows[:, None], widx].set(
-                    _kv_quantize(v32, vs_safe[:, None, :, None]),
-                    mode="drop")
+                    _kv_quantize(v32, vs_safe[:, None, :, None]
+                                 ).reshape(v.shape), mode="drop")
                 new_carry[f"k{i}_scale"] = ks_new
                 new_carry[f"v{i}_scale"] = vs_new
                 # the prompt attends over the DEQUANTIZED cache — the
                 # values decode-time reads will see, so prefill and
                 # decode stay one consistent numerics story
-                katt = kc.astype(jnp.float32) * ks_new[:, None, :, None]
-                vatt = vc.astype(jnp.float32) * vs_new[:, None, :, None]
+                katt = kc.reshape(B, max_len, heads_l, hd).astype(
+                    jnp.float32) * ks_new[:, None, :, None]
+                vatt = vc.reshape(B, max_len, heads_l, hd).astype(
+                    jnp.float32) * vs_new[:, None, :, None]
                 qatt = (q * scale).astype(jnp.float32)
                 p_dt = jnp.float32
             else:
@@ -1013,7 +1027,8 @@ def make_batch_prefill_step(model: Sequential, compute_dtype=None,
                     k.astype(cache_dtype), mode="drop")
                 vc = new_carry[f"v{i}"].at[rows[:, None], widx].set(
                     v.astype(cache_dtype), mode="drop")
-                katt, vatt = kc, vc
+                katt = kc.reshape(B, max_len, heads_l, hd)
+                vatt = vc.reshape(B, max_len, heads_l, hd)
                 qatt = (q * scale).astype(cache_dtype)
                 p_dt = cache_dtype
             new_carry[f"k{i}"], new_carry[f"v{i}"] = kc, vc
@@ -1144,8 +1159,12 @@ def make_batch_prefill_step(model: Sequential, compute_dtype=None,
 def _serving_init_carry(n_layers: int, max_len: int, heads: int, hd: int,
                         cache_dtype, kv_quant: bool, sampling: bool,
                         vocab: int):
-    """THE one pooled-carry layout: per-layer K/V rows + per-row ``pos``,
-    int8 dequant scales on the quantized layout, and the per-row
+    """THE one pooled-carry layout: per-layer K/V rows
+    ``(n_slots, max_len, heads*hd)`` (head-major lanes: the
+    ``(…, heads, hd)`` array with its two minor axes merged, which is
+    the shape the decode step's row scatter and whole-pool read agree
+    on — no program that holds the pool re-lays it out) + per-row
+    ``pos``, int8 dequant scales on the quantized layout, and the per-row
     sampling state (RNG lanes + penalty counters — the engine seeds rows
     at admission via ``KVPool.write_sampling``). Shared by
     :func:`make_batch_decode_step` and :func:`make_batch_verify_step` so
@@ -1157,9 +1176,9 @@ def _serving_init_carry(n_layers: int, max_len: int, heads: int, hd: int,
         carry = {"pos": jnp.zeros((n_slots,), jnp.int32)}
         kv_dt = jnp.int8 if kv_quant else cache_dtype
         for i in range(n_layers):
-            carry[f"k{i}"] = jnp.zeros((n_slots, max_len, heads, hd),
+            carry[f"k{i}"] = jnp.zeros((n_slots, max_len, heads * hd),
                                        kv_dt)
-            carry[f"v{i}"] = jnp.zeros((n_slots, max_len, heads, hd),
+            carry[f"v{i}"] = jnp.zeros((n_slots, max_len, heads * hd),
                                        kv_dt)
             if kv_quant:
                 # per-(slot, head) dequant scales; 0 = "no scale yet"
@@ -1183,7 +1202,9 @@ def make_decode_step(model: Sequential, compute_dtype=None):
     Returns ``(step_fn, init_carry)``:
 
     * ``init_carry(batch) -> carry`` — per-layer K/V caches
-      ``(batch, max_len, heads, head_dim)`` plus a position counter;
+      ``(batch, max_len, heads*head_dim)`` (head-major lanes — the
+      pooled layout, see :func:`_serving_init_carry`) plus a position
+      counter;
     * ``step_fn(params, tokens, carry) -> (logprobs, carry)`` —
       one token per call, attention reads the cache (O(1) new compute per
       step instead of re-running the full prefix). ``params`` may be
@@ -1216,6 +1237,7 @@ def make_decode_step(model: Sequential, compute_dtype=None):
     from jax import lax
 
     from bigdl_tpu.nn.misc import LookupTable
+    from bigdl_tpu.ops.decode_attention import folded_decode_attention
 
     model._ensure_params()
     mods = model.modules
@@ -1244,14 +1266,9 @@ def make_decode_step(model: Sequential, compute_dtype=None):
 
     cache_dtype = compute_dtype or jnp.float32
 
-    def init_carry(batch: int):
-        carry = {"pos": jnp.zeros((batch,), jnp.int32)}
-        for i in range(len(blocks0)):
-            carry[f"k{i}"] = jnp.zeros((batch, max_len, heads, hd),
-                                       cache_dtype)
-            carry[f"v{i}"] = jnp.zeros((batch, max_len, heads, hd),
-                                       cache_dtype)
-        return carry
+    init_carry = _serving_init_carry(len(blocks0), max_len, heads, hd,
+                                     cache_dtype, kv_quant=False,
+                                     sampling=False, vocab=0)
 
     _proj = _serving_proj
 
@@ -1272,23 +1289,18 @@ def make_decode_step(model: Sequential, compute_dtype=None):
             h = h[:, 0]
             ap = bp[blk._child_key(1)]
             q = _proj(ap["wq"], h).reshape(n, heads, hd)
-            k_new = _proj(ap["wk"], h).reshape(n, heads, hd)
-            v_new = _proj(ap["wv"], h).reshape(n, heads, hd)
+            k_new = _proj(ap["wk"], h)
+            v_new = _proj(ap["wv"], h)
             kc = lax.dynamic_update_slice_in_dim(
                 new_carry[f"k{i}"], k_new[:, None].astype(cache_dtype), t, 1)
             vc = lax.dynamic_update_slice_in_dim(
                 new_carry[f"v{i}"], v_new[:, None].astype(cache_dtype), t, 1)
             new_carry[f"k{i}"], new_carry[f"v{i}"] = kc, vc
-            # scores accumulate fp32 regardless of the serving dtype
-            s = jnp.einsum("nhd,nlhd->nhl",
-                           (q * scale).astype(cache_dtype), kc,
-                           preferred_element_type=jnp.float32)
-            valid = jnp.arange(max_len)[None, None, :] <= t
-            s = jnp.where(valid, s, -1e30)
-            p = jax.nn.softmax(s, axis=-1)
-            ctx = jnp.einsum("nhl,nlhd->nhd", p.astype(cache_dtype), vc,
-                             preferred_element_type=jnp.float32
-                             ).astype(x.dtype).reshape(n, heads * hd)
+            # THE single-query read of the stored cache (scores
+            # accumulate fp32 regardless of the serving dtype)
+            ctx = folded_decode_attention(
+                q, kc, vc, jnp.broadcast_to(t, (n,)), scale=scale,
+                out_dtype=x.dtype).reshape(n, heads * hd)
             x = x + _proj(ap["wo"], ctx)
             h2, _ = blk.ln2.apply(bp[blk._child_key(2)], x[:, None])
             h2 = h2[:, 0]
@@ -1365,7 +1377,7 @@ def make_batch_decode_step(model: Sequential, compute_dtype=None,
     Returns ``(step_fn, init_carry)``:
 
     * ``init_carry(n_slots) -> carry`` — identical layout to
-      :func:`make_decode_step` (per-layer ``(N, max_len, heads, hd)``
+      :func:`make_decode_step` (per-layer ``(N, max_len, heads*hd)``
       K/V + ``pos``), but ``pos`` is PER-ROW state, not uniform;
     * ``step_fn(params, tokens, active, carry) -> (logprobs, carry)`` —
       ``tokens`` (N,) 0-based ids, ``active`` (N,) bool. Active rows
@@ -1464,6 +1476,9 @@ def make_batch_decode_step(model: Sequential, compute_dtype=None,
     import jax.numpy as jnp
 
     from bigdl_tpu.nn.misc import LookupTable
+    from bigdl_tpu.ops.decode_attention import (
+        decode_attention, folded_decode_attention,
+    )
 
     model._ensure_params()
     mods = model.modules
@@ -1512,16 +1527,16 @@ def make_batch_decode_step(model: Sequential, compute_dtype=None,
             # slices (head-major rows), so the same _proj IS the
             # column-parallel half — zero communication
             q = aproj(ap["wq"], h, f"wq{i}").reshape(n, heads_l, hd)
-            k_new = aproj(ap["wk"], h, f"wk{i}").reshape(n, heads_l, hd)
-            v_new = aproj(ap["wv"], h, f"wv{i}").reshape(n, heads_l, hd)
+            k_new = aproj(ap["wk"], h, f"wk{i}")  # (N, heads_l*hd): the
+            v_new = aproj(ap["wv"], h, f"wv{i}")  # stored row, as it is
             kc_prev, vc_prev = new_carry[f"k{i}"], new_carry[f"v{i}"]
             if kv_quant:
                 # int8 storage: grow-only (slot, head) scale merge, then
                 # the same masked scatter contract — inactive rows have
                 # amax 0, so their scale, stored values, and the
                 # written-back old value are all bitwise untouched
-                k32 = k_new.astype(jnp.float32)
-                v32 = v_new.astype(jnp.float32)
+                k32 = k_new.astype(jnp.float32).reshape(n, heads_l, hd)
+                v32 = v_new.astype(jnp.float32).reshape(n, heads_l, hd)
                 k_amax = jnp.where(active[:, None],
                                    jnp.max(jnp.abs(k32), axis=-1), 0.0)
                 v_amax = jnp.where(active[:, None],
@@ -1530,8 +1545,10 @@ def make_batch_decode_step(model: Sequential, compute_dtype=None,
                  vs_safe) = _kv_quant_merge_step(
                     kc_prev, vc_prev, new_carry[f"k{i}_scale"],
                     new_carry[f"v{i}_scale"], k_amax, v_amax)
-                k_wr0 = _kv_quantize(k32, ks_safe[..., None])
-                v_wr0 = _kv_quantize(v32, vs_safe[..., None])
+                k_wr0 = _kv_quantize(k32, ks_safe[..., None]
+                                     ).reshape(n, heads_l * hd)
+                v_wr0 = _kv_quantize(v32, vs_safe[..., None]
+                                     ).reshape(n, heads_l * hd)
                 new_carry[f"k{i}_scale"] = ks_new
                 new_carry[f"v{i}_scale"] = vs_new
             else:
@@ -1540,8 +1557,8 @@ def make_batch_decode_step(model: Sequential, compute_dtype=None,
             # masked per-row scatter: inactive rows write their OLD value
             # back, so their cache stays bitwise identical
             k_old, v_old = kc_prev[rows, wpos], vc_prev[rows, wpos]
-            k_wr = jnp.where(active[:, None, None], k_wr0, k_old)
-            v_wr = jnp.where(active[:, None, None], v_wr0, v_old)
+            k_wr = jnp.where(active[:, None], k_wr0, k_old)
+            v_wr = jnp.where(active[:, None], v_wr0, v_old)
             kc = kc_prev.at[rows, wpos].set(k_wr)
             vc = vc_prev.at[rows, wpos].set(v_wr)
             new_carry[f"k{i}"], new_carry[f"v{i}"] = kc, vc
@@ -1550,25 +1567,19 @@ def make_batch_decode_step(model: Sequential, compute_dtype=None,
                 # TPU (int8 K/V loads, dequant fused as two scalar
                 # factors), jnp reference elsewhere — per-row masked
                 # single-query attention over cols 0..wpos[r]
-                from bigdl_tpu.ops.decode_attention import decode_attention
-
                 ctx = decode_attention(
                     q, kc, vc, wpos, k_scale=ks_new, v_scale=vs_new,
                     scale=scale, out_dtype=x.dtype
                 ).reshape(n, heads_l * hd)
             else:
-                # per-row causal mask over the row's own cache prefix;
-                # scores accumulate fp32 regardless of the serving dtype
-                s = jnp.einsum("nhd,nlhd->nhl",
-                               (q * scale).astype(cache_dtype), kc,
-                               preferred_element_type=jnp.float32)
-                valid = jnp.arange(max_len)[None, None, :] \
-                    <= wpos[:, None, None]
-                s = jnp.where(valid, s, -1e30)
-                p = jax.nn.softmax(s, axis=-1)
-                ctx = jnp.einsum("nhl,nlhd->nhd", p.astype(cache_dtype),
-                                 vc, preferred_element_type=jnp.float32
-                                 ).astype(x.dtype).reshape(n, heads_l * hd)
+                # per-row causal mask over the row's own cache prefix,
+                # read from the stored 3-D array (a 4-D view here costs
+                # two pool-sized copies per tensor per token on the
+                # TPU); scores accumulate fp32 regardless of the
+                # serving dtype
+                ctx = folded_decode_attention(
+                    q, kc, vc, wpos, scale=scale, out_dtype=x.dtype
+                ).reshape(n, heads_l * hd)
             if mesh is None:
                 x = x + aproj(ap["wo"], ctx, f"wo{i}")
             else:
@@ -1841,8 +1852,10 @@ def make_batch_verify_step(model: Sequential, compute_dtype=None,
             h, _ = blk.ln1.apply(bp[blk._child_key(0)], x)
             ap = bp[blk._child_key(1)]
             q = aproj(ap["wq"], h, f"wq{i}").reshape(N, S, heads_l, hd)
-            k = aproj(ap["wk"], h, f"wk{i}").reshape(N, S, heads_l, hd)
-            v = aproj(ap["wv"], h, f"wv{i}").reshape(N, S, heads_l, hd)
+            # stored rows as projected, 4-D view of the carry for the
+            # S-wide einsums — as in the batch prefill
+            k = aproj(ap["wk"], h, f"wk{i}")
+            v = aproj(ap["wv"], h, f"wv{i}")
             if kv_quant:
                 # int8 storage, ACCEPTED-ONLY merge: the chunk attention
                 # reads the stored cache dequantized at the CURRENT
@@ -1851,14 +1864,16 @@ def make_batch_verify_step(model: Sequential, compute_dtype=None,
                 # scale merge + quantized scatter are deferred past
                 # acceptance (below), so nothing a rejected draft
                 # produced can reach the carry
-                k32 = k.astype(jnp.float32)
-                v32 = v.astype(jnp.float32)
+                k32 = k.astype(jnp.float32).reshape(N, S, heads_l, hd)
+                v32 = v.astype(jnp.float32).reshape(N, S, heads_l, hd)
                 ks_old = new_carry[f"k{i}_scale"]
                 vs_old = new_carry[f"v{i}_scale"]
-                katt = (new_carry[f"k{i}"].astype(jnp.float32)
+                katt = (new_carry[f"k{i}"].reshape(
+                            N, max_len, heads_l, hd).astype(jnp.float32)
                         * ks_old[:, None, :, None]).at[
                             rows[:, None], widx].set(k32, mode="drop")
-                vatt = (new_carry[f"v{i}"].astype(jnp.float32)
+                vatt = (new_carry[f"v{i}"].reshape(
+                            N, max_len, heads_l, hd).astype(jnp.float32)
                         * vs_old[:, None, :, None]).at[
                             rows[:, None], widx].set(v32, mode="drop")
                 qatt = (q * scale).astype(jnp.float32)
@@ -1869,7 +1884,8 @@ def make_batch_verify_step(model: Sequential, compute_dtype=None,
                     k.astype(cache_dtype), mode="drop")
                 vc = new_carry[f"v{i}"].at[rows[:, None], widx].set(
                     v.astype(cache_dtype), mode="drop")
-                katt, vatt = kc, vc
+                katt = kc.reshape(N, max_len, heads_l, hd)
+                vatt = vc.reshape(N, max_len, heads_l, hd)
                 qatt = (q * scale).astype(cache_dtype)
                 p_dt = cache_dtype
                 new_carry[f"k{i}"], new_carry[f"v{i}"] = kc, vc
@@ -1952,10 +1968,12 @@ def make_batch_verify_step(model: Sequential, compute_dtype=None,
                 vc_rq, vs_new, vs_safe = _kv_quant_merge(
                     new_carry[f"v{i}"], new_carry[f"v{i}_scale"], v_amax)
                 new_carry[f"k{i}"] = kc_rq.at[rows[:, None], widx_e].set(
-                    _kv_quantize(k32, ks_safe[:, None, :, None]),
+                    _kv_quantize(k32, ks_safe[:, None, :, None]
+                                 ).reshape(N, S, heads_l * hd),
                     mode="drop")
                 new_carry[f"v{i}"] = vc_rq.at[rows[:, None], widx_e].set(
-                    _kv_quantize(v32, vs_safe[:, None, :, None]),
+                    _kv_quantize(v32, vs_safe[:, None, :, None]
+                                 ).reshape(N, S, heads_l * hd),
                     mode="drop")
                 new_carry[f"k{i}_scale"] = ks_new
                 new_carry[f"v{i}_scale"] = vs_new

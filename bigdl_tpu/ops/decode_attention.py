@@ -1,13 +1,18 @@
 """Pooled decode attention as a Pallas TPU kernel (+ jnp reference).
 
 The serving engine's decode step is memory-bandwidth-bound: every token
-re-reads the whole pooled KV cache ``(n_slots, max_len, heads, head_dim)``
-to score ONE query per row. This module owns that inner loop:
+re-reads the whole pooled KV cache, stored ``(n_slots, max_len,
+heads*head_dim)`` (head-major lanes), to score ONE query per row. This
+module owns that inner loop:
 
-* :func:`decode_attention_reference` — the jnp spelling (the math the
-  engine's inline decode path computes): masked single-query attention
-  over each row's own cache prefix ``0..pos[r]``, fp32 score/softmax
-  accumulation;
+* :func:`decode_attention_reference` — the plain jnp spelling: masked
+  single-query attention over each row's own cache prefix
+  ``0..pos[r]``, fp32 score/softmax accumulation, per-head einsums over
+  the ``(N, L, H, D)`` view;
+* :func:`folded_decode_attention` — the same sum computed against the
+  STORED ``(N, L, H*D)`` array (block-diagonal query, no 4-D view), so
+  the program that holds it never re-lays the pool out: the float
+  decode steps' path;
 * :func:`pooled_decode_attention` — the Pallas kernel (grid
   ``(n_rows, kv_blocks)``, online softmax in VMEM scratch, one
   ``(block_l, heads*head_dim)`` K/V tile resident per step) with the
@@ -53,12 +58,15 @@ def _auto_interpret() -> bool:
 
 
 def _check_qkv(q, k, v, k_scale, v_scale):
-    if q.ndim != 3 or k.ndim != 4 or v.ndim != 4:
+    """K/V come as the stored ``(N, L, H*D)`` array or its
+    ``(N, L, H, D)`` view — the same bytes, head-major lanes."""
+    if q.ndim != 3 or k.ndim not in (3, 4) or v.ndim != k.ndim:
         raise ValueError(
-            f"expected q (N, H, D) and k/v (N, L, H, D), got "
-            f"{q.shape} / {k.shape} / {v.shape}")
+            f"expected q (N, H, D) and k/v (N, L, H*D) or (N, L, H, D), "
+            f"got {q.shape} / {k.shape} / {v.shape}")
     n, h, d = q.shape
-    if k.shape != v.shape or k.shape[0] != n or k.shape[2:] != (h, d):
+    tail = (h, d) if k.ndim == 4 else (h * d,)
+    if k.shape != v.shape or k.shape[0] != n or k.shape[2:] != tail:
         raise ValueError(
             f"k/v {k.shape}/{v.shape} do not match q {q.shape}")
     if (k_scale is None) != (v_scale is None):
@@ -84,8 +92,9 @@ def decode_attention_reference(q, k, v, pos, k_scale=None, v_scale=None,
     contract the kernel is tested against AND the CPU serving path.
 
     ``q``: (N, H, D) one query per pooled row; ``k``/``v``:
-    (N, L, H, D) per-row caches (float, or int8 with (N, H) fp32
-    ``k_scale``/``v_scale``); ``pos``: (N,) int32 — row ``r`` attends
+    (N, L, H*D) per-row caches as stored, or their (N, L, H, D) view
+    (float, or int8 with (N, H) fp32 ``k_scale``/``v_scale``);
+    ``pos``: (N,) int32 — row ``r`` attends
     over its own cache columns ``0..pos[r]`` INCLUSIVE (the decode
     step's ``wpos``, where the new K/V was just written). Scores and
     softmax accumulate fp32 regardless of input dtype; the int8 path
@@ -96,6 +105,8 @@ def decode_attention_reference(q, k, v, pos, k_scale=None, v_scale=None,
     _check_qkv(q, k, v, k_scale, v_scale)
     n, h, d = q.shape
     L = k.shape[1]
+    k = k.reshape(n, L, h, d)
+    v = v.reshape(n, L, h, d)
     if scale is None:
         scale = d ** -0.5
     if out_dtype is None:
@@ -119,6 +130,52 @@ def decode_attention_reference(q, k, v, pos, k_scale=None, v_scale=None,
         p = jax.nn.softmax(jnp.where(valid, s, _NEG_INF), axis=-1)
         ctx = jnp.einsum("nhl,nlhd->nhd", p.astype(v.dtype), v,
                          preferred_element_type=jnp.float32)
+    return ctx.astype(out_dtype)
+
+
+def folded_decode_attention(q, k, v, pos, scale: Optional[float] = None,
+                            out_dtype=None):
+    """:func:`decode_attention_reference`'s float sum against the STORED
+    cache ``(N, L, H*D)``, never through a 4-D view of it — what the
+    float decode steps call every token.
+
+    A 4-D view of the pool costs the program that holds it two
+    pool-sized copies per tensor on the TPU (the device lays a
+    ``(..., H, 64)`` bf16 array out ``max_len``-minor, the row scatter
+    wants it the other way). So the row's query is spread into a
+    block-diagonal ``(H*D, H)`` matrix (column ``h`` holds ``q[h]`` in
+    lanes ``h*D..(h+1)*D``, zeros elsewhere — :func:`_decode_kernel`'s
+    trick): ``K (L, H*D) @ q_bd`` IS the per-head scores, and the
+    diagonal ``D``-wide blocks of ``p (H, L) @ V (L, H*D)`` are the
+    per-head contexts. The off-diagonal products are exact zeros in the
+    scores and discarded in the context, so this is the reference's sum
+    with extra zero addends: same ``scale``-then-cast query, f32
+    accumulation, ``-1e30`` mask and softmax, ``p`` cast to the cache
+    dtype. ``q``: (N, H, D); ``pos``: (N,) inclusive last column.
+    Returns (N, H, D) in ``out_dtype`` (default: q's dtype)."""
+    _check_qkv(q, k, v, None, None)
+    if k.ndim != 3:
+        raise ValueError(
+            f"the folded form reads the stored (N, L, H*D) cache, got "
+            f"{k.shape}")
+    n, h, d = q.shape
+    L = k.shape[1]
+    if scale is None:
+        scale = d ** -0.5
+    if out_dtype is None:
+        out_dtype = q.dtype
+    qs = (q * scale).astype(k.dtype)
+    q_bd = (qs[:, :, :, None] * jnp.eye(h, dtype=k.dtype)[:, None, :]
+            ).reshape(n, h * d, h)
+    s = jnp.einsum("nlc,nch->nhl", k, q_bd,
+                   preferred_element_type=jnp.float32)
+    valid = jnp.arange(L)[None, None, :] <= \
+        jnp.asarray(pos, jnp.int32)[:, None, None]
+    p = jax.nn.softmax(jnp.where(valid, s, _NEG_INF), axis=-1)
+    full = jnp.einsum("nhl,nlc->nhc", p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32)
+    # head h's context is its own D-wide block of row h
+    ctx = jnp.einsum("nhhd->nhd", full.reshape(n, h, h, d))
     return ctx.astype(out_dtype)
 
 
@@ -262,16 +319,17 @@ def pooled_decode_attention(q, k, v, pos, k_scale=None, v_scale=None,
     """Pallas pooled decode attention over slot-indexed KV.
 
     Same contract as :func:`decode_attention_reference` (q ``(N, H, D)``,
-    k/v ``(N, L, H, D)`` float or int8-with-``(N, H)``-scales, per-row
-    inclusive ``pos``), computed by the tiled online-softmax kernel.
+    k/v ``(N, L, H*D)`` as stored or their ``(N, L, H, D)`` view, float
+    or int8-with-``(N, H)``-scales, per-row inclusive ``pos``), computed
+    by the tiled online-softmax kernel.
     ``block`` is the KV-position tile length (None = auto);
     ``interpret=None`` auto-selects Pallas interpreter mode off-TPU via
     the shared ``utils.compat.auto_interpret`` probe. The cache window
     is right-padded to a block multiple when needed — padded columns
     sit beyond every row's ``pos`` and are masked like any other
-    out-of-window position. The kernel reads the cache through its
-    ``(N, L, H*D)`` view (heads folded into the lane axis — see
-    :func:`_decode_kernel`)."""
+    out-of-window position. The kernel reads the cache as
+    ``(N, L, H*D)`` (heads folded into the lane axis — see
+    :func:`_decode_kernel`), which is how the pool stores it."""
     from jax.experimental.pallas import tpu as pltpu
 
     from bigdl_tpu.utils.compat import pallas_tpu_compiler_params

@@ -9,7 +9,8 @@ step means admission and eviction never change tensor shapes — the XLA
 program is compiled once and reused for the engine's whole lifetime.
 
 The pool's tensors ARE a :func:`make_batch_decode_step` carry (same
-``pos``/``k{i}``/``v{i}`` layout), so the engine hands ``pool.carry``
+``pos``/``k{i}``/``v{i}`` layout, K/V rows ``(n_slots, max_len,
+heads*head_dim)``), so the engine hands ``pool.carry``
 straight to the step function and stores the returned carry back.
 """
 
@@ -193,7 +194,7 @@ class KVPool:
                 prefill_carry[key], row, 1, axis=0
             ).astype(carry[key].dtype)
             out[key] = lax.dynamic_update_slice(
-                carry[key], src, (slot, 0, 0, 0))
+                carry[key], src, (slot,) + (0,) * (carry[key].ndim - 1))
             # int8 layout: the row's (1, heads) dequant scales land
             # with it — a quantized row is meaningless without them
             skey = f"{key}_scale"

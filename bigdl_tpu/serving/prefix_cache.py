@@ -27,7 +27,7 @@ Lifecycle / invariants (pinned by tests/test_serving_admission.py):
 * ``insert(tokens, carry)`` stores a carry, splitting radix edges as
   needed; re-inserting an existing prefix just refreshes its LRU slot;
 * capacity is counted in ENTRIES (each entry is one full B=1 carry —
-  ``2 * n_layers * max_len * heads * head_dim`` cache elements — so
+  ``2 * n_layers`` arrays of ``(1, max_len, heads*head_dim)`` — so
   entry count, not token count, is what bounds memory). When over
   ``max_entries``, the least-recently-used carry with ``refs == 0`` is
   dropped and carry-less leaf chains are pruned; if every entry is

@@ -376,7 +376,8 @@ def phase_kernels(size, interpret):
     import jax.numpy as jnp
 
     from bigdl_tpu.ops.decode_attention import (
-        decode_attention_reference, pooled_decode_attention)
+        decode_attention_reference, folded_decode_attention,
+        pooled_decode_attention)
     from bigdl_tpu.ops.flash_attention import flash_attention
     from bigdl_tpu.parallel.ring_attention import attention
 
@@ -417,8 +418,15 @@ def phase_kernels(size, interpret):
     kernel = jax.jit(lambda *a, **kw: pooled_decode_attention(
         *a, interpret=interpret, **kw))
     ref = jax.jit(decode_attention_reference)
-    out["decode_bf16"] = excess(kernel(q, k, v, pos), ref(q, k, v, pos),
+    # kernel and folded form read the pool as it is stored, (N, L, H*D);
+    # the reference reads the 4-D view
+    stored = lambda x: x.reshape(n, L, h * d)
+    want = ref(q, k, v, pos)
+    out["decode_bf16"] = excess(kernel(q, stored(k), stored(v), pos), want,
                                 KERNEL_ATOL)
+    out["decode_folded"] = excess(
+        jax.jit(folded_decode_attention)(q, stored(k), stored(v), pos), want,
+        KERNEL_ATOL)
     # per-(row, head) symmetric int8: the serving carry's layout
     k32, v32 = k.astype(jnp.float32), v.astype(jnp.float32)
     ks = jnp.max(jnp.abs(k32), axis=(1, 3)) / 127.0
@@ -426,7 +434,7 @@ def phase_kernels(size, interpret):
     kq = jnp.round(k32 / ks[:, None, :, None]).astype(jnp.int8)
     vq = jnp.round(v32 / vs[:, None, :, None]).astype(jnp.int8)
     out["decode_int8"] = excess(
-        kernel(q, kq, vq, pos, k_scale=ks, v_scale=vs),
+        kernel(q, stored(kq), stored(vq), pos, k_scale=ks, v_scale=vs),
         ref(q, kq, vq, pos, k_scale=ks, v_scale=vs), KERNEL_ATOL)
     for name, x in out.items():
         assert x <= 1.0, f"{name}: {x:.2f}x the stated tolerance"

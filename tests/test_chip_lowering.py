@@ -50,10 +50,12 @@ def test_flash_backward_lowers_for_tpu():
     assert text.count("tpu_custom_call") >= 3
 
 
+@pytest.mark.parametrize("kv_shape", [(N, L, H * D), (N, L, H, D)],
+                         ids=["stored", "view"])
 @pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.int8])
-def test_pooled_decode_attention_lowers_for_tpu(kv_dtype):
+def test_pooled_decode_attention_lowers_for_tpu(kv_dtype, kv_shape):
     q = _sds((N, H, D), jnp.bfloat16)
-    kv = _sds((N, L, H, D), kv_dtype)
+    kv = _sds(kv_shape, kv_dtype)
     pos = _sds((N,), jnp.int32)
     if kv_dtype == jnp.int8:
         scale = _sds((N, H), jnp.float32)

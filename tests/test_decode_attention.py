@@ -12,7 +12,8 @@ import numpy as np
 import pytest
 
 from bigdl_tpu.ops.decode_attention import (
-    decode_attention, decode_attention_reference, pooled_decode_attention,
+    decode_attention, decode_attention_reference, folded_decode_attention,
+    pooled_decode_attention,
 )
 
 
@@ -133,6 +134,55 @@ def test_pos_zero_attends_only_first_column():
                                    atol=2e-5, rtol=2e-5)
 
 
+# -- the stored (N, L, H*D) cache, read without a 4-D view ------------------
+
+def _folded(x):
+    n, L, h, d = x.shape
+    return x.reshape(n, L, h * d)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-6),
+                                       (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_folded_matches_reference(dtype, tol, seed):
+    """The decode steps' float read: block-diagonal query against the
+    3-D array, the reference's sum plus exact zeros. ``_pooled`` draws
+    pos 0, mid-cache and L-1."""
+    q, k, v, pos = _pooled(n=5, L=64, dtype=dtype, seed=seed)
+    ref = decode_attention_reference(q, k, v, pos, out_dtype=jnp.float32)
+    got = folded_decode_attention(q, _folded(k), _folded(v), pos,
+                                  out_dtype=jnp.float32)
+    assert got.shape == q.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               atol=tol, rtol=tol)
+
+
+def test_folded_honours_scale_and_refuses_the_view():
+    q, k, v, pos = _pooled(n=2, L=16)
+    ref = decode_attention_reference(q, k, v, pos, scale=0.3)
+    got = folded_decode_attention(q, _folded(k), _folded(v), pos, scale=0.3)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               atol=1e-6, rtol=1e-6)
+    with pytest.raises(ValueError, match="stored"):
+        folded_decode_attention(q, k, v, pos)
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+def test_stored_shape_equals_its_view(quantized):
+    """Reference and kernel take the pool's 3-D K/V as it is stored and
+    give what they give for its 4-D view, bit for bit."""
+    q, k, v, pos = _pooled()
+    ks = vs = None
+    if quantized:
+        k, v, ks, vs = _quantize(k, v)
+    for fn in (decode_attention_reference,
+               lambda *a, **kw: pooled_decode_attention(
+                   *a, interpret=True, **kw)):
+        want = fn(q, k, v, pos, k_scale=ks, v_scale=vs)
+        got = fn(q, _folded(k), _folded(v), pos, k_scale=ks, v_scale=vs)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 # -- dispatch + validation -------------------------------------------------
 
 def test_auto_impl_uses_reference_off_tpu():
@@ -161,5 +211,8 @@ def test_validation_errors():
                                    v_scale=vs[:1])
     with pytest.raises(ValueError, match="do not match q"):
         decode_attention_reference(q, k[:, :, :2], v[:, :, :2], pos)
+    with pytest.raises(ValueError, match="do not match q"):
+        decode_attention_reference(q, k.reshape(2, 16, -1)[:, :, :-1],
+                                   v.reshape(2, 16, -1)[:, :, :-1], pos)
     with pytest.raises(ValueError, match="unknown impl"):
         decode_attention(q, k, v, pos, impl="magic")

@@ -1,0 +1,92 @@
+"""The pooled decode step, compiled for a v5e without one: the program
+that holds the K/V pool every token must not re-lay it out.
+
+The pool is stored ``(n_slots, max_len, heads*head_dim)``. Stored 4-D
+(``(…, heads, 64)``), the device lays it out ``max_len``-minor, the row
+scatter wants it the other way, and the compiled step carries two
+pool-sized ``copy`` instructions per tensor per token (PERF.md, PR 27:
+30 ms of a 44.5 ms step at GPT-2-medium). Nothing on the CPU shows
+that: the local libtpu compiles the real step for a described
+``v5e:2x2`` (XLA:TPU and Mosaic, no device), and the test reads the
+compiled HLO."""
+
+import math
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from bigdl_tpu.models import TransformerLM
+from bigdl_tpu.models.transformer import (
+    make_batch_decode_step, serving_params,
+)
+from bigdl_tpu.serving.sampling import make_knob_rows
+
+N_SLOTS, MAX_LEN, HEADS, HD, VOCAB = 8, 256, 2, 64, 512
+POOL_ELEMS = N_SLOTS * MAX_LEN * HEADS * HD
+
+
+@pytest.fixture(scope="module")
+def v5e_device():
+    from jax.experimental import topologies
+
+    # what libtpu reads when it is loaded with no chip behind it
+    with pytest.MonkeyPatch.context() as mp:
+        for key, val in (("TPU_ACCELERATOR_TYPE", "v5litepod-4"),
+                         ("TPU_SKIP_MDS_QUERY", "1"),
+                         ("TPU_WORKER_HOSTNAMES", "localhost")):
+            if key not in os.environ:
+                mp.setenv(key, val)
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:      # no libtpu, or one that cannot describe
+            pytest.skip(f"libtpu gives no v5e topology here: {e}")
+    return topo.devices[0]
+
+
+def _compile_decode_step(device, kv_quant):
+    from jax.sharding import SingleDeviceSharding
+
+    sh = SingleDeviceSharding(device)
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh)
+
+    lm = TransformerLM(VOCAB, hidden_size=HEADS * HD, n_heads=HEADS,
+                       n_layers=2, max_len=MAX_LEN, output="logits")
+    step, init_carry = make_batch_decode_step(
+        lm, jnp.bfloat16, sampling=True, kv_quant=kv_quant)
+    params = jax.tree.map(sds, jax.eval_shape(
+        lambda: serving_params(lm, jnp.bfloat16)))
+    carry = jax.tree.map(sds, jax.eval_shape(lambda: init_carry(N_SLOTS)))
+    knobs = jax.tree.map(sds, make_knob_rows(N_SLOTS, vocab=VOCAB))
+    return step.lower(params, sds(jnp.zeros((N_SLOTS,), jnp.int32)),
+                      sds(jnp.zeros((N_SLOTS,), bool)), carry,
+                      knobs).compile()
+
+
+@pytest.mark.parametrize("kv_quant", [False, True], ids=["bf16", "int8"])
+def test_decode_step_never_copies_the_pool(v5e_device, kv_quant, monkeypatch):
+    if kv_quant:
+        # the chip's int8 read is the Pallas kernel; on this host the
+        # dispatch probe would pick the jnp reference
+        monkeypatch.setattr("bigdl_tpu.utils.compat.auto_interpret",
+                            lambda: False)
+    compiled = _compile_decode_step(v5e_device, kv_quant)
+    text = compiled.as_text()
+    if kv_quant:
+        assert "pooled_decode_attention" in text
+    pool_copies = [
+        m.group(0)
+        for m in re.finditer(r"= \w+\[([\d,]+)\]\S* copy\(", text)
+        if math.prod(map(int, m.group(1).split(","))) == POOL_ELEMS]
+    assert not pool_copies, pool_copies
+    # the donated carry comes back in the layout it arrived in
+    carry_in = compiled.input_formats[0][3]
+    carry_out = compiled.output_formats[2]
+    for key in ("k0", "v0", "k1", "v1"):
+        assert carry_in[key].layout == carry_out[key].layout, key
+        assert carry_in[key].layout.major_to_minor == (0, 1, 2), key

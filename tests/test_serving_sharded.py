@@ -199,7 +199,7 @@ def test_kvpool_repr_and_occupancy_guard():
 
     def init(n):
         return {"pos": jnp.zeros((n,), jnp.int32),
-                "k0": jnp.zeros((n, 4, 2, 2)), "v0": jnp.zeros((n, 4, 2, 2))}
+                "k0": jnp.zeros((n, 4, 4)), "v0": jnp.zeros((n, 4, 4))}
 
     pool = KVPool(init, 2)
     r = repr(pool)
