@@ -848,6 +848,14 @@ _STEP_GETTERS = {
     "bigdl_tpu.models.transformer.get_batch_prefill_step": None,
 }
 
+#: the same steps reached through the model-family seam
+#: (serving/family.py): ``family.<method>(...)`` on any receiver
+_STEP_METHODS = {
+    "decode_step": 0,
+    "batch_prefill_step": None,
+    "prefill_step": None,
+}
+
 #: fallback pooled-carry key schema, used only when the scan does not
 #: include models/transformer.py (single-file fixture runs): must match
 #: what _serving_init_carry declares
@@ -948,10 +956,14 @@ def _step_binding_facts(ctx: FileContext) -> Dict:
     for node in ctx.by_type(ast.Assign):
         if not isinstance(node.value, ast.Call):
             continue
-        q = ctx.qualname(node.value.func)
-        if q not in _STEP_GETTERS:
+        func = node.value.func
+        q = ctx.qualname(func)
+        if q in _STEP_GETTERS:
+            idx = _STEP_GETTERS[q]
+        elif isinstance(func, ast.Attribute) and func.attr in _STEP_METHODS:
+            q, idx = f"family.{func.attr}", _STEP_METHODS[func.attr]
+        else:
             continue
-        idx = _STEP_GETTERS[q]
         for t in node.targets:
             target = t
             if idx is not None:

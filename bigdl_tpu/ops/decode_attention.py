@@ -152,21 +152,40 @@ def folded_decode_attention(q, k, v, pos, scale: Optional[float] = None,
     with extra zero addends: same ``scale``-then-cast query, f32
     accumulation, ``-1e30`` mask and softmax, ``p`` cast to the cache
     dtype. ``q``: (N, H, D); ``pos``: (N,) inclusive last column.
-    Returns (N, H, D) in ``out_dtype`` (default: q's dtype)."""
-    _check_qkv(q, k, v, None, None)
+    Returns (N, H, D) in ``out_dtype`` (default: q's dtype).
+
+    GROUPED queries: a cache of ``G < H`` heads ``(N, L, G*D)`` serves
+    query head ``j`` from K/V head ``j // (H // G)`` the same way:
+    column ``j`` of the query matrix holds ``q[j]`` in its K/V head's
+    lanes, and head ``j``'s context is that head's block of row ``j``."""
+    n, h, d = q.shape
     if k.ndim != 3:
         raise ValueError(
             f"the folded form reads the stored (N, L, H*D) cache, got "
             f"{k.shape}")
-    n, h, d = q.shape
+    g = k.shape[-1] // d
+    if g == h:
+        _check_qkv(q, k, v, None, None)
+    elif k.shape != v.shape or k.shape[0] != n or k.shape[-1] != g * d \
+            or g == 0 or h % g:
+        raise ValueError(
+            f"k/v {k.shape}/{v.shape} hold no whole number of K/V heads "
+            f"that divides q's {h} heads of {d}")
     L = k.shape[1]
     if scale is None:
         scale = d ** -0.5
     if out_dtype is None:
         out_dtype = q.dtype
     qs = (q * scale).astype(k.dtype)
-    q_bd = (qs[:, :, :, None] * jnp.eye(h, dtype=k.dtype)[:, None, :]
-            ).reshape(n, h * d, h)
+    if g == h:
+        q_bd = (qs[:, :, :, None] * jnp.eye(h, dtype=k.dtype)[:, None, :]
+                ).reshape(n, h * d, h)
+    else:
+        # own[j, c]: query head j reads K/V head c
+        own = (jnp.arange(h)[:, None] // (h // g)
+               == jnp.arange(g)[None, :])
+        q_bd = (qs[:, :, None, :] * own.astype(k.dtype)[None, :, :, None]
+                ).transpose(0, 2, 3, 1).reshape(n, g * d, h)
     s = jnp.einsum("nlc,nch->nhl", k, q_bd,
                    preferred_element_type=jnp.float32)
     valid = jnp.arange(L)[None, None, :] <= \
@@ -174,8 +193,12 @@ def folded_decode_attention(q, k, v, pos, scale: Optional[float] = None,
     p = jax.nn.softmax(jnp.where(valid, s, _NEG_INF), axis=-1)
     full = jnp.einsum("nhl,nlc->nhc", p.astype(v.dtype), v,
                       preferred_element_type=jnp.float32)
-    # head h's context is its own D-wide block of row h
-    ctx = jnp.einsum("nhhd->nhd", full.reshape(n, h, h, d))
+    # head j's context is its own K/V head's D-wide block of row j
+    if g == h:
+        ctx = jnp.einsum("nhhd->nhd", full.reshape(n, h, h, d))
+    else:
+        ctx = jnp.einsum("nhgd,hg->nhd", full.reshape(n, h, g, d),
+                         own.astype(jnp.float32))
     return ctx.astype(out_dtype)
 
 

@@ -3,7 +3,19 @@
 The serving layer between the model zoo and the parallel stack: many
 independent generation requests share ONE pooled, slot-indexed KV cache
 and ONE compiled per-row decode program, with FIFO admission into rows
-freed mid-flight (continuous batching). Decoding is sampled PER ROW
+freed mid-flight (continuous batching). The engine serves any model
+FAMILY that brings its programs through ``family.py``: ``TransformerLM``
+by delegation to ``models/transformer.py``, and ``models/falcon_h1.py``
+(a Mamba-2 mixer beside grouped-query attention in every layer), whose
+carry holds per-slot state leaves beside ``k{i}``/``v{i}`` — the
+float32 scan state ``ssm{i}`` and the convolution window ``conv{i}`` —
+that the pool scatters at admission, zeroes on ``free()``, carries
+through ``row_state``/``restore_row`` and counts in
+``state_bytes_per_slot``. That family REFUSES, with a ``ValueError`` at
+construction that names the option: ``prefix_cache``, ``speculative``,
+``adapters``, ``kv_dtype="int8"``, ``mesh``/``parallelism``,
+``admission="chunked"``/``"per_request"`` and ``tier`` (so also
+``DisaggregatedEngine``, which is always tiered). Decoding is sampled PER ROW
 (``sampling.py``): each request's ``SamplingParams`` (temperature,
 top-k/top-p, penalties, seed, stop sets) ride as per-row runtime arrays
 of the one compiled step — greedy and sampled requests mix freely with

@@ -1,4 +1,9 @@
-"""Continuous-batching serving engine for :func:`TransformerLM`.
+"""Continuous-batching serving engine for :func:`TransformerLM` and any
+other model family that brings its decode and prefill programs through
+``serving/family.py`` (``models/falcon_h1.py``: recurrent state beside
+K/V in the same pool; it refuses, by name at construction, the prefix
+cache, speculation, adapters, int8 K/V, a mesh, chunked and per-request
+admission and the host tier).
 
 ``generate()`` runs one request per call with a private KV carry and
 pays the full weight-read bandwidth per token for a single row.
@@ -270,17 +275,22 @@ class ServingEngine:
         import jax
         import jax.numpy as jnp
 
-        from bigdl_tpu.models.transformer import (
-            get_batch_decode_step, get_batch_prefill_step, get_prefill_step,
-            serving_params,
-        )
         from bigdl_tpu.serving.admission import AdmissionController
+        from bigdl_tpu.serving.family import check_options, family_of
         from bigdl_tpu.serving.prefix_cache import PrefixCache
 
         if admission not in ("batched", "per_request", "chunked"):
             raise ValueError(
                 f"unknown admission mode {admission!r} "
                 "(one of 'batched', 'per_request', 'chunked')")
+        # THE seam to the model's family (serving/family.py): its
+        # programs, its carry layout, and the options it refuses
+        family = self._family = family_of(model)
+        check_options(
+            family, prefix_cache=prefix_cache, speculative=speculative,
+            adapters=adapters, mesh=mesh, parallelism=parallelism,
+            tier=tier, kv_dtype=kv_dtype if kv_dtype == "int8" else None,
+            admission=admission if admission != "batched" else None)
         if chunk_budget is not None:
             if admission != "chunked":
                 raise ValueError(
@@ -339,8 +349,8 @@ class ServingEngine:
             else bool(preemption)
         model._ensure_params()
         self.model = model
-        self.max_len = model.modules[1].max_len
-        self._vocab = model.modules[0].n_index   # step-health token range
+        self.max_len = family.max_len
+        self._vocab = family.vocab               # step-health token range
         self.compute_dtype = compute_dtype
         # KV storage format: None follows compute_dtype (the status quo);
         # "int8" switches the pooled cache to the quantized layout
@@ -390,7 +400,7 @@ class ServingEngine:
         # weights as resident device buffers in the serving dtype
         # (runtime arguments — never baked into the compiled programs);
         # tensor-parallel planes pre-shard them over the model axis
-        sp = serving_params(model, compute_dtype)
+        sp = family.params(compute_dtype)
         self.params = (jax.device_put(sp) if self._plane is None
                        else self._plane.place_params(model, sp))
         # the SAMPLED pooled step is the only decode program: greedy
@@ -405,10 +415,9 @@ class ServingEngine:
         tp = self._plane is not None and self._plane.tensor_parallel
         if speculative is None:
             self._spec = None
-            self._step_fn, pool_init = get_batch_decode_step(
-                model, compute_dtype, sampling=True,
-                mesh=self.mesh if tp else None, kv_quant=kv_quant,
-                adapter=self._adapter_spec)
+            self._step_fn, pool_init = family.decode_step(
+                compute_dtype, mesh=self.mesh if tp else None,
+                kv_quant=kv_quant, adapter=self._adapter_spec)
         else:
             from bigdl_tpu.serving.speculative import Speculator
 
@@ -454,7 +463,8 @@ class ServingEngine:
                                         self._plane.model_shards)
         # KV-format observability: bytes one slot owns + the derived
         # effective-capacity number (slots a GiB of HBM would hold)
-        self.metrics.set_kv_format(kv_dtype, self.pool.kv_bytes_per_slot)
+        self.metrics.set_kv_format(kv_dtype, self.pool.kv_bytes_per_slot,
+                                   self.pool.state_bytes_per_slot)
         self.admission = admission
         self.keep_finished = keep_finished
         self.seed = int(seed)
@@ -511,8 +521,8 @@ class ServingEngine:
             # the sampling carry leaves in its shard_map specs); data-
             # only planes keep the stock prefill — its output rows
             # reshard into the sharded pool through the scatter
-            self._batch_prefill_fn = get_batch_prefill_step(
-                model, compute_dtype, mesh=self.mesh if tp else None,
+            self._batch_prefill_fn = family.batch_prefill_step(
+                compute_dtype, mesh=self.mesh if tp else None,
                 carry_sampling=tp, kv_quant=kv_quant,
                 adapter=self._adapter_spec)
             # True -> default cache, False/None -> off, else an instance
@@ -543,8 +553,8 @@ class ServingEngine:
                     "from a cached carry)")
             self.prefix_cache = None
             self.admitter = None
-            self._prefill_fn = get_prefill_step(model, compute_dtype,
-                                                kv_quant=kv_quant)
+            self._prefill_fn = family.prefill_step(compute_dtype,
+                                                   kv_quant=kv_quant)
             # ONE fresh B=1 carry for prefill, built once and reused for
             # every admission (prefill returns a new carry; jax arrays
             # are immutable, so sharing the zero input is free — at 137M
@@ -1067,6 +1077,13 @@ class ServingEngine:
         used += sum(int(self.pool.chunk_done[slot])
                     for slot in sched.partial)
         return used / (self.pool.n_slots * self.pool.max_len)
+
+    def _state_in_use(self) -> Optional[int]:
+        """Bytes of per-slot ``state`` leaves the in-use slots hold
+        (each holds all of its own, whatever its position), from host
+        state alone; None for a family that keeps none."""
+        per_slot = self.pool.state_bytes_per_slot
+        return per_slot * self.pool.used_slots if per_slot else None
 
     def _admitted_prefill_tokens(self, req: Request) -> List[int]:
         """0-based tokens whose K/V must be resident before ``req``
@@ -1612,7 +1629,8 @@ class ServingEngine:
             self.metrics.on_step(self.scheduler.queue_depth,
                                  self.pool.occupancy(),
                                  int(entry.active.sum()),
-                                 kv_used_share=self._kv_used_share())
+                                 kv_used_share=self._kv_used_share(),
+                                 state_in_use_bytes=self._state_in_use())
             self.metrics.on_sample_rows(entry.n_sampled,
                                         len(entry.rows) - entry.n_sampled)
             for slot, req in list(rows.items()):

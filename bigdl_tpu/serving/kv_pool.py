@@ -8,19 +8,53 @@ per-call private carries ``generate()`` builds. One pool + one compiled
 step means admission and eviction never change tensor shapes — the XLA
 program is compiled once and reused for the engine's whole lifetime.
 
-The pool's tensors ARE a :func:`make_batch_decode_step` carry (same
-``pos``/``k{i}``/``v{i}`` layout, K/V rows ``(n_slots, max_len,
-heads*head_dim)``), so the engine hands ``pool.carry``
-straight to the step function and stores the returned carry back.
+The pool's tensors ARE the carry of the model family's decode step
+(``serving/family.py``), so the engine hands ``pool.carry`` straight to
+the step function and stores the returned carry back. Every leaf has
+the slot as its first axis, and the pool treats each by WHAT IT IS
+(:func:`leaf_kind`), whatever family built the carry:
+
+* ``pos`` — the row's position counter;
+* ``kv`` — ``k{i}`` / ``v{i}``, position-indexed caches ``(n_slots,
+  max_len, heads*head_dim)``: admission scatters them, ``free()``
+  leaves them (stale rows are masked by ``pos``);
+* ``scale`` — ``k{i}_scale`` / ``v{i}_scale``, the int8 layout's
+  per-(slot, head) dequant scales: scattered with their rows, reset to
+  zero on ``free()`` (grow-only mid-flight);
+* ``lane`` — ``rng`` / ``tok_counts`` / ``prompt_mask``, the sampling
+  state :meth:`KVPool.write_sampling` seeds per admission;
+* ``state`` — every other per-slot leaf, state that is read WHOLE every
+  token whatever the position (a recurrent family's scan state
+  ``ssm{i}`` and convolution window ``conv{i}``,
+  ``models/falcon_h1.py``): scattered at admission like K/V, and reset
+  to zero on ``free()``, because no ``pos`` masks it and a row that
+  starts decoding without a prefill must not inherit its predecessor's.
 """
 
 from __future__ import annotations
 
+import re
 from typing import Dict, List, Optional
 
 from bigdl_tpu.serving.metrics import span
 
 _FREE_RESET = None
+
+_KV_KEY = re.compile(r"[kv]\d+")
+_SCALE_KEY = re.compile(r"[kv]\d+_scale")
+_LANE_KEYS = ("rng", "tok_counts", "prompt_mask")
+
+
+def leaf_kind(key: str) -> str:
+    """What a per-slot carry leaf is, by its key: ``"pos"``, ``"kv"``,
+    ``"scale"``, ``"lane"`` or ``"state"`` (module docstring)."""
+    if key == "pos":
+        return "pos"
+    if _KV_KEY.fullmatch(key):
+        return "kv"
+    if _SCALE_KEY.fullmatch(key):
+        return "scale"
+    return "lane" if key in _LANE_KEYS else "state"
 
 
 def _shared_free_reset():
@@ -61,7 +95,10 @@ class KVPool:
     grow-only mid-flight — a recycled slot must not inherit its
     previous occupant's range). ``kv_bytes_per_slot`` is the per-slot
     KV footprint in bytes (payload + scales) — the capacity
-    denominator behind the serving metrics and the kv_quant bench.
+    denominator behind the serving metrics and the kv_quant bench;
+    ``state_bytes_per_slot`` counts the slot's ``state`` leaves (0 for
+    a family that keeps none), which a slot holds in full whatever its
+    position.
     """
 
     def __init__(self, init_carry, n_slots: int,
@@ -80,7 +117,7 @@ class KVPool:
         self.carry = init_carry(self.n_slots)
         # k0, k1, ... — NOT k0_scale (the int8 layout's dequant scales)
         self.n_layers = sum(1 for k in self.carry
-                            if k.startswith("k") and k[1:].isdigit())
+                            if k[0] == "k" and leaf_kind(k) == "kv")
         self.max_len = int(self.carry["k0"].shape[1])
         self.quantized = "k0_scale" in self.carry
         # the storage-format knob is declarative: the carry (built by
@@ -97,13 +134,15 @@ class KVPool:
         self.kv_dtype = stored
         # bytes of KV state ONE slot owns (int8 payload + its scales,
         # or the float cache): the capacity denominator the kv_quant
-        # bench and serving/kv_bytes_per_slot metric report
-        import re
+        # bench and serving/kv_bytes_per_slot metric report; and the
+        # bytes of its other per-slot state (recurrent families)
+        def slot_bytes(*kinds):
+            return int(sum(
+                v.dtype.itemsize * int(np.prod(v.shape[1:]))
+                for k, v in self.carry.items() if leaf_kind(k) in kinds))
 
-        kv_key = re.compile(r"^[kv]\d+(_scale)?$")
-        self.kv_bytes_per_slot = int(sum(
-            v.dtype.itemsize * int(np.prod(v.shape[1:]))
-            for k, v in self.carry.items() if kv_key.match(k)))
+        self.kv_bytes_per_slot = slot_bytes("kv", "scale")
+        self.state_bytes_per_slot = slot_bytes("state")
         # LIFO free list: the most recently freed row is the most likely
         # to still be resident in cache/HBM
         self._free: List[int] = list(range(self.n_slots - 1, -1, -1))
@@ -120,17 +159,15 @@ class KVPool:
         # carries keep their mesh placement.)
         self._scatter = self._make_scatter()
         # ONE jitted, donated reset for free(): pos plus, on the int8
-        # layout, every (slot, head) dequant-scale row. Op-by-op eager
+        # layout, every (slot, head) dequant-scale row, plus every
+        # ``state`` leaf's row (module docstring). Op-by-op eager
         # .at[].set would be 1 + 2*n_layers separate device dispatches
         # (each allocating a fresh buffer) on the request-completion hot
         # path; the slot id is a traced scalar so the program compiles
         # once per pool. (_make_free_reset is the subclass hook — the
         # sharded pool pins output shardings, same as the scatter.)
-        self._reset_keys = ["pos"]
-        if self.quantized:
-            self._reset_keys += [f"{kind}{i}_scale"
-                                 for i in range(self.n_layers)
-                                 for kind in ("k", "v")]
+        self._reset_keys = [k for k in self.carry
+                            if leaf_kind(k) in ("pos", "scale", "state")]
         self._free_reset = self._make_free_reset()
         # CHUNK-PROGRESS tracking (chunked streaming admission —
         # serving/chunked.py): host-side mirrors of how much of a
@@ -182,27 +219,21 @@ class KVPool:
         # layer keys derive from the CARRY (static under trace), so one
         # impl serves both the target pool and an attached draft carry
         # (different layer counts/shapes key jit's own cache)
-        import re
-
         from jax import lax
 
         out = dict(carry)
         for key in carry:
-            if not re.fullmatch(r"[kv]\d+", key):
+            # K/V rows, the int8 layout's (1, heads) dequant scales (a
+            # quantized row is meaningless without them) and every
+            # ``state`` leaf land together; the sampling lanes are
+            # write_sampling's
+            if leaf_kind(key) not in ("kv", "scale", "state"):
                 continue
             src = lax.dynamic_slice_in_dim(
                 prefill_carry[key], row, 1, axis=0
             ).astype(carry[key].dtype)
             out[key] = lax.dynamic_update_slice(
                 carry[key], src, (slot,) + (0,) * (carry[key].ndim - 1))
-            # int8 layout: the row's (1, heads) dequant scales land
-            # with it — a quantized row is meaningless without them
-            skey = f"{key}_scale"
-            if skey in carry:
-                ssrc = lax.dynamic_slice_in_dim(
-                    prefill_carry[skey], row, 1, axis=0)
-                out[skey] = lax.dynamic_update_slice(
-                    carry[skey], ssrc, (slot, 0))
         out["pos"] = carry["pos"].at[slot].set(pos)
         return out
 
@@ -223,8 +254,9 @@ class KVPool:
         self._free.append(slot)
         # reset the row's position so a recycled slot starts fresh; the
         # stale K/V rows are harmless (masked by pos) and zeroing them
-        # would be pure HBM traffic. On the int8 layout the dequant
-        # scales reset too: scales are grow-only in-step, so a recycled
+        # would be pure HBM traffic. ``state`` leaves are zeroed: they
+        # are read whole from the first token on. On the int8 layout the
+        # dequant scales reset too: scales are grow-only in-step, so a recycled
         # slot MUST drop its previous occupant's scale — a stale large
         # scale would quantize the next request's (smaller) values
         # coarsely for its whole lifetime. One donated jitted dispatch
@@ -313,7 +345,8 @@ class KVPool:
 
     def read_row(self, slot: int) -> Dict:
         """One allocated slot's carry as a B=1 slice, every leaf (K/V
-        layers + scales, pos, sampling lanes) — the carry half of the
+        layers + scales, state leaves, pos, sampling lanes) — the
+        carry half of the
         :meth:`row_state` payload a PREEMPTED or handed-off row leaves
         behind. The slices are fresh device arrays (jax
         arrays are immutable), so they survive the slot's ``free()``
@@ -356,7 +389,8 @@ class KVPool:
         """EVERYTHING one allocated slot carries, as the canonical row
         payload (``serving/disagg.py``'s ``ROW_PAYLOAD_KEYS`` schema
         minus the request metadata): the B=1 target-carry slice from
-        :meth:`read_row` (K/V layers, int8 dequant scales, ``pos``, and
+        :meth:`read_row` (K/V layers, int8 dequant scales, ``state``
+        leaves, ``pos``, and
         — on sampling carries — the RNG lane, penalty counts, and
         prompt mask), the ``chunk_done``/``chunk_target``/``adapter``
         host mirrors,
@@ -382,8 +416,9 @@ class KVPool:
 
     def restore_row(self, slot: int, payload: Dict) -> None:
         """Scatter a :meth:`row_state` payload into an allocated slot,
-        byte-identically: K/V + scales + ``pos`` through the donated
-        admission scatter, sampling lanes/counts/mask by direct row
+        byte-identically: K/V + scales + state leaves + ``pos`` through
+        the donated admission scatter, sampling lanes/counts/mask by
+        direct row
         set (the :meth:`write_sampling` leaves, restored verbatim
         instead of rebuilt), the chunk mirrors from the payload's own
         values, and the draft slice through the draft scatter when both
@@ -408,7 +443,7 @@ class KVPool:
             # sampling lanes ride the payload (write_sampling's leaves):
             # restored verbatim, not rebuilt — the handoff receiver must
             # reproduce the sender's lane state without knowing its seed
-            for key in ("rng", "tok_counts", "prompt_mask"):
+            for key in _LANE_KEYS:
                 if key in carry and key in self.carry:
                     self.carry[key] = self.carry[key].at[slot].set(
                         jnp.asarray(carry[key])[0])

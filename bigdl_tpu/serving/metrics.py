@@ -29,6 +29,11 @@ Counters (all under the ``serving/`` prefix in the backing Metrics):
 * ``kv_used_share``     — sampled every engine step: resident K/V
   positions (sum of ``pos`` over in-use slots, from host state — no
   readback) over the ``n_slots x max_len`` the pool reserves
+* ``state_in_use_bytes`` — sampled every engine step, only where the
+  model's family keeps per-slot state beside K/V (a recurrent scan
+  state, a convolution window): in-use slots x
+  ``state_bytes_per_slot``, host state, no readback. A slot holds all
+  of its state whatever its position
 * ``fence_wait_s``      — the time the host was BLOCKED in the step's
   one fence readback (span ``fence``; the ``DEVICE_PHASES`` half of the
   ``host_step_s`` split)
@@ -187,6 +192,8 @@ KV-format counters (``serving/kv_pool.py`` — set once at construction):
 * ``kv_bits``            — bits per stored K/V element (32/16/8)
 * ``kv_bytes_per_slot``  — one slot's KV footprint in bytes (int8
   payload + per-(slot, head) scales on the quantized path)
+* ``state_bytes_per_slot`` — one slot's other per-slot state in bytes
+  (``KVPool.state_bytes_per_slot``; 0 for a family that keeps none)
 * ``kv_slots_per_gib``   — derived effective capacity: concurrent
   slots per GiB of HBM at this format (the int8 path's ~2x headline)
 """
@@ -280,7 +287,8 @@ class ServingMetrics:
         self.metrics.add("serving/queue_wait_s", float(seconds))
 
     def on_step(self, queue_depth: int, occupancy: float,
-                batch_active: int, kv_used_share: float) -> None:
+                batch_active: int, kv_used_share: float,
+                state_in_use_bytes: Optional[int] = None) -> None:
         # a declared CLOCK_SITES unit (serving/faults.py): the serve-
         # duration anchor timestamps (_t_start/_t_last span the whole
         # serve for summary()'s wall number) deliberately read the raw
@@ -295,6 +303,9 @@ class ServingMetrics:
         self.metrics.add("serving/slot_occupancy", float(occupancy))
         self.metrics.add("serving/batch_active", float(batch_active))
         self.metrics.add("serving/kv_used_share", float(kv_used_share))
+        if state_in_use_bytes is not None:
+            self.metrics.add("serving/state_in_use_bytes",
+                             float(state_in_use_bytes))
 
     def on_first_token(self, ttft_s: float) -> None:
         self.metrics.add("serving/ttft_s", float(ttft_s))
@@ -402,7 +413,8 @@ class ServingMetrics:
         self.metrics.set("serving/mesh_data_shards", float(data_shards))
         self.metrics.set("serving/mesh_model_shards", float(model_shards))
 
-    def set_kv_format(self, kv_dtype: str, bytes_per_slot: int) -> None:
+    def set_kv_format(self, kv_dtype: str, bytes_per_slot: int,
+                      state_bytes_per_slot: int = 0) -> None:
         """Record the pooled cache's storage format (once, at
         construction): bits per stored K/V element, the per-slot KV
         footprint in bytes (int8 payload + dequant scales, or the float
@@ -412,6 +424,8 @@ class ServingMetrics:
         bits = {"fp32": 32.0, "bf16": 16.0, "int8": 8.0}.get(kv_dtype, 0.0)
         self.metrics.set("serving/kv_bits", bits)
         self.metrics.set("serving/kv_bytes_per_slot", float(bytes_per_slot))
+        self.metrics.set("serving/state_bytes_per_slot",
+                         float(state_bytes_per_slot))
         self.metrics.set("serving/kv_slots_per_gib",
                          float((1 << 30) // max(int(bytes_per_slot), 1)))
 

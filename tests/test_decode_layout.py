@@ -90,3 +90,53 @@ def test_decode_step_never_copies_the_pool(v5e_device, kv_quant, monkeypatch):
     for key in ("k0", "v0", "k1", "v1"):
         assert carry_in[key].layout == carry_out[key].layout, key
         assert carry_in[key].layout.major_to_minor == (0, 1, 2), key
+
+
+def test_recurrent_family_decode_step_copies_neither_cache_nor_state(
+        v5e_device):
+    """The Falcon-H1 family's decode step at head and state widths of
+    the published model (128-wide heads, a 128 x 256 state a head): the
+    grouped-query read of the stored 3-D K/V and the in-place update of
+    the float32 scan state leave no pool-sized copy either."""
+    from jax.sharding import SingleDeviceSharding
+
+    from bigdl_tpu.models.falcon_h1 import FalconH1LM
+
+    sh = SingleDeviceSharding(v5e_device)
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh)
+
+    config = dict(
+        vocab_size=VOCAB, hidden_size=256, intermediate_size=512,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=128, mamba_d_ssm=512, mamba_n_heads=4, mamba_d_head=128,
+        mamba_d_state=256, mamba_n_groups=2, mamba_d_conv=4,
+        mamba_chunk_size=128, rms_norm_eps=1e-5, rope_theta=1e11,
+        embedding_multiplier=5.66, lm_head_multiplier=0.0078125,
+        attention_in_multiplier=1, attention_out_multiplier=0.0375,
+        key_multiplier=0.011, ssm_in_multiplier=0.25,
+        ssm_out_multiplier=0.088, ssm_multipliers=[0.35, 0.25, 0.18, 0.5,
+                                                   0.35],
+        mlp_multipliers=[0.18, 0.011])
+    lm = FalconH1LM(config, max_len=MAX_LEN, param_dtype="bfloat16")
+    family = lm.serving_family()
+    step, init_carry = family.decode_step(jnp.bfloat16)
+    params = jax.tree.map(sds, jax.eval_shape(
+        lm.init_params, jax.random.PRNGKey(0)))
+    carry = jax.tree.map(sds, jax.eval_shape(lambda: init_carry(N_SLOTS)))
+    knobs = jax.tree.map(sds, make_knob_rows(N_SLOTS, vocab=VOCAB))
+    compiled = step.lower(params, sds(jnp.zeros((N_SLOTS,), jnp.int32)),
+                          sds(jnp.zeros((N_SLOTS,), bool)), carry,
+                          knobs).compile()
+    pooled = {math.prod(carry[key].shape) for key in ("k0", "ssm0")}
+    copies = [
+        m.group(0)
+        for m in re.finditer(r"= \w+\[([\d,]+)\]\S* copy\(",
+                             compiled.as_text())
+        if math.prod(map(int, m.group(1).split(","))) in pooled]
+    assert not copies, copies
+    carry_in = compiled.input_formats[0][3]
+    carry_out = compiled.output_formats[2]
+    for key in ("k0", "v1", "ssm0", "conv1"):
+        assert carry_in[key].layout == carry_out[key].layout, key
