@@ -12,7 +12,9 @@ device mesh". ``DataSet.array(...)`` → ``LocalDataSet``;
 ``DataSet.rdd(...)`` / ``.distributed()`` → ``DistributedDataSet`` (same
 host-side iterator machinery, plus shard arithmetic). Feeding 256 chips is
 the real bottleneck at pod scale (SURVEY.md §7), so the iterator layer stays
-thin numpy and the optimizer overlaps host→device transfer with compute.
+thin numpy and the optimizer's batch feeder (``optim/feeder.py``) draws from
+it on a thread of its own: batches are built and placed on the device ahead
+of the training loop, while the step runs.
 """
 
 from __future__ import annotations

@@ -62,16 +62,29 @@ class SampleToMiniBatch(Transformer):
     def __init__(self, batch_size: int, drop_remainder: bool = True) -> None:
         self.batch_size = batch_size
         self.drop_remainder = drop_remainder
+        #: ``staging(samples) -> out`` of ``stack_samples``, read when an
+        #: iterator is made: that iterator builds its batches where
+        #: ``staging`` says, and a batch is good only until ``staging``
+        #: hands the same arrays out again. The training loop's feeder
+        #: sets it around its own ``data(train=True)`` call; left None,
+        #: every batch is made of new arrays.
+        self.staging = None
 
     def apply(self, it: Iterator[Sample]) -> Iterator[MiniBatch]:
+        return self._batches(it, self.staging)
+
+    def _batches(self, it, staging) -> Iterator[MiniBatch]:
+        def stack(buf):
+            return stack_samples(buf, out=staging(buf) if staging else None)
+
         buf = []
         for s in it:
             buf.append(s)
             if len(buf) == self.batch_size:
-                yield stack_samples(buf)
+                yield stack(buf)
                 buf = []
         if buf and not self.drop_remainder:
-            yield stack_samples(buf)
+            yield stack(buf)
 
 
 SampleToBatch = SampleToMiniBatch  # early-reference alias

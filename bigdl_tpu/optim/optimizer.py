@@ -26,6 +26,7 @@ import numpy as np
 from bigdl_tpu.dataset.dataset import AbstractDataSet, DataSet, DistributedDataSet
 from bigdl_tpu.dataset.sample import MiniBatch, Sample
 from bigdl_tpu.dataset.transformer import SampleToMiniBatch
+from bigdl_tpu.optim.feeder import BatchFeeder
 from bigdl_tpu.optim.metrics import Metrics
 from bigdl_tpu.optim.optim_method import OptimMethod, SGD
 from bigdl_tpu.optim.train_step import make_eval_step, make_train_step
@@ -116,7 +117,9 @@ def _adapt_restored_tree(template, restored, what: str, _path: str = ""):
 
 
 def _ensure_dataset(dataset, batch_size: Optional[int],
-                    drop_remainder: bool = True) -> AbstractDataSet:
+                    drop_remainder: bool = True,
+                    batcher: Optional[SampleToMiniBatch] = None
+                    ) -> AbstractDataSet:
     if dataset is None:
         raise ValueError(
             "Optimizer requires a dataset (pass dataset=...; a raw Sample "
@@ -133,8 +136,8 @@ def _ensure_dataset(dataset, batch_size: Optional[int],
         # yielding MiniBatch (Scala-style transformer chain) passes through.
         probe = next(iter(dataset.data(train=False)), None)
         if isinstance(probe, Sample):
-            dataset = dataset.transform(
-                SampleToMiniBatch(batch_size, drop_remainder=drop_remainder))
+            dataset = dataset.transform(batcher or SampleToMiniBatch(
+                batch_size, drop_remainder=drop_remainder))
     return dataset
 
 
@@ -159,7 +162,12 @@ class Optimizer:
     def __init__(self, model=None, dataset=None, criterion=None,
                  batch_size: Optional[int] = None, end_trigger=None, **kw):
         self.model = model
-        self.dataset = _ensure_dataset(dataset, batch_size)
+        # the batching stage is the optimizer's own, so that the loop's
+        # feeder can have it build batches in the feeder's arrays
+        self._batcher = None if batch_size is None else \
+            SampleToMiniBatch(batch_size)
+        self.dataset = _ensure_dataset(dataset, batch_size,
+                                       batcher=self._batcher)
         self.criterion = criterion
         self.optim_method: OptimMethod = SGD()
         self.end_when: Trigger = end_trigger or Trigger.max_epoch(1)
@@ -186,6 +194,7 @@ class Optimizer:
         self._preempt_flag = False
         self._async_ckptr = None
         self._async_pending_marker = None
+        self._feeder: Optional[BatchFeeder] = None
 
     # -- fluent config (reference names, snake_case) -----------------------
 
@@ -778,6 +787,11 @@ class Optimizer:
         try:
             return self._optimize_loop(resume)
         finally:
+            if self._feeder is not None:
+                # before the training iterator goes: a generator cannot be
+                # closed while the feeder's thread is inside it
+                self._feeder.close()
+                self._feeder = None
             if prev_sigterm is not None:
                 import signal
 
@@ -818,7 +832,16 @@ class Optimizer:
 
         base_key = RNG.next_key()
 
-        data_iter = self.dataset.data(train=True)
+        # the input path runs AHEAD of the loop on a thread of its own
+        # (optim/feeder.py): batches arrive built and placed, as far ahead
+        # as the end trigger's peek allows, so a finite or shared iterator
+        # never loses a batch to a count-based stop. Loss-triggered stops
+        # can't be predicted pre-sync and may drop what is queued.
+        # _optimize_once stops it on every way out of this function.
+        feeder = self._feeder = BatchFeeder(
+            self.dataset, self._batcher, place_batch, self.end_when, state,
+            self.metrics)
+        data_iter = feeder.data_iter
         epoch_size = self.dataset.size()
         seen_this_epoch = 0
         if resume and state["neval"] > 1:
@@ -839,16 +862,8 @@ class Optimizer:
                         f"differently sized) than the one that wrote the "
                         f"checkpoint") from None
             seen_this_epoch = state.get("seen", 0)
-        next_ready = None            # (inp, tgt, bsz) placed ahead of time
+        feeder.start(seen_this_epoch)
         epoch_start = time.time()
-
-        def fetch():
-            """The next batch, placed: the input path's share of an
-            iteration (StopIteration passes through, leaving no sample)."""
-            with self.metrics.span("train.fetch", "data fetch time"):
-                b = next(data_iter)
-                return (*place_batch(b), b.size())
-
         while not self.end_when(state):
             if self._preempt_flag:
                 self._checkpoint(
@@ -879,52 +894,36 @@ class Optimizer:
                     self._profile["active"] = False
             # one iteration = one step of the profiler's step analysis;
             # its phases below are series and profile events at once
-            # (Metrics.span). "computing time" keeps its meaning: dispatch
-            # to float(loss), with the next batch's fetch inside.
+            # (Metrics.span). "computing time" is the whole of it: the wait
+            # for the batch, the dispatch, float(loss).
             with self.metrics.span("train.iteration",
                                    step_num=state["neval"]):
-                # input pipelining: the NEXT batch is fetched/placed while the
-                # dispatched (async) step still runs on the device; float(loss)
-                # is the only host sync point
-                if next_ready is None:
-                    try:
-                        next_ready = fetch()
-                    except StopIteration:
-                        logger.warning(
-                            "data iterator exhausted before end_when fired; "
-                            "stopping. (Possible causes: the iterator yields "
-                            "fewer batches than dataset.size() implies, or a "
-                            "directly-constructed stateful Trigger without a "
-                            "side-effect-free peek_fn.)")
-                        break
-                inp, tgt, bsz = next_ready
+                # the input path's share of an iteration AS THE LOOP SEES
+                # IT: the wait for the feeder, ~0 when the batch was ready
+                # (StopIteration passes through, leaving no sample)
                 t0 = time.perf_counter()
-                # the LAUNCH of the step (with the batch's host-to-device
-                # copy where place_batch left it on the host): host time,
-                # the program's device time is the trace's jit_step
+                try:
+                    with self.metrics.span("train.fetch", "data fetch time"):
+                        inp, tgt, bsz = feeder.get()
+                except StopIteration:
+                    logger.warning(
+                        "data iterator exhausted before end_when fired; "
+                        "stopping. (Possible causes: the iterator yields "
+                        "fewer batches than dataset.size() implies, or a "
+                        "directly-constructed stateful Trigger without a "
+                        "side-effect-free peek_fn.)")
+                    break
+                # the LAUNCH of the step: host time, the program's device
+                # time is the trace's jit_step
                 with self.metrics.span("train.dispatch", "dispatch time"):
                     rng = jax.random.fold_in(base_key, state["neval"])
                     params, opt_state, model_state, loss = step(
                         params, opt_state, model_state, rng, inp, tgt,
                     )
-                # prefetch overlaps device compute — but only when the loop
-                # will actually run again, so finite/shared iterators never
-                # lose a batch to a discarded prefetch. The speculative state
-                # mirrors the counter updates below; loss-triggered stops
-                # can't be predicted pre-sync and may still prefetch once.
-                spec = dict(state)
-                spec["neval"] += 1
-                spec["epoch_finished"] = seen_this_epoch + bsz >= epoch_size
-                if spec["epoch_finished"]:
-                    spec["epoch"] += 1
-                if self.end_when.peek(spec):
-                    next_ready = None
-                else:
-                    try:
-                        next_ready = fetch()     # overlaps device compute
-                    except StopIteration:
-                        # finite custom iterators: end_when decides at loop top
-                        next_ready = None
+                # nothing for the host to do until float(loss): the feeder
+                # builds the next batch now, under the step, not beside the
+                # launch
+                feeder.launched()
                 # the loop's one sync: the host BLOCKED on the step
                 with self.metrics.span("train.loss_sync", "loss sync time"):
                     loss_f = float(loss)
@@ -1025,6 +1024,9 @@ class LocalOptimizer(Optimizer):
         )
 
         def place_batch(batch: MiniBatch):
-            return batch.get_input(), batch.get_target()
+            # on the feeder's thread: the copy to the device is done before
+            # the loop launches the step, not inside the launch
+            return (jax.device_put(batch.get_input()),
+                    jax.device_put(batch.get_target()))
 
         return step, place_batch, params, opt_state, model_state

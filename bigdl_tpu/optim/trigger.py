@@ -13,10 +13,11 @@ from typing import Callable
 
 class Trigger:
     """``fn(state) -> bool`` decides firing; ``peek_fn`` must be a
-    SIDE-EFFECT-FREE predictor of ``fn``. The optimizer calls ``peek`` on a
-    speculative post-step state to decide batch prefetch, so a stateful
-    ``fn`` used as its own peek (the default) would consume its latch on a
-    state that never becomes real. Factories below supply correct peeks;
+    SIDE-EFFECT-FREE predictor of ``fn``. The optimizer's batch feeder calls
+    ``peek`` ON ITS OWN THREAD, on speculative states a few iterations
+    ahead, to decide whether to draw another batch, so a stateful ``fn``
+    used as its own peek (the default) would consume its latch on a state
+    that never becomes real. Factories below supply correct peeks;
     directly-constructed stateful Triggers must pass ``peek_fn``
     explicitly (the optimizer also guards the loop-top ``next()`` so a
     wrong peek degrades to a clean stop, not a crash)."""
@@ -32,8 +33,8 @@ class Trigger:
     def peek(self, state) -> bool:
         """Side-effect-free evaluation: would the trigger fire on this
         state? Stateful triggers (every_epoch) must NOT consume their
-        one-shot latch here — the optimizer peeks at a speculative
-        post-step state to decide whether to prefetch the next batch."""
+        one-shot latch here — the optimizer's feeder peeks at speculative
+        states ahead of the loop to decide whether to draw the next batch."""
         return self._peek(state)
 
     def and_(self, other: "Trigger") -> "Trigger":

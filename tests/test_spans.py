@@ -265,6 +265,15 @@ def test_three_iterations_leave_equal_counts_in_every_phase(recorder):
     assert [ids["step_num"] for ids, _ in its] == [1, 2, 3]
     inside = {n for n, _, d in recorder.events if d == 1}
     assert inside == {"train.fetch", "train.dispatch", "train.loss_sync"}
+    # the feeder builds on a thread of its own and opens no span there:
+    # train.fetch, the loop's wait for it, is the one fetch span, once an
+    # iteration; the feeder's two series have a sample a batch handed over
+    # (max_iteration's peek lets it draw exactly the three)
+    assert recorder.names().count("train.fetch") == 3
+    assert len(recorder.events) == 3 * 4
+    assert opt.metrics.get("batch build time")[1] == 3
+    assert opt.metrics.get("input ready")[1] == 3
+    assert set(opt.metrics.values("input ready")) <= {0.0, 1.0}
 
 
 # -- names the benchmark's readers look for -----------------------------------
