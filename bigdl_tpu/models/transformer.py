@@ -453,27 +453,26 @@ def serving_carry_specs(model: Sequential, sampling: bool = False,
     and mixing the two spellings between placement and step output would
     double-compile the one serving program. ``kv_quant`` adds the int8
     path's ``(N, heads)`` dequant-scale leaves — their head axis shards
-    over ``model_axis`` alongside the heads they scale."""
+    over ``model_axis`` alongside the heads they scale. ``data_axis``
+    None replicates the rows: the carry of the batched prefill, whose
+    sampling leaves (``sampling``) ride through untouched but must
+    still be named."""
     from jax.sharding import PartitionSpec as P
 
-    model._ensure_params()
-    off = _decode_head_offset(model)
-    _, _, blocks, _, _ = _resolve_decode_views(model, off, model.params)
-    specs = {"pos": P(data_axis)}
-    kv = P(data_axis) if model_axis is None \
-        else P(data_axis, None, model_axis)
-    ks = P(data_axis) if model_axis is None \
-        else P(data_axis, model_axis)
-    for i in range(len(blocks)):
+    row = P() if data_axis is None else P(data_axis)
+    specs = {"pos": row}
+    kv = row if model_axis is None else P(data_axis, None, model_axis)
+    ks = row if model_axis is None else P(data_axis, model_axis)
+    for i in range(_serving_meta(model, None).n_layers):
         specs[f"k{i}"] = kv
         specs[f"v{i}"] = kv
         if kv_quant:
             specs[f"k{i}_scale"] = ks
             specs[f"v{i}_scale"] = ks
     if sampling:
-        specs["rng"] = P(data_axis)
-        specs["tok_counts"] = P(data_axis)
-        specs["prompt_mask"] = P(data_axis)
+        specs["rng"] = row
+        specs["tok_counts"] = row
+        specs["prompt_mask"] = row
     return specs
 
 
@@ -521,11 +520,8 @@ def adapter_bank_specs(model: Sequential, model_axis: str = "model"):
     chip must gather any row's factors."""
     from jax.sharding import PartitionSpec as P
 
-    model._ensure_params()
-    off = _decode_head_offset(model)
-    _, _, blocks, _, _ = _resolve_decode_views(model, off, model.params)
     specs = {}
-    for i in range(len(blocks)):
+    for i in range(_serving_meta(model, None).n_layers):
         for name in ("wq", "wk", "wv", "fc1"):
             specs[f"{name}{i}_a"] = P()
             specs[f"{name}{i}_b"] = P(None, model_axis)
@@ -558,29 +554,6 @@ def _adapter_delta(bank, site: str, ids, h, scale):
         d = jnp.einsum("nsr,nor->nso", z, b,
                        preferred_element_type=jnp.float32)
     return d * jnp.float32(scale)
-
-
-def _adapter_proj_fns(adapter, adapter_ids, bank):
-    """``(proj, rp_delta)`` for one step invocation: ``proj(p, h, site)``
-    is the serving projection plus the rows' LoRA delta (plain
-    ``_serving_proj``, site ignored, when no adapter is configured);
-    ``rp_delta(h, site)`` is the fp32 partial delta the row-parallel
-    mesh sites fold into their closing psum via ``_tp_row_proj`` (None
-    without an adapter — the projection then runs unchanged)."""
-    if adapter is None:
-        return (lambda p, h, site: _serving_proj(p, h),
-                lambda h, site: None)
-    ascale = adapter.scale
-
-    def proj(p, h, site):
-        y = _serving_proj(p, h)
-        return y + _adapter_delta(bank, site, adapter_ids, h,
-                                  ascale).astype(y.dtype)
-
-    def rp_delta(h, site):
-        return _adapter_delta(bank, site, adapter_ids, h, ascale)
-
-    return proj, rp_delta
 
 
 # Over-provision a growing scale by this factor. A requantization
@@ -687,6 +660,533 @@ def _serving_proj(p, x):
     return jnp.matmul(x, p["weight"].T) + p["bias"]
 
 
+def _tp_row_proj(p, x, axis_name: str, delta32=None):
+    """Row-parallel serving projection: this chip's partial product is
+    completed by the block's one closing psum; the bias (replicated)
+    is added once, post-psum (``parallel.tensor_parallel``'s layout).
+    Partials and the psum accumulate fp32 and round to the serving
+    dtype ONCE — matching the unsharded matmul's single rounding, so
+    bf16 TP serving stays token-aligned with the single-device engine
+    instead of drifting an ulp per psum addend. ``delta32``: an fp32
+    per-chip LoRA partial delta folded into the SAME psum (the adapter
+    path keeps the two-collectives-per-block budget; None = no-op)."""
+    import jax.numpy as jnp
+
+    from bigdl_tpu.parallel.tensor_parallel import row_parallel_linear
+
+    return row_parallel_linear(x, p["weight"], p.get("bias"), axis_name,
+                               accum_dtype=jnp.float32,
+                               partial_add=delta32)
+
+
+def _proj_fns(adapter=None, adapter_ids=None, bank=None, mesh=None,
+              model_axis=None):
+    """``(proj, row_proj)`` for one program invocation, both called as
+    ``f(p, h, site)``. ``proj`` is the serving projection (under a mesh
+    the params are per-chip column-parallel slices, head-major rows, so
+    the same call IS the column-parallel half — zero communication)
+    plus, with an ``adapter``, the rows' LoRA delta. ``row_proj`` closes
+    the attention (``wo``) and the MLP (``fc2``): the same function
+    without a mesh; under one, :func:`_tp_row_proj` — the block's two
+    collectives — with the adapter's fp32 partial delta inside its
+    psum."""
+    if adapter is None:
+        def delta(h, site):
+            return None
+
+        def proj(p, h, site):
+            return _serving_proj(p, h)
+    else:
+        def delta(h, site):
+            return _adapter_delta(bank, site, adapter_ids, h, adapter.scale)
+
+        def proj(p, h, site):
+            y = _serving_proj(p, h)
+            return y + delta(h, site).astype(y.dtype)
+
+    if mesh is None:
+        return proj, proj
+
+    def row_proj(p, h, site):
+        return _tp_row_proj(p, h, model_axis, delta32=delta(h, site))
+
+    return proj, row_proj
+
+
+def _serving_ln(ln, p, x):
+    """LayerNorm over the last axis of ``(N, Hid)`` or ``(B, L, Hid)``.
+    One query a row goes through a ``(N, 1, Hid)`` view: the operation
+    order of the decode program the cell runs, whose lowered text is
+    held fixed."""
+    if x.ndim == 2:
+        return ln.apply(p, x[:, None])[0][:, 0]
+    return ln.apply(p, x)[0]
+
+
+def _embed(lookup_w, pos_w, tokens, pos_rows):
+    """Token rows (ids clipped into the vocabulary) plus the position
+    rows of the view's queries (``pos_rows``, the first half of a
+    view)."""
+    import jax.numpy as jnp
+
+    x = jnp.take(lookup_w, jnp.clip(tokens, 0, lookup_w.shape[0] - 1),
+                 axis=0)
+    return x + pos_rows(pos_w)
+
+
+def _head(lnf, lnf_p, lin_p, x):
+    """Final LayerNorm and the LM head: logits in the serving dtype.
+    :func:`_log_probs` finishes them — apart, because the programs
+    advance ``pos`` between the two."""
+    return _serving_proj(lin_p, _serving_ln(lnf, lnf_p, x))
+
+
+def _log_probs(logits):
+    """float32 log-softmax, whatever the serving dtype."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+
+
+def _block(blk, bp, i, x, proj, row_proj, attend):
+    """THE transformer block of every serving program, over ``(N, Hid)``
+    (one query a row) and ``(B, L, Hid)`` alike: ``x + wo(attend(q, k,
+    v))`` of ``ln1(x)``, then ``x + fc2(gelu(fc1(ln2(x))))``. ``proj`` /
+    ``row_proj`` come from :func:`_proj_fns`; ``attend(i, q, k, v)`` is
+    a cache view's second half: it gets ``q`` per head ``(…, heads,
+    hd)`` and ``k``/``v`` as the stored rows ``(…, heads*hd)``, writes
+    layer ``i``'s cache leaves and returns the context ``(…,
+    heads*hd)``. A new layer kind is a fourth view or a config-driven
+    norm in here, not a sixth program body."""
+    import jax
+
+    ap = bp[blk._child_key(1)]
+    h = _serving_ln(blk.ln1, bp[blk._child_key(0)], x)
+    q = proj(ap["wq"], h, f"wq{i}").reshape(
+        *h.shape[:-1], -1, blk.attn.head_dim)
+    k = proj(ap["wk"], h, f"wk{i}")
+    v = proj(ap["wv"], h, f"wv{i}")
+    x = x + row_proj(ap["wo"], attend(i, q, k, v), f"wo{i}")
+    h2 = _serving_ln(blk.ln2, bp[blk._child_key(2)], x)
+    hmid = jax.nn.gelu(proj(bp[blk._child_key(3)], h2, f"fc1{i}"))
+    return x + row_proj(bp[blk._child_key(4)], hmid, f"fc2{i}")
+
+
+# -- the three cache views. A view is ``(pos_rows, attend)``: where a
+# query shape's tokens sit (their position-embedding rows) and, per
+# layer, how it writes the cache and what it reads back — its write
+# rule, its mask, its int8 branch. ``attend`` records the new ``k{i}`` /
+# ``v{i}`` (/ ``_scale``) leaves in ``new_carry``, the program's output
+# carry. The views stay separate on purpose: the B=1 programs are what
+# generate() runs and what the tests hold the pooled programs against,
+# and that independence lives in the write and the mask.
+
+
+def _token_view(new_carry, active, max_len, scale, cache_dtype,
+                kv_quant=False):
+    """View *token*: one query a row at the row's ``pos``, read through
+    the single-query attention over the stored cache. ``active`` None:
+    lockstep rows at the uniform ``pos[0]`` (:func:`make_decode_step`).
+    ``active`` (N,) bool: pooled rows, each at its own ``pos[r]``, the
+    inactive ones pure ballast (:func:`make_batch_decode_step`)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from bigdl_tpu.ops.decode_attention import (
+        decode_attention, folded_decode_attention,
+    )
+
+    pos = new_carry["pos"]
+    n = pos.shape[0]
+    if active is None:
+        # one dynamic_update_slice per tensor: no per-row gathers or
+        # masked scatters on the path beam_search scans over
+        t = pos[0]
+
+        def pos_rows(pos_w):
+            return lax.dynamic_index_in_dim(pos_w, t, keepdims=False)
+
+        def attend(i, q, k_new, v_new):
+            kc = lax.dynamic_update_slice_in_dim(
+                new_carry[f"k{i}"], k_new[:, None].astype(cache_dtype), t, 1)
+            vc = lax.dynamic_update_slice_in_dim(
+                new_carry[f"v{i}"], v_new[:, None].astype(cache_dtype), t, 1)
+            new_carry[f"k{i}"], new_carry[f"v{i}"] = kc, vc
+            return folded_decode_attention(
+                q, kc, vc, jnp.broadcast_to(t, (n,)), scale=scale,
+                out_dtype=q.dtype).reshape(k_new.shape)
+
+        return pos_rows, attend
+
+    rows = jnp.arange(n)
+    # write index per row: clamps to the last cache index rather than
+    # silently wrapping
+    wpos = jnp.clip(pos, 0, max_len - 1)
+
+    def pos_rows(pos_w):
+        return jnp.take(pos_w, wpos, axis=0)
+
+    def attend(i, q, k_new, v_new):
+        kc_prev, vc_prev = new_carry[f"k{i}"], new_carry[f"v{i}"]
+        if kv_quant:
+            # int8 storage: grow-only (slot, head) scale merge, then
+            # the same masked scatter contract — inactive rows have
+            # amax 0, so their scale, stored values, and the
+            # written-back old value are all bitwise untouched
+            k32 = k_new.astype(jnp.float32).reshape(q.shape)
+            v32 = v_new.astype(jnp.float32).reshape(q.shape)
+            k_amax = jnp.where(active[:, None],
+                               jnp.max(jnp.abs(k32), axis=-1), 0.0)
+            v_amax = jnp.where(active[:, None],
+                               jnp.max(jnp.abs(v32), axis=-1), 0.0)
+            (kc_prev, vc_prev, ks_new, vs_new, ks_safe,
+             vs_safe) = _kv_quant_merge_step(
+                kc_prev, vc_prev, new_carry[f"k{i}_scale"],
+                new_carry[f"v{i}_scale"], k_amax, v_amax)
+            k_wr0 = _kv_quantize(k32, ks_safe[..., None]
+                                 ).reshape(k_new.shape)
+            v_wr0 = _kv_quantize(v32, vs_safe[..., None]
+                                 ).reshape(v_new.shape)
+            new_carry[f"k{i}_scale"] = ks_new
+            new_carry[f"v{i}_scale"] = vs_new
+        else:
+            k_wr0 = k_new.astype(cache_dtype)
+            v_wr0 = v_new.astype(cache_dtype)
+        # masked per-row scatter: inactive rows write their OLD value
+        # back, so their cache stays bitwise identical
+        k_old, v_old = kc_prev[rows, wpos], vc_prev[rows, wpos]
+        k_wr = jnp.where(active[:, None], k_wr0, k_old)
+        v_wr = jnp.where(active[:, None], v_wr0, v_old)
+        kc = kc_prev.at[rows, wpos].set(k_wr)
+        vc = vc_prev.at[rows, wpos].set(v_wr)
+        new_carry[f"k{i}"], new_carry[f"v{i}"] = kc, vc
+        if kv_quant:
+            # the pooled decode op: Pallas kernel on TPU (int8 K/V
+            # loads, dequant fused as two scalar factors), jnp
+            # reference elsewhere — per-row masked single-query
+            # attention over cols 0..wpos[r]
+            ctx = decode_attention(
+                q, kc, vc, wpos, k_scale=ks_new, v_scale=vs_new,
+                scale=scale, out_dtype=q.dtype)
+        else:
+            # per-row causal mask over the row's own cache prefix, read
+            # from the stored 3-D array (a 4-D view here costs two
+            # pool-sized copies per tensor per token on the TPU);
+            # scores accumulate fp32 regardless of the serving dtype
+            ctx = folded_decode_attention(
+                q, kc, vc, wpos, scale=scale, out_dtype=q.dtype)
+        return ctx.reshape(k_new.shape)
+
+    return pos_rows, attend
+
+
+def _fresh_prompt_view(new_carry, P, scale, cache_dtype, kv_quant=False):
+    """View *fresh prompt* (:func:`make_prefill_step`): ``P`` columns
+    from position 0 of a FRESH carry, K/V written at ``0..P-1``, dense
+    causal attention over the prompt alone — ``(P, P)`` scores where the
+    window view would attend over the full ``max_len`` cache (3x the
+    attention work at P=127/max_len=384)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    causal = jnp.tril(jnp.ones((P, P), bool))
+
+    def pos_rows(pos_w):
+        return pos_w[:P]
+
+    def attend(i, q, k, v):
+        stored, dtype = k.shape, q.dtype       # (B, P, heads*hd)
+        k, v = k.reshape(q.shape), v.reshape(q.shape)
+        if kv_quant:
+            # fresh carry (pos 0, scale 0): the degenerate one-shot
+            # case of the grow-only merge — s_old is 0, so the
+            # chunk's amax sets the scale (headroom included) and
+            # the "requantized" zero cache passes through as zeros.
+            # Routing through _kv_quant_merge keeps THE one copy of
+            # the write rule honest.
+            k32, v32 = k.astype(jnp.float32), v.astype(jnp.float32)
+            kc_rq, ks, ks_safe = _kv_quant_merge(
+                new_carry[f"k{i}"], new_carry[f"k{i}_scale"],
+                jnp.max(jnp.abs(k32), axis=(1, 3)))
+            vc_rq, vs, vs_safe = _kv_quant_merge(
+                new_carry[f"v{i}"], new_carry[f"v{i}_scale"],
+                jnp.max(jnp.abs(v32), axis=(1, 3)))
+            kq = _kv_quantize(k32, ks_safe[:, None, :, None])
+            vq = _kv_quantize(v32, vs_safe[:, None, :, None])
+            # write into the REQUANTIZED cache (zeros requantize to
+            # zeros on the fresh-carry contract, so this is free
+            # here — but discarding kc_rq would silently corrupt any
+            # future warm-carry caller the pos guard can't see,
+            # e.g. under an outer trace)
+            new_carry[f"k{i}"] = lax.dynamic_update_slice_in_dim(
+                kc_rq, kq.reshape(stored), 0, 1)
+            new_carry[f"v{i}"] = lax.dynamic_update_slice_in_dim(
+                vc_rq, vq.reshape(stored), 0, 1)
+            new_carry[f"k{i}_scale"] = ks
+            new_carry[f"v{i}_scale"] = vs
+            # attend over the dequantized values decode will read
+            k = kq.astype(jnp.float32) * ks_safe[:, None, :, None]
+            v = vq.astype(jnp.float32) * vs_safe[:, None, :, None]
+            q = q.astype(jnp.float32)
+        else:
+            new_carry[f"k{i}"] = lax.dynamic_update_slice_in_dim(
+                new_carry[f"k{i}"],
+                k.astype(cache_dtype).reshape(stored), 0, 1)
+            new_carry[f"v{i}"] = lax.dynamic_update_slice_in_dim(
+                new_carry[f"v{i}"],
+                v.astype(cache_dtype).reshape(stored), 0, 1)
+        # scores accumulate fp32 like the decode step
+        s = jnp.einsum("bqhd,bkhd->bhqk", q * scale, k,
+                       preferred_element_type=jnp.float32)
+        s = jnp.where(causal[None, None], s, -1e30)
+        p = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
+                          preferred_element_type=jnp.float32
+                          ).astype(dtype).reshape(stored)
+
+    return pos_rows, attend
+
+
+def _window_view(new_carry, lengths, L, max_len, scale, cache_dtype,
+                 kv_quant=False, deferred=None):
+    """View *window* (:func:`make_batch_prefill_step`,
+    :func:`make_batch_verify_step`): ``L`` query columns a row at the
+    row's own offset ``pos[r]``, the first ``lengths[r]`` of them real,
+    attending over the row's whole cache window. Returns ``(pos_rows,
+    attend, rows, qpos)``. With ``kv_quant`` and a ``deferred`` list
+    (the verify step) ``attend`` commits nothing: it appends the layer's
+    fp32 ``(k, v)`` chunk to the list, for the caller's accepted-only
+    commit."""
+    import jax
+    import jax.numpy as jnp
+
+    start = new_carry["pos"]                       # (B,) per-row offset
+    B = start.shape[0]
+    rows = jnp.arange(B)
+    qpos = start[:, None] + jnp.arange(L)[None]    # (B, L) absolute
+    inb = jnp.arange(L)[None] < lengths[:, None]   # (B, L) valid mask
+    # pad/overflow columns scatter to index max_len → dropped; valid
+    # columns are in range (the callers' contract) and strictly
+    # increasing per row, so writes never collide
+    widx = jnp.where(inb, qpos, max_len)
+
+    def pos_rows(pos_w):
+        return jnp.take(pos_w, jnp.clip(qpos, 0, max_len - 1), axis=0)
+
+    def attend(i, q, k, v):
+        window = (B, max_len) + q.shape[2:]        # the cache, per head
+        if not kv_quant:
+            kc = new_carry[f"k{i}"].at[rows[:, None], widx].set(
+                k.astype(cache_dtype), mode="drop")
+            vc = new_carry[f"v{i}"].at[rows[:, None], widx].set(
+                v.astype(cache_dtype), mode="drop")
+            katt, vatt = kc.reshape(window), vc.reshape(window)
+            new_carry[f"k{i}"], new_carry[f"v{i}"] = kc, vc
+        else:
+            k32 = k.astype(jnp.float32).reshape(q.shape)
+            v32 = v.astype(jnp.float32).reshape(q.shape)
+            if deferred is None:
+                # int8 storage: per-(row, head) amax over the VALID
+                # columns only (pad columns must not inflate the
+                # scale), grow-only merge with the cached prefix's
+                # scale, then the same dropped-index masked scatter
+                inbf = inb[:, :, None, None]
+                k_amax = jnp.max(jnp.abs(k32) * inbf, axis=(1, 3))
+                v_amax = jnp.max(jnp.abs(v32) * inbf, axis=(1, 3))
+                kc_rq, ks_new, ks_safe = _kv_quant_merge(
+                    new_carry[f"k{i}"], new_carry[f"k{i}_scale"], k_amax)
+                vc_rq, vs_new, vs_safe = _kv_quant_merge(
+                    new_carry[f"v{i}"], new_carry[f"v{i}_scale"], v_amax)
+                kc = kc_rq.at[rows[:, None], widx].set(
+                    _kv_quantize(k32, ks_safe[:, None, :, None]
+                                 ).reshape(k.shape), mode="drop")
+                vc = vc_rq.at[rows[:, None], widx].set(
+                    _kv_quantize(v32, vs_safe[:, None, :, None]
+                                 ).reshape(v.shape), mode="drop")
+                new_carry[f"k{i}_scale"] = ks_new
+                new_carry[f"v{i}_scale"] = vs_new
+                # the prompt attends over the DEQUANTIZED cache — the
+                # values decode-time reads will see, so prefill and
+                # decode stay one consistent numerics story
+                katt = kc.reshape(window).astype(
+                    jnp.float32) * ks_new[:, None, :, None]
+                vatt = vc.reshape(window).astype(
+                    jnp.float32) * vs_new[:, None, :, None]
+                new_carry[f"k{i}"], new_carry[f"v{i}"] = kc, vc
+            else:
+                # the stored cache dequantized at its CURRENT
+                # (pre-merge) scales with the chunk's own FLOAT K/V
+                # overlaid in place
+                ks_old = new_carry[f"k{i}_scale"]
+                vs_old = new_carry[f"v{i}_scale"]
+                katt = (new_carry[f"k{i}"].reshape(window).astype(
+                            jnp.float32) * ks_old[:, None, :, None]).at[
+                                rows[:, None], widx].set(k32, mode="drop")
+                vatt = (new_carry[f"v{i}"].reshape(window).astype(
+                            jnp.float32) * vs_old[:, None, :, None]).at[
+                                rows[:, None], widx].set(v32, mode="drop")
+                deferred.append((k32, v32))
+        # queries attend over the row's FULL cache window (cached
+        # prefix + this chunk) under an absolute causal mask; scores
+        # accumulate fp32 regardless of the serving dtype
+        att_dtype = jnp.float32 if kv_quant else cache_dtype
+        qatt = (q * scale).astype(att_dtype)
+        s = jnp.einsum("blhd,bmhd->bhlm", qatt, katt,
+                       preferred_element_type=jnp.float32)
+        valid = (jnp.arange(max_len)[None, None, None, :]
+                 <= qpos[:, None, :, None])
+        s = jnp.where(valid, s, -1e30)
+        p = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("bhlm,bmhd->blhd", p.astype(att_dtype), vatt,
+                          preferred_element_type=jnp.float32
+                          ).astype(q.dtype).reshape(k.shape)
+
+    return pos_rows, attend, rows, qpos
+
+
+def _check_tp_divisibility(model: Sequential, heads: int, tp: int) -> None:
+    """Fail fast (with the fix in the message) when a model cannot split
+    over a ``tp``-way model axis: whole heads and whole MLP hidden rows
+    must land on each chip."""
+    if tp <= 0:
+        raise ValueError(f"model-axis size must be positive, got {tp}")
+    hidden = model.modules[1].hidden_size
+    mlp_hidden = None
+    for m in model.modules:
+        inner = m.modules[0] if isinstance(m, Remat) else m
+        if isinstance(inner, TransformerBlock):
+            mlp_hidden = inner.fc1.output_size
+            break
+    if heads % tp:
+        raise ValueError(
+            f"n_heads {heads} not divisible by the model-axis size {tp} "
+            "— tensor-parallel serving shards whole heads")
+    if mlp_hidden is not None and mlp_hidden % tp:
+        raise ValueError(
+            f"MLP hidden {mlp_hidden} not divisible by the model-axis "
+            f"size {tp}")
+    if hidden % tp:
+        raise ValueError(
+            f"hidden {hidden} not divisible by the model-axis size {tp}")
+
+
+def _serving_meta(model: Sequential, compute_dtype, mesh=None,
+                  model_axis: str = "model"):
+    """What every serving factory reads off the model at build time,
+    from the UNCAST params (structure only, no weight copy). With a
+    ``mesh``, also the fail-fast check that the model splits over its
+    model axis."""
+    from types import SimpleNamespace
+
+    import jax.numpy as jnp
+
+    from bigdl_tpu.nn.misc import LookupTable
+
+    model._ensure_params()
+    mods = model.modules
+    assert isinstance(mods[0], LookupTable), "TransformerLM-shaped model"
+    off = _decode_head_offset(model)
+    blocks0 = _resolve_decode_views(model, off, model.params)[2]
+    attn0 = blocks0[0][0].attn
+    if mesh is not None:
+        _check_tp_divisibility(model, attn0.n_heads,
+                               int(mesh.shape[model_axis]))
+    return SimpleNamespace(
+        off=off, lnf=mods[-2 - off], n_layers=len(blocks0),
+        max_len=mods[1].max_len, vocab=mods[0].n_index,
+        heads=attn0.n_heads, hd=attn0.head_dim,
+        scale=attn0.head_dim ** -0.5,
+        cache_dtype=compute_dtype or jnp.float32)
+
+
+def _captured_params(model: Sequential, compute_dtype):
+    """``get()`` -> the build-time weights cast for serving, made on the
+    first call only: the ``params=None`` mode of the B=1 steps, which
+    bakes them into the program as constants."""
+    cache: list = []
+
+    def get():
+        if not cache:
+            cache.append(_cast_keep_scales(model.params, compute_dtype))
+        return cache[0]
+
+    return get
+
+
+def _shard_step(fn, model: Sequential, mesh, data_axis, model_axis: str,
+                cspecs, n_out: int, knobs: bool = False, adapter=None):
+    """THE ``shard_map`` lowering of the pooled programs — the
+    tensor-parallel serving plane (``bigdl_tpu.serving.sharded``). Each
+    takes ``(params, rows, rows, carry[, knobs][, adapter_ids, bank])``
+    and returns ``n_out`` row arrays and the carry; ``cspecs`` is the
+    carry's spec tree (:func:`serving_carry_specs`). Params shard
+    Megatron-style (:func:`tp_param_specs`) and so does the adapter
+    bank with the weights it adapts; row arrays, knobs and adapter ids
+    shard over ``data_axis``, or replicate when it is None (the batched
+    prefill: its rows are few and short-lived, so sharding them would
+    buy little and break the B=1 prefix-cache path).
+
+    check_vma off: sampled tokens and non-head state are REPLICATED over
+    the model axis (every model chip computes the identical post-psum
+    value deterministically), which the static replication checker
+    cannot prove through the sampler's vmapped random.split."""
+    from jax.sharding import PartitionSpec as P
+
+    from bigdl_tpu.serving.sampling import knob_partition_specs
+    from bigdl_tpu.utils.compat import shard_map
+
+    row = P() if data_axis is None else P(data_axis)
+    in_specs = (tp_param_specs(model, model_axis), row, row, cspecs)
+    if knobs:
+        in_specs += (knob_partition_specs(data_axis),)
+    if adapter is not None:
+        in_specs += (row, adapter_bank_specs(model, model_axis))
+    return shard_map(fn, mesh=mesh, in_specs=in_specs,
+                     out_specs=(row,) * n_out + (cspecs,), check_vma=False)
+
+
+def _serving_init_carry(n_layers: int, max_len: int, heads: int, hd: int,
+                        cache_dtype, kv_quant: bool, sampling: bool,
+                        vocab: int):
+    """THE one pooled-carry layout: per-layer K/V rows
+    ``(n_slots, max_len, heads*hd)`` (head-major lanes: the
+    ``(…, heads, hd)`` array with its two minor axes merged, which is
+    the shape the decode step's row scatter and whole-pool read agree
+    on — no program that holds the pool re-lays it out) + per-row
+    ``pos``, int8 dequant scales on the quantized layout, and the per-row
+    sampling state (RNG lanes + penalty counters — the engine seeds rows
+    at admission via ``KVPool.write_sampling``). Shared by
+    :func:`make_batch_decode_step` and :func:`make_batch_verify_step` so
+    a pool built by either hands its carry to the other unchanged (the
+    speculative engine's verify step IS its decode step)."""
+    import jax.numpy as jnp
+
+    def init_carry(n_slots: int):
+        carry = {"pos": jnp.zeros((n_slots,), jnp.int32)}
+        kv_dt = jnp.int8 if kv_quant else cache_dtype
+        for i in range(n_layers):
+            carry[f"k{i}"] = jnp.zeros((n_slots, max_len, heads * hd),
+                                       kv_dt)
+            carry[f"v{i}"] = jnp.zeros((n_slots, max_len, heads * hd),
+                                       kv_dt)
+            if kv_quant:
+                # per-(slot, head) dequant scales; 0 = "no scale yet"
+                # (fresh rows — the first write establishes it)
+                carry[f"k{i}_scale"] = jnp.zeros((n_slots, heads),
+                                                 jnp.float32)
+                carry[f"v{i}_scale"] = jnp.zeros((n_slots, heads),
+                                                 jnp.float32)
+        if sampling:
+            carry["rng"] = jnp.zeros((n_slots, 2), jnp.uint32)
+            carry["tok_counts"] = jnp.zeros((n_slots, vocab), jnp.int32)
+            carry["prompt_mask"] = jnp.zeros((n_slots, vocab), bool)
+        return carry
+
+    return init_carry
+
+
 def make_prefill_step(model: Sequential, compute_dtype=None,
                       kv_quant: bool = False):
     """ONE-pass prompt ingestion for the KV-cached decoder (the serving
@@ -716,120 +1216,38 @@ def make_prefill_step(model: Sequential, compute_dtype=None,
     benchmarks/decode_bench.py). ``params`` follows the same runtime-
     argument convention as the decode step (``serving_params``).
 
-    ``kv_quant=True`` writes the cache int8 with (row, head) scales —
-    the fresh-carry contract makes this the degenerate one-shot case of
-    the grow-only merge (old scale is 0, so the written chunk's amax IS
-    the scale) — and runs the prompt's own attention over the
-    dequantized values, mirroring :func:`make_batch_prefill_step`."""
+    ``kv_quant=True`` writes the cache int8 with (row, head) scales and
+    runs the prompt's own attention over the dequantized values,
+    mirroring :func:`make_batch_prefill_step`. The block is
+    :func:`_block` under the *fresh prompt* view
+    (:func:`_fresh_prompt_view`); test_prefill_matches_sequential_decode
+    holds cache and logits against the decode step for plain, bf16 and
+    int8 models."""
     import jax
     import jax.numpy as jnp
-    from jax import lax
 
-    from bigdl_tpu.nn.misc import LookupTable
+    m = _serving_meta(model, compute_dtype)
+    get_p0 = _captured_params(model, compute_dtype)
+    proj, row_proj = _proj_fns()
 
-    model._ensure_params()
-    mods = model.modules
-    assert isinstance(mods[0], LookupTable), "TransformerLM-shaped model"
-    max_len = mods[1].max_len
-    off = _decode_head_offset(model)
-    lnf = mods[-2 - off]
-    _, _, blocks0, _, _ = _resolve_decode_views(model, off, model.params)
-    attn0 = blocks0[0][0].attn
-    heads, hd = attn0.n_heads, attn0.head_dim
-    scale = hd ** -0.5
-    cache_dtype = compute_dtype or jnp.float32
-    _p0_cache: list = []
-
-    def get_p0():
-        if not _p0_cache:
-            _p0_cache.append(_cast_keep_scales(model.params, compute_dtype))
-        return _p0_cache[0]
-
-    # NOTE: the per-block body below intentionally parallels (not shares)
-    # make_decode_step's loop — a length-generic unification would make
-    # prefill attend over the full max_len cache instead of the P-sized
-    # prompt (3x the attention work at P=127/max_len=384). The drift risk
-    # is pinned by test_prefill_matches_sequential_decode, which asserts
-    # cache/logit equality against the decode step for plain, bf16 and
-    # int8 models.
     def prefill(params, tokens, carry):
         Pt = get_p0() if params is None else \
             _cast_keep_scales(params, compute_dtype)
         lookup_w, pos_w, blocks, lnf_p, lin_p = \
-            _resolve_decode_views(model, off, Pt)
-        B, P = tokens.shape
-        if P > max_len:
-            raise ValueError(f"prompt length {P} exceeds max_len {max_len}")
-        x = jnp.take(lookup_w, jnp.clip(tokens, 0, lookup_w.shape[0] - 1),
-                     axis=0)                          # (B, P, Hid)
-        x = x + pos_w[:P]
-        causal = jnp.tril(jnp.ones((P, P), bool))
+            _resolve_decode_views(model, m.off, Pt)
+        P = tokens.shape[1]
+        if P > m.max_len:
+            raise ValueError(
+                f"prompt length {P} exceeds max_len {m.max_len}")
         new_carry = dict(carry)
+        pos_rows, attend = _fresh_prompt_view(
+            new_carry, P, m.scale, m.cache_dtype, kv_quant)
+        x = _embed(lookup_w, pos_w, tokens, pos_rows)     # (B, P, Hid)
         for i, (blk, bp) in enumerate(blocks):
-            h, _ = blk.ln1.apply(bp[blk._child_key(0)], x)
-            ap = bp[blk._child_key(1)]
-            q = _serving_proj(ap["wq"], h).reshape(B, P, heads, hd)
-            k = _serving_proj(ap["wk"], h).reshape(B, P, heads, hd)
-            v = _serving_proj(ap["wv"], h).reshape(B, P, heads, hd)
-            if kv_quant:
-                # fresh carry (pos 0, scale 0): the degenerate one-shot
-                # case of the grow-only merge — s_old is 0, so the
-                # chunk's amax sets the scale (headroom included) and
-                # the "requantized" zero cache passes through as zeros.
-                # Routing through _kv_quant_merge keeps THE one copy of
-                # the write rule honest.
-                k32, v32 = k.astype(jnp.float32), v.astype(jnp.float32)
-                kc_rq, ks, ks_safe = _kv_quant_merge(
-                    new_carry[f"k{i}"], new_carry[f"k{i}_scale"],
-                    jnp.max(jnp.abs(k32), axis=(1, 3)))
-                vc_rq, vs, vs_safe = _kv_quant_merge(
-                    new_carry[f"v{i}"], new_carry[f"v{i}_scale"],
-                    jnp.max(jnp.abs(v32), axis=(1, 3)))
-                kq = _kv_quantize(k32, ks_safe[:, None, :, None])
-                vq = _kv_quantize(v32, vs_safe[:, None, :, None])
-                # write into the REQUANTIZED cache (zeros requantize to
-                # zeros on the fresh-carry contract, so this is free
-                # here — but discarding kc_rq would silently corrupt any
-                # future warm-carry caller the pos guard can't see,
-                # e.g. under an outer trace)
-                new_carry[f"k{i}"] = lax.dynamic_update_slice_in_dim(
-                    kc_rq, kq.reshape(B, P, heads * hd), 0, 1)
-                new_carry[f"v{i}"] = lax.dynamic_update_slice_in_dim(
-                    vc_rq, vq.reshape(B, P, heads * hd), 0, 1)
-                new_carry[f"k{i}_scale"] = ks
-                new_carry[f"v{i}_scale"] = vs
-                # attend over the dequantized values decode will read
-                k = kq.astype(jnp.float32) * ks_safe[:, None, :, None]
-                v = vq.astype(jnp.float32) * vs_safe[:, None, :, None]
-                q = q.astype(jnp.float32)
-            else:
-                # the carry stores (B, max_len, heads*hd): the rows go
-                # in as projected, the 4-D view is the attention's own
-                new_carry[f"k{i}"] = lax.dynamic_update_slice_in_dim(
-                    new_carry[f"k{i}"],
-                    k.astype(cache_dtype).reshape(B, P, heads * hd), 0, 1)
-                new_carry[f"v{i}"] = lax.dynamic_update_slice_in_dim(
-                    new_carry[f"v{i}"],
-                    v.astype(cache_dtype).reshape(B, P, heads * hd), 0, 1)
-            # dense causal attention over the prompt (P is prompt-sized;
-            # scores accumulate fp32 like the decode step)
-            s = jnp.einsum("bqhd,bkhd->bhqk", q * scale, k,
-                           preferred_element_type=jnp.float32)
-            s = jnp.where(causal[None, None], s, -1e30)
-            p = jax.nn.softmax(s, axis=-1)
-            ctx = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
-                             preferred_element_type=jnp.float32
-                             ).astype(x.dtype).reshape(B, P, heads * hd)
-            x = x + _serving_proj(ap["wo"], ctx)
-            h2, _ = blk.ln2.apply(bp[blk._child_key(2)], x)
-            mlp = _serving_proj(bp[blk._child_key(4)], jax.nn.gelu(
-                _serving_proj(bp[blk._child_key(3)], h2)))
-            x = x + mlp
-        xf, _ = lnf.apply(lnf_p, x[:, -1:])           # last position only
-        logits = _serving_proj(lin_p, xf[:, 0])
+            x = _block(blk, bp, i, x, proj, row_proj, attend)
+        logits = _head(m.lnf, lnf_p, lin_p, x[:, P - 1])  # last position
         new_carry["pos"] = jnp.full_like(carry["pos"], P)
-        return jax.nn.log_softmax(logits.astype(jnp.float32),
-                                  axis=-1), new_carry
+        return _log_probs(logits), new_carry
 
     jitted = jax.jit(prefill)
 
@@ -892,13 +1310,10 @@ def make_batch_prefill_step(model: Sequential, compute_dtype=None,
     * returns per-row log-probs of each row's LAST VALID position (the
       next-token distribution after the prompt) and the updated carry.
 
-    Masking: pad columns never reach the cache (their scatter indices
-    are routed out of bounds and DROPPED), queries use absolute
-    positions ``pos[r] + i`` for both the position embedding and the
-    causal mask, and attention runs over the row's full cache window so
-    cached-prefix keys participate — one program shape per (B, L)
-    regardless of per-row lengths or start offsets. That bounds the
-    compiled-program set by the bucket count where per-row
+    The block is :func:`_block` under the *window* view
+    (:func:`_window_view`, which owns the masking): one program shape
+    per (B, L) regardless of per-row lengths or start offsets. That
+    bounds the compiled-program set by the bucket count where per-row
     :func:`make_prefill_step` calls compile per DISTINCT LENGTH (the
     PR-1 admission stall — see docs/serving.md). The tradeoff: scores
     span ``(L, max_len)`` instead of ``(P, P)``, so for one lone short
@@ -914,199 +1329,54 @@ def make_batch_prefill_step(model: Sequential, compute_dtype=None,
     float round-off — the wider masked reduction can reorder XLA sums —
     pinned by tests/test_serving_admission.py.
 
-    ``mesh`` lowers the program through ``utils.compat.shard_map`` with
-    the same Megatron layout as :func:`make_batch_decode_step`: heads +
-    MLP hidden shard over ``model_axis`` (two psums per block), while
-    tokens/lengths/carry rows stay REPLICATED over ``data_axis`` —
-    prefill rows are few and short-lived, so sharding them would buy
-    little and break the B=1 prefix-cache path. The returned carry's
-    K/V are head-sharded, matching the sharded pool's decode layout.
-
-    ``kv_quant=True`` matches the int8 decode carry
-    (:func:`make_batch_decode_step` with the same knob): written K/V
-    quantize through the grow-only (row, head) scale merge — a suffix
+    ``mesh``, ``kv_quant`` and ``adapter`` are
+    :func:`make_batch_decode_step`'s, and the carry that comes back is
+    that step's. What differs: under a mesh tokens/lengths/carry rows
+    stay REPLICATED over ``data_axis`` (:func:`_shard_step`), and
+    ``carry_sampling`` names the sampling leaves of such a pool's carry,
+    which ride through untouched; with ``kv_quant`` a suffix
     continuation over a quantized cached prefix requantizes the prefix
-    when the suffix raises the scale — and the prompt's own attention
-    reads the DEQUANTIZED cache, so prefill scores see exactly the
-    values decode will (ballast rows still pass through bitwise:
-    zero-length rows have amax 0 and their scatter drops).
-
-    ``adapter`` (a :class:`~bigdl_tpu.serving.lora.AdapterSpec`) makes
-    the returned step the multi-tenant variant: ``prefill(params,
-    tokens, lengths, carry, adapter_ids, bank)``, where ``adapter_ids``
-    (B,) int32 selects each row's pooled low-rank factor pair and
-    ``bank`` is the AdapterBank's device-array dict — both runtime
-    VALUES of the same one program (bank row 0 is the all-zeros null
-    adapter, so mixed base/tenant batches never recompile). The six
-    per-block projections add the rows' gathered delta; under a mesh
-    the row-parallel sites fold their fp32 partial delta into the
-    block's existing closing psum (collective count unchanged)."""
+    when the suffix raises the scale, and zero-length rows still pass
+    through bitwise (amax 0, and their scatter drops); with an
+    ``adapter`` the call is ``prefill(params, tokens, lengths, carry,
+    adapter_ids, bank)``."""
     import jax
     import jax.numpy as jnp
 
-    from bigdl_tpu.nn.misc import LookupTable
-
-    model._ensure_params()
-    mods = model.modules
-    assert isinstance(mods[0], LookupTable), "TransformerLM-shaped model"
-    max_len = mods[1].max_len
-    off = _decode_head_offset(model)
-    lnf = mods[-2 - off]
-    _, _, blocks0, _, _ = _resolve_decode_views(model, off, model.params)
-    attn0 = blocks0[0][0].attn
-    heads, hd = attn0.n_heads, attn0.head_dim
-    scale = hd ** -0.5
-    cache_dtype = compute_dtype or jnp.float32
-    _proj = _serving_proj
-    tp = 1 if mesh is None else int(mesh.shape[model_axis])
-    if mesh is not None:
-        _check_tp_divisibility(model, heads, tp)
-    heads_l = heads // tp
+    m = _serving_meta(model, compute_dtype, mesh, model_axis)
+    max_len = m.max_len
 
     def prefill(params, tokens, lengths, carry, adapter_ids=None,
                 bank=None):
         Pt = _cast_keep_scales(params, compute_dtype)
         lookup_w, pos_w, blocks, lnf_p, lin_p = \
-            _resolve_decode_views(model, off, Pt)
-        aproj, rp_delta = _adapter_proj_fns(adapter, adapter_ids, bank)
-        B, L = tokens.shape
-        start = carry["pos"]                           # (B,) per-row offset
-        rows = jnp.arange(B)
-        qpos = start[:, None] + jnp.arange(L)[None]    # (B, L) absolute
-        inb = jnp.arange(L)[None] < lengths[:, None]   # (B, L) valid mask
-        # pad/overflow columns scatter to index max_len → dropped; valid
-        # columns are in range (checked wrapper) and strictly increasing
-        # per row, so writes never collide
-        widx = jnp.where(inb, qpos, max_len)
-        x = jnp.take(lookup_w, jnp.clip(tokens, 0, lookup_w.shape[0] - 1),
-                     axis=0)                           # (B, L, Hid)
-        x = x + jnp.take(pos_w, jnp.clip(qpos, 0, max_len - 1), axis=0)
+            _resolve_decode_views(model, m.off, Pt)
+        proj, row_proj = _proj_fns(adapter, adapter_ids, bank, mesh,
+                                   model_axis)
+        L = tokens.shape[1]
+        start = carry["pos"]
         new_carry = dict(carry)
+        pos_rows, attend, rows, _ = _window_view(
+            new_carry, lengths, L, max_len, m.scale, m.cache_dtype,
+            kv_quant)
+        x = _embed(lookup_w, pos_w, tokens, pos_rows)     # (B, L, Hid)
         for i, (blk, bp) in enumerate(blocks):
-            h, _ = blk.ln1.apply(bp[blk._child_key(0)], x)
-            ap = bp[blk._child_key(1)]
-            q = aproj(ap["wq"], h, f"wq{i}").reshape(B, L, heads_l, hd)
-            # (B, L, heads_l*hd): the stored rows, as projected — the
-            # (…, heads_l, hd) view of the carry further down is this
-            # program's own, for its multi-query einsums
-            k = aproj(ap["wk"], h, f"wk{i}")
-            v = aproj(ap["wv"], h, f"wv{i}")
-            if kv_quant:
-                # int8 storage: per-(row, head) amax over the VALID
-                # columns only (pad columns must not inflate the scale),
-                # grow-only merge with the cached prefix's scale, then
-                # the same dropped-index masked scatter
-                k32 = k.astype(jnp.float32).reshape(B, L, heads_l, hd)
-                v32 = v.astype(jnp.float32).reshape(B, L, heads_l, hd)
-                inbf = inb[:, :, None, None]
-                k_amax = jnp.max(jnp.abs(k32) * inbf, axis=(1, 3))
-                v_amax = jnp.max(jnp.abs(v32) * inbf, axis=(1, 3))
-                kc_rq, ks_new, ks_safe = _kv_quant_merge(
-                    new_carry[f"k{i}"], new_carry[f"k{i}_scale"], k_amax)
-                vc_rq, vs_new, vs_safe = _kv_quant_merge(
-                    new_carry[f"v{i}"], new_carry[f"v{i}_scale"], v_amax)
-                kc = kc_rq.at[rows[:, None], widx].set(
-                    _kv_quantize(k32, ks_safe[:, None, :, None]
-                                 ).reshape(k.shape), mode="drop")
-                vc = vc_rq.at[rows[:, None], widx].set(
-                    _kv_quantize(v32, vs_safe[:, None, :, None]
-                                 ).reshape(v.shape), mode="drop")
-                new_carry[f"k{i}_scale"] = ks_new
-                new_carry[f"v{i}_scale"] = vs_new
-                # the prompt attends over the DEQUANTIZED cache — the
-                # values decode-time reads will see, so prefill and
-                # decode stay one consistent numerics story
-                katt = kc.reshape(B, max_len, heads_l, hd).astype(
-                    jnp.float32) * ks_new[:, None, :, None]
-                vatt = vc.reshape(B, max_len, heads_l, hd).astype(
-                    jnp.float32) * vs_new[:, None, :, None]
-                qatt = (q * scale).astype(jnp.float32)
-                p_dt = jnp.float32
-            else:
-                kc = new_carry[f"k{i}"].at[rows[:, None], widx].set(
-                    k.astype(cache_dtype), mode="drop")
-                vc = new_carry[f"v{i}"].at[rows[:, None], widx].set(
-                    v.astype(cache_dtype), mode="drop")
-                katt = kc.reshape(B, max_len, heads_l, hd)
-                vatt = vc.reshape(B, max_len, heads_l, hd)
-                qatt = (q * scale).astype(cache_dtype)
-                p_dt = cache_dtype
-            new_carry[f"k{i}"], new_carry[f"v{i}"] = kc, vc
-            # queries attend over the row's FULL cache window (cached
-            # prefix + this chunk) under an absolute causal mask; scores
-            # accumulate fp32 regardless of the serving dtype
-            s = jnp.einsum("blhd,bmhd->bhlm", qatt, katt,
-                           preferred_element_type=jnp.float32)
-            valid = (jnp.arange(max_len)[None, None, None, :]
-                     <= qpos[:, None, :, None])
-            s = jnp.where(valid, s, -1e30)
-            p = jax.nn.softmax(s, axis=-1)
-            ctx = jnp.einsum("bhlm,bmhd->blhd", p.astype(p_dt), vatt,
-                             preferred_element_type=jnp.float32
-                             ).astype(x.dtype).reshape(B, L, heads_l * hd)
-            if mesh is None:
-                x = x + aproj(ap["wo"], ctx, f"wo{i}")
-            else:
-                x = x + _tp_row_proj(ap["wo"], ctx, model_axis,
-                                     delta32=rp_delta(ctx, f"wo{i}"))
-            h2, _ = blk.ln2.apply(bp[blk._child_key(2)], x)
-            hmid = jax.nn.gelu(aproj(bp[blk._child_key(3)], h2, f"fc1{i}"))
-            if mesh is None:
-                mlp = aproj(bp[blk._child_key(4)], hmid, f"fc2{i}")
-            else:
-                mlp = _tp_row_proj(bp[blk._child_key(4)], hmid, model_axis,
-                                   delta32=rp_delta(hmid, f"fc2{i}"))
-            x = x + mlp
+            x = _block(blk, bp, i, x, proj, row_proj, attend)
         # each row's next-token logits come from its LAST VALID position
         last = jnp.clip(lengths - 1, 0, L - 1)
-        xf, _ = lnf.apply(lnf_p, x[rows, last][:, None])
-        logits = _proj(lin_p, xf[:, 0])
+        logits = _head(m.lnf, lnf_p, lin_p, x[rows, last])
         new_carry["pos"] = start + lengths.astype(start.dtype)
-        return jax.nn.log_softmax(logits.astype(jnp.float32),
-                                  axis=-1), new_carry
+        return _log_probs(logits), new_carry
 
-    if adapter is None:
-        run = prefill
+    if mesh is None:
+        jitted = jax.jit(prefill)
     else:
-        # pin the adapter arity (shard_map's in_specs tree must match
-        # the call positionally — no defaulted tail)
-        def run(params, tokens, lengths, carry, adapter_ids, bank):
-            return prefill(params, tokens, lengths, carry, adapter_ids,
-                           bank)
-        # one program, one name in a profile (jit_prefill), whatever
-        # the engine was built with
-        run.__name__ = run.__qualname__ = "prefill"
-    if mesh is not None:
-        from jax.sharding import PartitionSpec as P
-
-        from bigdl_tpu.utils.compat import shard_map as _shard_map
-
-        kv = P(None, None, model_axis)
-        cspecs = {"pos": P()}
-        for i in range(len(blocks0)):
-            cspecs[f"k{i}"] = kv
-            cspecs[f"v{i}"] = kv
-            if kv_quant:
-                # (B, heads) dequant scales shard with their heads
-                cspecs[f"k{i}_scale"] = P(None, model_axis)
-                cspecs[f"v{i}_scale"] = P(None, model_axis)
-        if carry_sampling:
-            # a sampling-enabled pool's zero carry rides through prefill
-            # untouched — but shard_map's spec tree must still name
-            # every leaf (replicated: prefill never reads them)
-            cspecs["rng"] = P()
-            cspecs["tok_counts"] = P()
-            cspecs["prompt_mask"] = P()
-        in_specs = [tp_param_specs(model, model_axis), P(), P(), cspecs]
-        if adapter is not None:
-            # adapter ids replicate like tokens/lengths (prefill rows
-            # are few); the bank shards Megatron-style with the weights
-            in_specs += [P(), adapter_bank_specs(model, model_axis)]
-        jitted = jax.jit(_shard_map(
-            run, mesh=mesh, in_specs=tuple(in_specs),
-            out_specs=(P(), cspecs), check_vma=False))
-    else:
-        jitted = jax.jit(run)
+        jitted = jax.jit(_shard_step(
+            prefill, model, mesh, None, model_axis,
+            serving_carry_specs(model, sampling=carry_sampling,
+                                data_axis=None, model_axis=model_axis,
+                                kv_quant=kv_quant),
+            n_out=1, adapter=adapter))
 
     def prefill_checked(params, tokens, lengths, carry, *adapter_args):
         import numpy as np
@@ -1156,46 +1426,6 @@ def make_batch_prefill_step(model: Sequential, compute_dtype=None,
     return prefill_checked
 
 
-def _serving_init_carry(n_layers: int, max_len: int, heads: int, hd: int,
-                        cache_dtype, kv_quant: bool, sampling: bool,
-                        vocab: int):
-    """THE one pooled-carry layout: per-layer K/V rows
-    ``(n_slots, max_len, heads*hd)`` (head-major lanes: the
-    ``(…, heads, hd)`` array with its two minor axes merged, which is
-    the shape the decode step's row scatter and whole-pool read agree
-    on — no program that holds the pool re-lays it out) + per-row
-    ``pos``, int8 dequant scales on the quantized layout, and the per-row
-    sampling state (RNG lanes + penalty counters — the engine seeds rows
-    at admission via ``KVPool.write_sampling``). Shared by
-    :func:`make_batch_decode_step` and :func:`make_batch_verify_step` so
-    a pool built by either hands its carry to the other unchanged (the
-    speculative engine's verify step IS its decode step)."""
-    import jax.numpy as jnp
-
-    def init_carry(n_slots: int):
-        carry = {"pos": jnp.zeros((n_slots,), jnp.int32)}
-        kv_dt = jnp.int8 if kv_quant else cache_dtype
-        for i in range(n_layers):
-            carry[f"k{i}"] = jnp.zeros((n_slots, max_len, heads * hd),
-                                       kv_dt)
-            carry[f"v{i}"] = jnp.zeros((n_slots, max_len, heads * hd),
-                                       kv_dt)
-            if kv_quant:
-                # per-(slot, head) dequant scales; 0 = "no scale yet"
-                # (fresh rows — the first write establishes it)
-                carry[f"k{i}_scale"] = jnp.zeros((n_slots, heads),
-                                                 jnp.float32)
-                carry[f"v{i}_scale"] = jnp.zeros((n_slots, heads),
-                                                 jnp.float32)
-        if sampling:
-            carry["rng"] = jnp.zeros((n_slots, 2), jnp.uint32)
-            carry["tok_counts"] = jnp.zeros((n_slots, vocab), jnp.int32)
-            carry["prompt_mask"] = jnp.zeros((n_slots, vocab), bool)
-        return carry
-
-    return init_carry
-
-
 def make_decode_step(model: Sequential, compute_dtype=None):
     """KV-cached incremental decoding for a trained :func:`TransformerLM`.
 
@@ -1231,136 +1461,37 @@ def make_decode_step(model: Sequential, compute_dtype=None):
     (``Quantizer.quantize(lm, scheme="weight_only")``) decode through the
     same step — projections whose params carry ``weight_q`` run the int8
     dequant-into-matmul path, compounding with ``compute_dtype``.
+
+    The block is :func:`_block` under the lockstep *token* view
+    (:func:`_token_view` with no ``active`` mask).
     """
     import jax
-    import jax.numpy as jnp
-    from jax import lax
 
-    from bigdl_tpu.nn.misc import LookupTable
-    from bigdl_tpu.ops.decode_attention import folded_decode_attention
-
-    model._ensure_params()
-    mods = model.modules
-    assert isinstance(mods[0], LookupTable), "TransformerLM-shaped model"
-    posemb = mods[1]
-    max_len = posemb.max_len
-    off = _decode_head_offset(model)
-    lnf = mods[-2 - off]
-
-    def resolve(Pt):
-        return _resolve_decode_views(model, off, Pt)
-
-    # structural metadata from the UNCAST params (no weight copy); the
-    # converted P0 copy is materialized lazily, only if a caller uses the
-    # params=None (baked-constants) mode
-    _, _, blocks0, _, _ = resolve(model.params)
-    attn0 = blocks0[0][0].attn
-    _p0_cache: list = []
-
-    def get_p0():
-        if not _p0_cache:
-            _p0_cache.append(_cast_keep_scales(model.params, compute_dtype))
-        return _p0_cache[0]
-    heads, hd = attn0.n_heads, attn0.head_dim
-    scale = hd ** -0.5
-
-    cache_dtype = compute_dtype or jnp.float32
-
-    init_carry = _serving_init_carry(len(blocks0), max_len, heads, hd,
-                                     cache_dtype, kv_quant=False,
+    m = _serving_meta(model, compute_dtype)
+    get_p0 = _captured_params(model, compute_dtype)
+    proj, row_proj = _proj_fns()
+    init_carry = _serving_init_carry(m.n_layers, m.max_len, m.heads, m.hd,
+                                     m.cache_dtype, kv_quant=False,
                                      sampling=False, vocab=0)
 
-    _proj = _serving_proj
-
     def step(params, tokens, carry):
-        if params is None:
-            Pt = get_p0()    # captured weights, baked in as jit constants
-        else:
-            Pt = _cast_keep_scales(params, compute_dtype)
-        lookup_w, pos_w, blocks, lnf_p, lin_p = resolve(Pt)
-        n = tokens.shape[0]
-        t = carry["pos"][0]                      # uniform across rows
-        x = jnp.take(lookup_w, jnp.clip(tokens, 0, lookup_w.shape[0] - 1),
-                     axis=0)                     # (N, Hid)
-        x = x + lax.dynamic_index_in_dim(pos_w, t, keepdims=False)
+        Pt = get_p0() if params is None else \
+            _cast_keep_scales(params, compute_dtype)
+        lookup_w, pos_w, blocks, lnf_p, lin_p = \
+            _resolve_decode_views(model, m.off, Pt)
         new_carry = dict(carry)
+        pos_rows, attend = _token_view(new_carry, None, m.max_len, m.scale,
+                                       m.cache_dtype)
+        x = _embed(lookup_w, pos_w, tokens, pos_rows)     # (N, Hid)
         for i, (blk, bp) in enumerate(blocks):
-            h, _ = blk.ln1.apply(bp[blk._child_key(0)], x[:, None])
-            h = h[:, 0]
-            ap = bp[blk._child_key(1)]
-            q = _proj(ap["wq"], h).reshape(n, heads, hd)
-            k_new = _proj(ap["wk"], h)
-            v_new = _proj(ap["wv"], h)
-            kc = lax.dynamic_update_slice_in_dim(
-                new_carry[f"k{i}"], k_new[:, None].astype(cache_dtype), t, 1)
-            vc = lax.dynamic_update_slice_in_dim(
-                new_carry[f"v{i}"], v_new[:, None].astype(cache_dtype), t, 1)
-            new_carry[f"k{i}"], new_carry[f"v{i}"] = kc, vc
-            # THE single-query read of the stored cache (scores
-            # accumulate fp32 regardless of the serving dtype)
-            ctx = folded_decode_attention(
-                q, kc, vc, jnp.broadcast_to(t, (n,)), scale=scale,
-                out_dtype=x.dtype).reshape(n, heads * hd)
-            x = x + _proj(ap["wo"], ctx)
-            h2, _ = blk.ln2.apply(bp[blk._child_key(2)], x[:, None])
-            h2 = h2[:, 0]
-            mlp = _proj(bp[blk._child_key(4)],
-                        jax.nn.gelu(_proj(bp[blk._child_key(3)], h2)))
-            x = x + mlp
-        xf, _ = lnf.apply(lnf_p, x[:, None])
-        logits = _proj(lin_p, xf[:, 0])
+            x = _block(blk, bp, i, x, proj, row_proj, attend)
+        logits = _head(m.lnf, lnf_p, lin_p, x)
         new_carry["pos"] = carry["pos"] + 1
-        return jax.nn.log_softmax(logits.astype(jnp.float32),
-                                  axis=-1), new_carry
+        return _log_probs(logits), new_carry
 
     # shapes are static across steps: compile once, reuse every token
     # (composes with beam_search's lax.scan — jit-of-jit inlines)
     return jax.jit(step), init_carry
-
-
-def _tp_row_proj(p, x, axis_name: str, delta32=None):
-    """Row-parallel serving projection: this chip's partial product is
-    completed by the block's one closing psum; the bias (replicated)
-    is added once, post-psum (``parallel.tensor_parallel``'s layout).
-    Partials and the psum accumulate fp32 and round to the serving
-    dtype ONCE — matching the unsharded matmul's single rounding, so
-    bf16 TP serving stays token-aligned with the single-device engine
-    instead of drifting an ulp per psum addend. ``delta32``: an fp32
-    per-chip LoRA partial delta folded into the SAME psum (the adapter
-    path keeps the two-collectives-per-block budget; None = no-op)."""
-    import jax.numpy as jnp
-
-    from bigdl_tpu.parallel.tensor_parallel import row_parallel_linear
-
-    return row_parallel_linear(x, p["weight"], p.get("bias"), axis_name,
-                               accum_dtype=jnp.float32,
-                               partial_add=delta32)
-
-
-def _check_tp_divisibility(model: Sequential, heads: int, tp: int) -> None:
-    """Fail fast (with the fix in the message) when a model cannot split
-    over a ``tp``-way model axis: whole heads and whole MLP hidden rows
-    must land on each chip."""
-    if tp <= 0:
-        raise ValueError(f"model-axis size must be positive, got {tp}")
-    hidden = model.modules[1].hidden_size
-    mlp_hidden = None
-    for m in model.modules:
-        inner = m.modules[0] if isinstance(m, Remat) else m
-        if isinstance(inner, TransformerBlock):
-            mlp_hidden = inner.fc1.output_size
-            break
-    if heads % tp:
-        raise ValueError(
-            f"n_heads {heads} not divisible by the model-axis size {tp} "
-            "— tensor-parallel serving shards whole heads")
-    if mlp_hidden is not None and mlp_hidden % tp:
-        raise ValueError(
-            f"MLP hidden {mlp_hidden} not divisible by the model-axis "
-            f"size {tp}")
-    if hidden % tp:
-        raise ValueError(
-            f"hidden {hidden} not divisible by the model-axis size {tp}")
 
 
 def make_batch_decode_step(model: Sequential, compute_dtype=None,
@@ -1409,13 +1540,12 @@ def make_batch_decode_step(model: Sequential, compute_dtype=None,
     * inactive rows stay bitwise untouched (rng/counts included); their
       token/log-prob outputs are garbage the caller must ignore.
 
-    NOTE: the per-layer body below intentionally parallels (not shares)
-    make_decode_step's loop — unifying them would put per-row gathers and
-    masked scatters on the lockstep path that beam_search scans over.
-    The drift risk is pinned by test_batch_decode_step_matches_single_row
-    and the engine-vs-generate parity tests (plain + bf16): any fix to
-    the decode math (mask constant, cache-dtype casts, _serving_proj)
-    must land in BOTH loops or those tests fail.
+    The block is :func:`_block`, shared with :func:`make_decode_step`;
+    what differs is the pooled *token* view (:func:`_token_view` with
+    the ``active`` mask): per-row gathers and masked scatters that stay
+    off the lockstep path. test_batch_decode_step_matches_single_row and
+    the engine-vs-generate parity tests (plain + bf16) hold the two
+    views against each other.
 
     ``params``/``compute_dtype`` follow the :func:`make_decode_step`
     conventions (runtime params tree via :func:`serving_params`, fp32
@@ -1425,13 +1555,10 @@ def make_batch_decode_step(model: Sequential, compute_dtype=None,
     silently wrapping).
 
     ``mesh`` (a ``jax.sharding.Mesh`` with ``data_axis`` and
-    ``model_axis``) lowers the step through ``utils.compat.shard_map``
-    instead of a bare jit — the tensor-parallel serving plane
-    (``bigdl_tpu.serving.sharded``): slot rows shard over ``data_axis``,
-    attention heads + MLP hidden shard over ``model_axis`` with the
-    Megatron two-collectives-per-block layout (one psum closing the
-    attention output projection, one closing the MLP — the column-
-    parallel QKV/fc1 halves communicate nothing; see
+    ``model_axis``) lowers the step through :func:`_shard_step`
+    instead of a bare jit: slot rows shard over ``data_axis``,
+    attention heads + MLP hidden over ``model_axis`` with the Megatron
+    two-collectives-per-block layout (:func:`_proj_fns`; see
     ``parallel/tensor_parallel.py``). Callers place params with
     :func:`tp_param_specs` and the carry with
     :func:`serving_carry_specs`; requires ``n_heads`` and
@@ -1446,14 +1573,11 @@ def make_batch_decode_step(model: Sequential, compute_dtype=None,
     fp32 scale per (slot, head) (carry keys ``k{i}_scale``/
     ``v{i}_scale``, shape ``(N, heads)`` — ~overhead-free next to the
     halved cache payload). Writes quantize through the grow-only scale
-    merge (:func:`_kv_quant_merge`: a slot's scale only ever grows;
-    stored values are requantized on growth, and rows that write
-    nothing — inactive rows — pass through bitwise, preserving the
-    ballast contract above). The attention read routes through
-    :func:`bigdl_tpu.ops.decode_attention.decode_attention` with the
-    dequantization FUSED into the K/V load (the Pallas pooled decode
-    kernel on TPU, its jnp reference elsewhere — scales factor out of
-    both contractions exactly, so int8 bytes are what cross HBM).
+    merge (:func:`_kv_quant_merge`; inactive rows pass through bitwise,
+    preserving the ballast contract above). The attention read routes
+    through :func:`bigdl_tpu.ops.decode_attention.decode_attention`
+    with the dequantization FUSED into the K/V load (scales factor out
+    of both contractions exactly, so int8 bytes are what cross HBM).
     Quantization is an engine-level storage choice, not per-row state:
     a ``kv_quant`` step is still ONE compiled program for every
     traffic mix, same as the float step (pinned by
@@ -1466,145 +1590,32 @@ def make_batch_decode_step(model: Sequential, compute_dtype=None,
     every block's six projections add the rows' gathered low-rank delta
     (``_adapter_delta``; bank row 0 is the all-zeros NULL adapter, so
     base rows add an exact 0.0 and mixed base/tenant traffic is the
-    same ONE compiled program). Under a mesh the column-parallel sites
-    compute their delta chip-locally (A replicated, B's out axis
-    sharded) and the row-parallel sites fold an fp32 partial delta into
-    the block's existing closing psum — the two-collectives-per-block
-    budget is unchanged (see :func:`adapter_bank_specs`).
+    same ONE compiled program). Under a mesh the two-collectives-per-
+    block budget is unchanged (see :func:`adapter_bank_specs`).
     """
     import jax
     import jax.numpy as jnp
 
-    from bigdl_tpu.nn.misc import LookupTable
-    from bigdl_tpu.ops.decode_attention import (
-        decode_attention, folded_decode_attention,
-    )
+    m = _serving_meta(model, compute_dtype, mesh, model_axis)
+    init_carry = _serving_init_carry(m.n_layers, m.max_len, m.heads, m.hd,
+                                     m.cache_dtype, kv_quant, sampling,
+                                     m.vocab)
 
-    model._ensure_params()
-    mods = model.modules
-    assert isinstance(mods[0], LookupTable), "TransformerLM-shaped model"
-    max_len = mods[1].max_len
-    vocab = mods[0].n_index
-    off = _decode_head_offset(model)
-    lnf = mods[-2 - off]
-    _, _, blocks0, _, _ = _resolve_decode_views(model, off, model.params)
-    attn0 = blocks0[0][0].attn
-    heads, hd = attn0.n_heads, attn0.head_dim
-    scale = hd ** -0.5
-    cache_dtype = compute_dtype or jnp.float32
-    tp = 1 if mesh is None else int(mesh.shape[model_axis])
-    if mesh is not None:
-        _check_tp_divisibility(model, heads, tp)
-    # per-device head count: under shard_map each chip sees its own
-    # head slice of the (already column-parallel) QKV projections
-    heads_l = heads // tp
-
-    init_carry = _serving_init_carry(len(blocks0), max_len, heads, hd,
-                                     cache_dtype, kv_quant, sampling,
-                                     vocab)
-
-    _proj = _serving_proj
-
-    def forward(params, tokens, active, carry, adapter_ids=None,
-                bank=None):
+    def step(params, tokens, active, carry, adapter_ids=None, bank=None):
         Pt = _cast_keep_scales(params, compute_dtype)
         lookup_w, pos_w, blocks, lnf_p, lin_p = \
-            _resolve_decode_views(model, off, Pt)
-        aproj, rp_delta = _adapter_proj_fns(adapter, adapter_ids, bank)
-        n = tokens.shape[0]
-        pos = carry["pos"]                        # (N,) per-row
-        rows = jnp.arange(n)
-        wpos = jnp.clip(pos, 0, max_len - 1)      # write index per row
-        x = jnp.take(lookup_w, jnp.clip(tokens, 0, lookup_w.shape[0] - 1),
-                     axis=0)                      # (N, Hid)
-        x = x + jnp.take(pos_w, wpos, axis=0)
+            _resolve_decode_views(model, m.off, Pt)
+        proj, row_proj = _proj_fns(adapter, adapter_ids, bank, mesh,
+                                   model_axis)
         new_carry = dict(carry)
+        pos_rows, attend = _token_view(new_carry, active, m.max_len,
+                                       m.scale, m.cache_dtype, kv_quant)
+        x = _embed(lookup_w, pos_w, tokens, pos_rows)     # (N, Hid)
         for i, (blk, bp) in enumerate(blocks):
-            h, _ = blk.ln1.apply(bp[blk._child_key(0)], x[:, None])
-            h = h[:, 0]
-            ap = bp[blk._child_key(1)]
-            # under a mesh these params are per-chip column-parallel
-            # slices (head-major rows), so the same _proj IS the
-            # column-parallel half — zero communication
-            q = aproj(ap["wq"], h, f"wq{i}").reshape(n, heads_l, hd)
-            k_new = aproj(ap["wk"], h, f"wk{i}")  # (N, heads_l*hd): the
-            v_new = aproj(ap["wv"], h, f"wv{i}")  # stored row, as it is
-            kc_prev, vc_prev = new_carry[f"k{i}"], new_carry[f"v{i}"]
-            if kv_quant:
-                # int8 storage: grow-only (slot, head) scale merge, then
-                # the same masked scatter contract — inactive rows have
-                # amax 0, so their scale, stored values, and the
-                # written-back old value are all bitwise untouched
-                k32 = k_new.astype(jnp.float32).reshape(n, heads_l, hd)
-                v32 = v_new.astype(jnp.float32).reshape(n, heads_l, hd)
-                k_amax = jnp.where(active[:, None],
-                                   jnp.max(jnp.abs(k32), axis=-1), 0.0)
-                v_amax = jnp.where(active[:, None],
-                                   jnp.max(jnp.abs(v32), axis=-1), 0.0)
-                (kc_prev, vc_prev, ks_new, vs_new, ks_safe,
-                 vs_safe) = _kv_quant_merge_step(
-                    kc_prev, vc_prev, new_carry[f"k{i}_scale"],
-                    new_carry[f"v{i}_scale"], k_amax, v_amax)
-                k_wr0 = _kv_quantize(k32, ks_safe[..., None]
-                                     ).reshape(n, heads_l * hd)
-                v_wr0 = _kv_quantize(v32, vs_safe[..., None]
-                                     ).reshape(n, heads_l * hd)
-                new_carry[f"k{i}_scale"] = ks_new
-                new_carry[f"v{i}_scale"] = vs_new
-            else:
-                k_wr0 = k_new.astype(cache_dtype)
-                v_wr0 = v_new.astype(cache_dtype)
-            # masked per-row scatter: inactive rows write their OLD value
-            # back, so their cache stays bitwise identical
-            k_old, v_old = kc_prev[rows, wpos], vc_prev[rows, wpos]
-            k_wr = jnp.where(active[:, None], k_wr0, k_old)
-            v_wr = jnp.where(active[:, None], v_wr0, v_old)
-            kc = kc_prev.at[rows, wpos].set(k_wr)
-            vc = vc_prev.at[rows, wpos].set(v_wr)
-            new_carry[f"k{i}"], new_carry[f"v{i}"] = kc, vc
-            if kv_quant:
-                # attention via the pooled decode op: Pallas kernel on
-                # TPU (int8 K/V loads, dequant fused as two scalar
-                # factors), jnp reference elsewhere — per-row masked
-                # single-query attention over cols 0..wpos[r]
-                ctx = decode_attention(
-                    q, kc, vc, wpos, k_scale=ks_new, v_scale=vs_new,
-                    scale=scale, out_dtype=x.dtype
-                ).reshape(n, heads_l * hd)
-            else:
-                # per-row causal mask over the row's own cache prefix,
-                # read from the stored 3-D array (a 4-D view here costs
-                # two pool-sized copies per tensor per token on the
-                # TPU); scores accumulate fp32 regardless of the
-                # serving dtype
-                ctx = folded_decode_attention(
-                    q, kc, vc, wpos, scale=scale, out_dtype=x.dtype
-                ).reshape(n, heads_l * hd)
-            if mesh is None:
-                x = x + aproj(ap["wo"], ctx, f"wo{i}")
-            else:
-                # row-parallel output projection — collective 1 of 2
-                # (the adapter's partial delta rides the same psum)
-                x = x + _tp_row_proj(ap["wo"], ctx, model_axis,
-                                     delta32=rp_delta(ctx, f"wo{i}"))
-            h2, _ = blk.ln2.apply(bp[blk._child_key(2)], x[:, None])
-            h2 = h2[:, 0]
-            hmid = jax.nn.gelu(aproj(bp[blk._child_key(3)], h2, f"fc1{i}"))
-            if mesh is None:
-                mlp = aproj(bp[blk._child_key(4)], hmid, f"fc2{i}")
-            else:
-                # row-parallel MLP projection — collective 2 of 2
-                mlp = _tp_row_proj(bp[blk._child_key(4)], hmid, model_axis,
-                                   delta32=rp_delta(hmid, f"fc2{i}"))
-            x = x + mlp
-        xf, _ = lnf.apply(lnf_p, x[:, None])
-        logits = _proj(lin_p, xf[:, 0])
-        new_carry["pos"] = pos + active.astype(jnp.int32)
-        return jax.nn.log_softmax(logits.astype(jnp.float32),
-                                  axis=-1), new_carry
-
-    def step(params, tokens, active, carry):
-        return forward(params, tokens, active, carry)
+            x = _block(blk, bp, i, x, proj, row_proj, attend)
+        logits = _head(m.lnf, lnf_p, lin_p, x)
+        new_carry["pos"] = carry["pos"] + active.astype(jnp.int32)
+        return _log_probs(logits), new_carry
 
     def sample_step(params, tokens, active, carry, knobs,
                     adapter_ids=None, bank=None):
@@ -1613,68 +1624,33 @@ def make_batch_decode_step(model: Sequential, compute_dtype=None,
         # imported lazily — serving imports models, not vice versa)
         from bigdl_tpu.serving.sampling import sample_rows
 
-        logp, new_carry = forward(params, tokens, active, carry,
-                                  adapter_ids, bank)
+        logp, new_carry = step(params, tokens, active, carry,
+                               adapter_ids, bank)
         tok, chosen, new_keys, new_counts = sample_rows(
             logp, carry["rng"], knobs, carry["tok_counts"],
             carry["prompt_mask"])
         # inactive rows: rng/counts bitwise untouched, same contract as
-        # the K/V scatter above
+        # the K/V scatter
         new_carry["rng"] = jnp.where(active[:, None], new_keys,
                                      carry["rng"])
         new_carry["tok_counts"] = jnp.where(active[:, None], new_counts,
                                             carry["tok_counts"])
         return tok, chosen, new_carry
 
+    fn = sample_step if sampling else step
+    if mesh is not None:
+        fn = _shard_step(
+            fn, model, mesh, data_axis, model_axis,
+            serving_carry_specs(model, sampling=sampling,
+                                data_axis=data_axis, model_axis=model_axis,
+                                kv_quant=kv_quant),
+            n_out=2 if sampling else 1, knobs=sampling, adapter=adapter)
     # the carry is DONATED: the engine replaces its pooled carry with the
     # step's output every token, and without donation XLA materializes a
     # complete second copy of the whole KV pool per generated token
     # (~300 MB/step at 137M/8 slots). Callers must not touch the input
     # carry after a step — read it (np.asarray) before stepping.
-    if adapter is None:
-        fn = sample_step if sampling else step
-    elif sampling:
-        # pinned adapter arity (shard_map in_specs match positionally)
-        def fn(params, tokens, active, carry, knobs, adapter_ids, bank):
-            return sample_step(params, tokens, active, carry, knobs,
-                               adapter_ids, bank)
-    else:
-        def fn(params, tokens, active, carry, adapter_ids, bank):
-            return forward(params, tokens, active, carry, adapter_ids,
-                           bank)
-    if mesh is not None:
-        from jax.sharding import PartitionSpec as P
-
-        from bigdl_tpu.serving.sampling import knob_partition_specs
-        from bigdl_tpu.utils.compat import shard_map as _shard_map
-
-        pspecs = tp_param_specs(model, model_axis)
-        cspecs = serving_carry_specs(model, sampling=sampling,
-                                     data_axis=data_axis,
-                                     model_axis=model_axis,
-                                     kv_quant=kv_quant)
-        row = P(data_axis)
-        if sampling:
-            in_specs = (pspecs, row, row, cspecs,
-                        knob_partition_specs(data_axis))
-            out_specs = (row, row, cspecs)
-        else:
-            in_specs = (pspecs, row, row, cspecs)
-            out_specs = (row, cspecs)
-        if adapter is not None:
-            # per-row adapter ids shard with their rows; the bank
-            # shards Megatron-style with the weights it adapts
-            in_specs = in_specs + (row,
-                                   adapter_bank_specs(model, model_axis))
-        # check_vma/check_rep off: sampled tokens and non-head state are
-        # REPLICATED over the model axis (every model chip computes the
-        # identical post-psum value deterministically), which the static
-        # replication checker cannot prove through the sampler's vmapped
-        # random.split
-        fn = _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_vma=False)
-    jitted = jax.jit(fn, donate_argnums=(3,))
-    return jitted, init_carry
+    return jax.jit(fn, donate_argnums=(3,)), init_carry
 
 
 def make_batch_verify_step(model: Sequential, compute_dtype=None,
@@ -1687,34 +1663,36 @@ def make_batch_verify_step(model: Sequential, compute_dtype=None,
     (``bigdl_tpu.serving.speculative``): one compiled program scores a
     per-row CHUNK of candidate tokens against the target model and
     advances each row by however many the target confirms — the
-    multi-token generalization of :func:`make_batch_decode_step`.
-    Structurally this is the masked multi-row prefill
-    (:func:`make_batch_prefill_step`'s per-row start offsets already
-    express "continue this row's suffix"); what is new is that EVERY
-    chunk position's next-token distribution is kept and fed through
-    the per-row sampler, not just the last one.
+    multi-token generalization of :func:`make_batch_decode_step`. It is
+    the batched prefill's block and cache view (:func:`_block` under
+    :func:`_window_view`: per-row start offsets already express
+    "continue this row's suffix"); this factory adds what only it has:
+    EVERY chunk position's distribution through the per-row sampler,
+    the acceptance chain, and the deferred accepted-only int8 commit.
 
     Returns ``(verify_fn, init_carry)``; ``init_carry`` builds exactly
     the :func:`make_batch_decode_step` ``sampling=True`` carry (shared
     layout — a pool built by either hands its carry to the other).
+    ``mesh``, ``kv_quant`` and ``adapter`` are that step's too (chunk
+    outputs replicate over the model axis like its sampled tokens; the
+    target scores each row under that ROW'S adapter, and the engine
+    pins drafts to the null adapter, see serving/speculative.py).
 
-    ``verify_fn(params, tokens, lengths, carry, knobs) ->
-    (tokens_out, logps_out, n_emit, carry)``:
+    ``verify_fn(params, tokens, lengths, carry, knobs[, adapter_ids,
+    bank]) -> (tokens_out, logps_out, n_emit, carry)``:
 
     * ``tokens``: (N, ``width``) 0-based ids — row r's column 0 is its
       current decode input (the engine's ``next_token``), columns
       ``1..lengths[r]-1`` are DRAFT proposals for the following
-      positions; columns at and beyond ``lengths[r]`` are pad the
-      program never uses;
+      positions; columns at and beyond ``lengths[r]`` are pad;
     * ``lengths``: (N,) int32, ``0 <= lengths[r] <= width`` — how many
-      chunk positions row r runs this step (``k_r`` drafts + 1).
-      ``lengths[r] == 1`` is EXACTLY the plain sampled decode step
-      (one input, one draw, one emission — a normal row in a mixed
-      speculative/normal batch costs nothing extra), and
-      ``lengths[r] == 0`` rows are pure ballast: carry bitwise
-      untouched, outputs garbage (the ``active`` convention). Per-row
-      lengths are runtime VALUES of one compiled (N, width) program —
-      traffic mix never recompiles;
+      chunk positions row r runs this step (``k_r`` drafts + 1), runtime
+      VALUES of the one (N, width) program. ``lengths[r] == 1`` is
+      EXACTLY the plain sampled decode step, so a normal row in a mixed
+      batch costs nothing extra; ``lengths[r] == 0`` rows are ballast
+      (the ``active`` convention). The caller keeps ``pos[r] +
+      lengths[r] <= max_len`` (the engine enforces it): columns out of
+      range would be silently dropped by the masked scatter;
     * ``knobs``: the per-row sampling knob dict
       (:func:`~bigdl_tpu.serving.sampling.make_knob_rows`);
     * ``tokens_out``/``logps_out``: (N, width) — position j's token is
@@ -1722,111 +1700,60 @@ def make_batch_verify_step(model: Sequential, compute_dtype=None,
       (:func:`~bigdl_tpu.serving.sampling.sample_rows`) from the
       target's next-token distribution after chunk inputs ``0..j``,
       with the row's RNG lane split once per position IN ORDER and
-      penalty counts updated per draw — each position computes the
-      same math the plain decode step would had the accepted prefix
-      been fed token by token. (Numerics caveat, the kv_quant
-      accuracy contract's sibling: the chunked path rounds reduced-
-      precision activations in a different order than the single-
-      token step, so at bf16 an argmax sitting on a sub-rounding
-      near-tie — untrained near-uniform logits — can flip vs the
+      penalty counts updated per draw — the math the plain decode step
+      would do had the accepted prefix been fed token by token.
+      (Numerics caveat: the chunked path rounds reduced-precision
+      activations in a different order than the single-token step, so
+      at bf16 an argmax on a sub-rounding near-tie can flip against the
       baseline; fp32 parity is exact on the dev box, and the parity
-      tests pin configs with real gaps);
+      tests hold token identity where the gaps are real);
     * ``n_emit``: (N,) int32 — ``1 + (leading positions whose drawn
-      token equals the NEXT chunk input)``. Acceptance is
-      sampled-token agreement: position j's draw is a valid emission
-      iff drafts ``1..j`` all matched the draws before them (so its
-      conditioning context is the true emitted stream); the first
-      mismatch position still emits — its draw came from the correct
-      conditional — and everything after it is discarded. For
-      temperature-0 rows this is standard greedy speculative
-      verification (argmax agreement), token-identical to the baseline
-      engine; for sampled rows the EMITTED stream equals the baseline
-      engine's stream draw for draw (same lane splits, same
-      conditionals — the draft only controls how many of those draws
-      land per step, never their values), which is what makes fixed
-      seeds replay across speculative/normal engines and
-      eviction/readmission. (This deliberately trades Leviathan-style
-      distribution-matching rejection sampling — which consumes
-      randomness in a draft-dependent pattern and so cannot replay the
-      baseline stream — for exact stream equality; acceptance rate is
-      then ``P(draft == the sampler's draw)``.)
+      token equals the NEXT chunk input)``: position j's draw is a
+      valid emission iff drafts ``1..j`` all matched the draws before
+      them (its context is then the true emitted stream); the first
+      mismatch still emits — its draw came from the correct conditional
+      — and everything after it is discarded. Temperature-0 rows: the
+      standard greedy verification, token-identical to the baseline
+      engine. Sampled rows: the EMITTED stream equals the baseline
+      engine's draw for draw (same lane splits, same conditionals — the
+      draft only controls how many draws land per step), which is what
+      makes fixed seeds replay across speculative/normal engines and
+      eviction/readmission. (Traded away on purpose: Leviathan-style
+      rejection sampling, which consumes randomness in a draft-
+      dependent pattern and cannot replay the baseline stream;
+      acceptance rate is ``P(draft == the sampler's draw)``.)
 
-    The carry rollback contract: K/V for ALL ``lengths[r]`` inputs are
-    written at ``pos[r]..pos[r]+lengths[r]-1`` (the masked dropped-index
-    scatter of the batch prefill), but ``pos`` advances by only
-    ``n_emit[r]`` — positions past the accepted prefix are stale bytes
-    BEHIND ``pos``, invisible to every later step (the same masking
-    that makes recycled slots safe) and overwritten as decoding
-    proceeds. Rollback is pointer arithmetic, not a cache rewrite.
-    The RNG lane and penalty counts advance by exactly ``n_emit[r]``
-    draws for the same reason.
+    Rollback is pointer arithmetic, not a cache rewrite: K/V for ALL
+    ``lengths[r]`` inputs are written at ``pos[r]..``, but ``pos`` — and
+    the RNG lane and the penalty counts — advance by ``n_emit[r]`` only;
+    what lies past the accepted prefix is stale bytes BEHIND ``pos``,
+    invisible to every later step (the masking that makes recycled
+    slots safe) and overwritten as decoding proceeds.
 
-    ``mesh``/``kv_quant`` follow :func:`make_batch_decode_step`: the
-    tensor-parallel lowering shards heads/MLP hidden over
-    ``model_axis`` with slot rows over ``data_axis`` (chunk outputs
-    replicate over the model axis like the sampled step's). The int8
-    cache path merges ACCEPTED COLUMNS ONLY: the chunk's own attention
-    reads the stored cache dequantized at its CURRENT (pre-merge)
-    scales with the chunk's float K/V overlaid in place, and the
-    grow-only (slot, head) scale merge + quantized scatter are
-    DEFERRED until ``n_emit`` is known — the amax covers emitted
-    positions alone and only they are written, so a REJECTED draft can
-    never touch a row's scales or stored bytes: two steps from the same
-    state whose accepted outcome agrees return BITWISE-identical
-    carries no matter what their rejected columns held (unit-pinned in
-    tests/test_serving_kv_quant.py::test_int8_draft_independence_exact,
-    with end-to-end stream equality across good/garbage drafts pinned
-    beside it). The trade, tiny and documented: in-step attention sees
-    the chunk's own K/V unrounded (the plain decode step reads the
-    current token int8-roundtripped), so int8 spec-vs-baseline parity
-    stays the pinned-config contract it always was.
-
-    Caller contract (the engine enforces it): ``pos[r] + lengths[r] <=
-    max_len`` — out-of-range columns would be silently dropped by the
-    masked scatter, exactly like :func:`make_batch_prefill_step`.
-
-    NOTE: the per-block body parallels (not shares)
-    make_batch_prefill_step's loop for the same reason the decode/
-    prefill pair documents — drift is pinned by the speculative parity
-    tests (tests/test_serving_speculative.py: greedy outputs equal the
-    baseline engine and generate()).
-
-    ``adapter`` follows :func:`make_batch_decode_step`: the signature
-    grows a trailing ``(adapter_ids, bank)`` pair and every chunk
-    position's six projections add the rows' gathered low-rank delta —
-    the TARGET model's verification scores each row under that ROW'S
-    adapter, so accept-rate accounting can never mix an adapted target
-    with the wrong factors (the engine pins drafts to the null
-    adapter; see serving/speculative.py).
+    ``kv_quant`` merges ACCEPTED COLUMNS ONLY: the chunk's attention
+    reads the stored cache dequantized at its CURRENT scales with the
+    chunk's float K/V overlaid, and the grow-only scale merge + the
+    quantized scatter wait until ``n_emit`` is known, so a REJECTED
+    draft can never touch a row's scales or stored bytes: two steps
+    from one state whose accepted outcome agrees return BITWISE-
+    identical carries whatever their rejected columns held
+    (tests/test_serving_kv_quant.py::
+    test_int8_draft_independence_exact). The trade: in-step attention
+    sees the chunk's own K/V unrounded where the plain decode step
+    reads the current token int8-roundtripped, so int8 spec-vs-baseline
+    parity is a pinned-config contract.
     """
     import jax
     import jax.numpy as jnp
 
-    from bigdl_tpu.nn.misc import LookupTable
-
-    model._ensure_params()
-    mods = model.modules
-    assert isinstance(mods[0], LookupTable), "TransformerLM-shaped model"
     if width < 1:
         raise ValueError(f"width must be >= 1, got {width}")
-    max_len = mods[1].max_len
-    vocab = mods[0].n_index
-    off = _decode_head_offset(model)
-    lnf = mods[-2 - off]
-    _, _, blocks0, _, _ = _resolve_decode_views(model, off, model.params)
-    attn0 = blocks0[0][0].attn
-    heads, hd = attn0.n_heads, attn0.head_dim
-    scale = hd ** -0.5
-    cache_dtype = compute_dtype or jnp.float32
-    tp = 1 if mesh is None else int(mesh.shape[model_axis])
-    if mesh is not None:
-        _check_tp_divisibility(model, heads, tp)
-    heads_l = heads // tp
+    m = _serving_meta(model, compute_dtype, mesh, model_axis)
+    max_len = m.max_len
     S = int(width)
-
-    init_carry = _serving_init_carry(len(blocks0), max_len, heads, hd,
-                                     cache_dtype, kv_quant, True, vocab)
-    _proj = _serving_proj
+    init_carry = _serving_init_carry(m.n_layers, max_len, m.heads, m.hd,
+                                     m.cache_dtype, kv_quant, True,
+                                     m.vocab)
 
     def verify(params, tokens, lengths, carry, knobs, adapter_ids=None,
                bank=None):
@@ -1834,90 +1761,25 @@ def make_batch_verify_step(model: Sequential, compute_dtype=None,
 
         Pt = _cast_keep_scales(params, compute_dtype)
         lookup_w, pos_w, blocks, lnf_p, lin_p = \
-            _resolve_decode_views(model, off, Pt)
-        aproj, rp_delta = _adapter_proj_fns(adapter, adapter_ids, bank)
+            _resolve_decode_views(model, m.off, Pt)
+        proj, row_proj = _proj_fns(adapter, adapter_ids, bank, mesh,
+                                   model_axis)
         N = tokens.shape[0]
-        start = carry["pos"]                          # (N,) per-row
-        rows = jnp.arange(N)
-        qpos = start[:, None] + jnp.arange(S)[None]   # (N, S) absolute
-        inb = jnp.arange(S)[None] < lengths[:, None]  # (N, S) valid cols
-        # pad/overflow columns scatter to index max_len -> dropped
-        widx = jnp.where(inb, qpos, max_len)
-        x = jnp.take(lookup_w, jnp.clip(tokens, 0, lookup_w.shape[0] - 1),
-                     axis=0)                          # (N, S, Hid)
-        x = x + jnp.take(pos_w, jnp.clip(qpos, 0, max_len - 1), axis=0)
+        start = carry["pos"]
         new_carry = dict(carry)
-        chunk_kv = []            # per-layer float chunk K/V (int8 path)
+        # per-layer fp32 chunk K/V: the int8 path's commit waits for
+        # acceptance, so nothing a rejected draft produced can reach
+        # the carry
+        chunk_kv = [] if kv_quant else None
+        pos_rows, attend, rows, qpos = _window_view(
+            new_carry, lengths, S, max_len, m.scale, m.cache_dtype,
+            kv_quant, deferred=chunk_kv)
+        x = _embed(lookup_w, pos_w, tokens, pos_rows)     # (N, S, Hid)
         for i, (blk, bp) in enumerate(blocks):
-            h, _ = blk.ln1.apply(bp[blk._child_key(0)], x)
-            ap = bp[blk._child_key(1)]
-            q = aproj(ap["wq"], h, f"wq{i}").reshape(N, S, heads_l, hd)
-            # stored rows as projected, 4-D view of the carry for the
-            # S-wide einsums — as in the batch prefill
-            k = aproj(ap["wk"], h, f"wk{i}")
-            v = aproj(ap["wv"], h, f"wv{i}")
-            if kv_quant:
-                # int8 storage, ACCEPTED-ONLY merge: the chunk attention
-                # reads the stored cache dequantized at the CURRENT
-                # scales with the chunk's own FLOAT K/V overlaid (cast
-                # to fp32, the quantized path's attention dtype); the
-                # scale merge + quantized scatter are deferred past
-                # acceptance (below), so nothing a rejected draft
-                # produced can reach the carry
-                k32 = k.astype(jnp.float32).reshape(N, S, heads_l, hd)
-                v32 = v.astype(jnp.float32).reshape(N, S, heads_l, hd)
-                ks_old = new_carry[f"k{i}_scale"]
-                vs_old = new_carry[f"v{i}_scale"]
-                katt = (new_carry[f"k{i}"].reshape(
-                            N, max_len, heads_l, hd).astype(jnp.float32)
-                        * ks_old[:, None, :, None]).at[
-                            rows[:, None], widx].set(k32, mode="drop")
-                vatt = (new_carry[f"v{i}"].reshape(
-                            N, max_len, heads_l, hd).astype(jnp.float32)
-                        * vs_old[:, None, :, None]).at[
-                            rows[:, None], widx].set(v32, mode="drop")
-                qatt = (q * scale).astype(jnp.float32)
-                p_dt = jnp.float32
-                chunk_kv.append((k32, v32))
-            else:
-                kc = new_carry[f"k{i}"].at[rows[:, None], widx].set(
-                    k.astype(cache_dtype), mode="drop")
-                vc = new_carry[f"v{i}"].at[rows[:, None], widx].set(
-                    v.astype(cache_dtype), mode="drop")
-                katt = kc.reshape(N, max_len, heads_l, hd)
-                vatt = vc.reshape(N, max_len, heads_l, hd)
-                qatt = (q * scale).astype(cache_dtype)
-                p_dt = cache_dtype
-                new_carry[f"k{i}"], new_carry[f"v{i}"] = kc, vc
-            # each chunk position attends over the row's full cache
-            # window under the absolute causal mask; fp32 accumulation
-            s = jnp.einsum("blhd,bmhd->bhlm", qatt, katt,
-                           preferred_element_type=jnp.float32)
-            valid = (jnp.arange(max_len)[None, None, None, :]
-                     <= qpos[:, None, :, None])
-            s = jnp.where(valid, s, -1e30)
-            p = jax.nn.softmax(s, axis=-1)
-            ctx = jnp.einsum("bhlm,bmhd->blhd", p.astype(p_dt), vatt,
-                             preferred_element_type=jnp.float32
-                             ).astype(x.dtype).reshape(N, S, heads_l * hd)
-            if mesh is None:
-                x = x + aproj(ap["wo"], ctx, f"wo{i}")
-            else:
-                x = x + _tp_row_proj(ap["wo"], ctx, model_axis,
-                                     delta32=rp_delta(ctx, f"wo{i}"))
-            h2, _ = blk.ln2.apply(bp[blk._child_key(2)], x)
-            hmid = jax.nn.gelu(aproj(bp[blk._child_key(3)], h2, f"fc1{i}"))
-            if mesh is None:
-                mlp = aproj(bp[blk._child_key(4)], hmid, f"fc2{i}")
-            else:
-                mlp = _tp_row_proj(bp[blk._child_key(4)], hmid, model_axis,
-                                   delta32=rp_delta(hmid, f"fc2{i}"))
-            x = x + mlp
+            x = _block(blk, bp, i, x, proj, row_proj, attend)
         # EVERY position's next-token distribution (the whole point —
-        # prefill keeps only the last valid one)
-        xf, _ = lnf.apply(lnf_p, x)
-        logits = _proj(lin_p, xf)                     # (N, S, V)
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        # prefill keeps only the last valid one): (N, S, V)
+        logp = _log_probs(_head(m.lnf, lnf_p, lin_p, x))
         # sequential per-position sampling through THE one sampler: the
         # lane splits once per position in order, penalty counts grow
         # per draw — position j computes exactly the baseline step's
@@ -1969,12 +1831,10 @@ def make_batch_verify_step(model: Sequential, compute_dtype=None,
                     new_carry[f"v{i}"], new_carry[f"v{i}_scale"], v_amax)
                 new_carry[f"k{i}"] = kc_rq.at[rows[:, None], widx_e].set(
                     _kv_quantize(k32, ks_safe[:, None, :, None]
-                                 ).reshape(N, S, heads_l * hd),
-                    mode="drop")
+                                 ).reshape(N, S, -1), mode="drop")
                 new_carry[f"v{i}"] = vc_rq.at[rows[:, None], widx_e].set(
                     _kv_quantize(v32, vs_safe[:, None, :, None]
-                                 ).reshape(N, S, heads_l * hd),
-                    mode="drop")
+                                 ).reshape(N, S, -1), mode="drop")
                 new_carry[f"k{i}_scale"] = ks_new
                 new_carry[f"v{i}_scale"] = vs_new
         # lane/counts advance by EXACTLY n_emit draws. The lane: select
@@ -1999,35 +1859,13 @@ def make_batch_verify_step(model: Sequential, compute_dtype=None,
         new_carry["pos"] = start + n_emit
         return s_tok, s_lp, n_emit, new_carry
 
-    if adapter is None:
-        fn = verify
-    else:
-        # pinned adapter arity (shard_map in_specs match positionally)
-        def fn(params, tokens, lengths, carry, knobs, adapter_ids, bank):
-            return verify(params, tokens, lengths, carry, knobs,
-                          adapter_ids, bank)
+    fn = verify
     if mesh is not None:
-        from jax.sharding import PartitionSpec as P
-
-        from bigdl_tpu.serving.sampling import knob_partition_specs
-        from bigdl_tpu.utils.compat import shard_map as _shard_map
-
-        cspecs = serving_carry_specs(model, sampling=True,
-                                     data_axis=data_axis,
-                                     model_axis=model_axis,
-                                     kv_quant=kv_quant)
-        row = P(data_axis)
-        in_specs = (tp_param_specs(model, model_axis), row, row, cspecs,
-                    knob_partition_specs(data_axis))
-        if adapter is not None:
-            in_specs = in_specs + (row,
-                                   adapter_bank_specs(model, model_axis))
-        # check_vma off for the decode step's reason: chunk draws and
-        # non-head state replicate over the model axis deterministically,
-        # which the static checker cannot prove through the sampler
-        fn = _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                        out_specs=(row, row, row, cspecs),
-                        check_vma=False)
+        fn = _shard_step(
+            fn, model, mesh, data_axis, model_axis,
+            serving_carry_specs(model, sampling=True, data_axis=data_axis,
+                                model_axis=model_axis, kv_quant=kv_quant),
+            n_out=3, knobs=True, adapter=adapter)
     # carry donated like the decode step's: the engine swaps its pooled
     # carry for the output every super-step
     return jax.jit(fn, donate_argnums=(3,)), init_carry

@@ -89,17 +89,33 @@ def test_greedy_parity_int8_vs_float_kv(dtype_name, lm):
 
 def test_greedy_parity_per_request_admission(lm):
     """The per_request (B=1 prefill) admission path writes the same
-    quantized rows: parity vs the batched-admission int8 engine AND
-    vs the float engine, including 1-token prompts (whose rows enter
-    decode with a still-zero scale established on the first step)."""
+    quantized rows as batched admission: the two int8 engines agree
+    token for token, including 1-token prompts (whose rows enter decode
+    with a still-zero scale established on the first step).
+
+    Against the FLOAT engine the contract is the accuracy one of
+    test_greedy_parity_int8_vs_float_kv, chosen-token log-probs within
+    the quantization tolerance, and not token identity: these requests
+    are not the pinned parity set, and on an untrained model a rollout
+    can sit on a near-tie that ~0.5% of cache rounding flips (here one
+    does: 0.003 between the two engines' choices). So the log-probs are
+    compared while both engines have fed the same tokens, through the
+    first position where they choose differently — there both still
+    score the same context — and no further."""
     reqs = [([3], 6), ([7, 1, 4], 8), ([2, 9], 5), ([5] * 7, 6)]
     e_f, r_f, o_f = _run(lm, reqs, n_slots=2)
     e_b, r_b, o_b = _run(lm, reqs, n_slots=2, kv_dtype="int8")
     e_p, r_p, o_p = _run(lm, reqs, n_slots=2, kv_dtype="int8",
                          admission="per_request")
     for a, b, c in zip(r_f, r_b, r_p):
-        np.testing.assert_array_equal(o_f[a], o_b[b])
         np.testing.assert_array_equal(o_b[b], o_p[c])
+        np.testing.assert_allclose(e_b.logprobs(b), e_p.logprobs(c),
+                                   rtol=0, atol=1e-5)
+        differ = np.flatnonzero(np.asarray(o_f[a]) != np.asarray(o_b[b]))
+        same_context = int(differ[0]) + 1 if differ.size else len(o_f[a])
+        np.testing.assert_allclose(e_f.logprobs(a)[:same_context],
+                                   e_b.logprobs(b)[:same_context],
+                                   atol=0.08)
 
 
 # -- fixed-seed sampled reproducibility ------------------------------------

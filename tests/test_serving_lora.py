@@ -114,9 +114,14 @@ def test_engine_submit_validation(lm, bank):
 @pytest.mark.parametrize("dtype_name", ["fp32", "bf16"])
 def test_null_adapter_token_identical_to_plain_engine(dtype_name, lm, bank):
     """An adapter-enabled engine serving only null-adapter traffic is
-    token-identical (and logprob-identical) to an engine built without
-    a bank: the id-0 rows gather all-zero factors and the delta
-    vanishes exactly, in both dtypes."""
+    token-identical to an engine built without a bank: the id-0 rows
+    gather all-zero factors and the delta vanishes exactly, in both
+    dtypes. The chosen log-probs are held to 1e-6 at fp32, not to the
+    bit: the two engines run two separately compiled programs (one
+    carries the gathers and the ``+ 0.0`` of every site), and XLA is
+    free to order a float32 reduction differently in each — the last
+    bit of one log-prob in this trace (2.4e-7). At bf16 they come out
+    equal and are held to that."""
     import jax.numpy as jnp
 
     from bigdl_tpu.serving import ServingEngine
@@ -136,7 +141,12 @@ def test_null_adapter_token_identical_to_plain_engine(dtype_name, lm, bank):
     o1 = eng.drain()
     for a, b in zip(r0, r1):
         np.testing.assert_array_equal(o0[a], o1[b])
-        np.testing.assert_array_equal(plain.logprobs(a), eng.logprobs(b))
+        if dt is None:
+            np.testing.assert_allclose(plain.logprobs(a), eng.logprobs(b),
+                                       rtol=0, atol=1e-6)
+        else:
+            np.testing.assert_array_equal(plain.logprobs(a),
+                                          eng.logprobs(b))
 
 
 def test_adapted_rows_actually_diverge(lm, bank):

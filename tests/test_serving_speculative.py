@@ -97,13 +97,53 @@ def test_greedy_spec_matches_generate(which, lm, good_draft, bad_draft,
     assert eng.pool.free_slots == eng.pool.n_slots
 
 
+def _top2_gaps(lm, prompt, stream, compute_dtype):
+    """Best minus second-best next-token log-prob at every position of
+    ``stream`` (1-based ids a greedy engine emitted after ``prompt``),
+    from the B=1 programs at ``compute_dtype``."""
+    import jax
+    import jax.numpy as jnp
+
+    from bigdl_tpu.models.transformer import (
+        get_decode_step, get_prefill_step, serving_params,
+    )
+
+    step, init_carry = get_decode_step(lm, compute_dtype)
+    params = jax.device_put(serving_params(lm, compute_dtype))
+    carry = init_carry(1)
+    fed = [t - 1 for t in prompt]
+    if len(fed) > 1:
+        _, carry = get_prefill_step(lm, compute_dtype)(
+            params, jnp.asarray([fed[:-1]], jnp.int32), carry)
+    gaps, tok = [], fed[-1]
+    for t in stream:
+        logp, carry = step(params, jnp.asarray([tok], jnp.int32), carry)
+        best = np.sort(np.asarray(logp[0]))[-2:]
+        gaps.append(float(best[1] - best[0]))
+        tok = int(t) - 1
+    return gaps
+
+
 def test_greedy_spec_matches_baseline_engine_bf16(lm, good_draft):
     """bf16 serving dtype through the speculative engine equals the
-    bf16 baseline engine token for token (greedy)."""
+    bf16 baseline engine token for token (greedy) wherever bf16 can
+    tell the baseline's best token from its second best.
+
+    The verify step scores a chunk where the baseline scores one token
+    a step: two separately compiled programs that round bf16
+    activations in a different order (make_batch_verify_step's
+    numerics caveat). Logits below 4 in magnitude are bf16 numbers
+    2**-6 apart, so a best and a second best closer than two such steps
+    is a tie either program may break either way; this untrained model
+    has one (0.005) in this trace. There the two engines' chosen
+    log-probs must still agree to one step, and the rest of that
+    request — a different context from then on — is not compared. The
+    fp32 and int8 twins beside this test stay exact."""
     import jax.numpy as jnp
 
     from bigdl_tpu.serving import ServingEngine
 
+    step = 2.0 ** -6
     reqs = [([3, 7, 2], 8), ([5], 6), ([9, 1, 4], 7)]
     base = ServingEngine(lm, n_slots=3, compute_dtype=jnp.bfloat16)
     rb = [base.submit(p, max_new_tokens=n) for p, n in reqs]
@@ -112,8 +152,20 @@ def test_greedy_spec_matches_baseline_engine_bf16(lm, good_draft):
                          speculative=_spec(good_draft))
     rs = [spec.submit(p, max_new_tokens=n) for p, n in reqs]
     outs_s = spec.drain()
-    for a, b in zip(rb, rs):
-        np.testing.assert_array_equal(outs_b[a], outs_s[b])
+    held = 0
+    for (p, n), a, b in zip(reqs, rb, rs):
+        gaps = _top2_gaps(lm, p, outs_b[a], jnp.bfloat16)
+        assert len(outs_s[b]) == len(outs_b[a]) == n
+        for j, gap in enumerate(gaps):
+            assert abs(base.logprobs(a)[j] - spec.logprobs(b)[j]) <= step, \
+                (p, j)
+            if gap > 2 * step:
+                assert outs_b[a][j] == outs_s[b][j], (p, j, gap)
+                held += 1
+            elif outs_b[a][j] != outs_s[b][j]:
+                break                  # a tie, broken the other way
+    # nearly every position is held to token identity, not excused
+    assert held >= 0.75 * sum(n for _, n in reqs)
 
 
 def test_greedy_spec_matches_baseline_engine_int8(lm, good_draft,

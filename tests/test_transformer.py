@@ -581,3 +581,52 @@ def test_prefill_matches_sequential_decode(rng):
         l1, _ = step(Pp, nxt, c_pre)
         l2, _ = step(Pp, nxt, c_seq)
         assert_close(np.asarray(l1), np.asarray(l2), atol=max(atol, 1e-4))
+
+
+@pytest.mark.parametrize("program", ["prefill", "batch_prefill", "decode",
+                                     "batch_decode", "batch_verify"])
+def test_serving_programs_share_one_block(program, monkeypatch):
+    """Every serving program of the GPT-2 family runs its layers through
+    the ONE ``transformer._block``: tracing a program of a 2-layer model
+    calls it exactly twice. A factory that inlines its own
+    LayerNorm → projections → MLP again calls it less often and fails
+    here."""
+    import jax
+    import jax.numpy as jnp
+
+    from bigdl_tpu.models import TransformerLM, transformer
+    from bigdl_tpu.serving.sampling import make_knob_rows
+
+    V, N, L = 19, 3, 4
+    lm = TransformerLM(V, hidden_size=32, n_heads=4, n_layers=2, max_len=16)
+    calls = []
+    real_block = transformer._block
+
+    def counting_block(*args):
+        calls.append(args[2])                    # the layer index
+        return real_block(*args)
+
+    monkeypatch.setattr(transformer, "_block", counting_block)
+    params = transformer.serving_params(lm)
+    tokens, rows = jnp.zeros((N, L), jnp.int32), jnp.ones((N,), jnp.int32)
+    knobs = make_knob_rows(N)
+    if program == "prefill":
+        _, init_carry = transformer.make_decode_step(lm)
+        fn = transformer.make_prefill_step(lm)._jitted
+        args = (params, tokens, init_carry(N))
+    elif program == "batch_prefill":
+        _, init_carry = transformer.make_batch_decode_step(lm)
+        fn = transformer.make_batch_prefill_step(lm)._jitted
+        args = (params, tokens, rows, init_carry(N))
+    elif program == "decode":
+        fn, init_carry = transformer.make_decode_step(lm)
+        args = (params, tokens[:, 0], init_carry(N))
+    elif program == "batch_decode":
+        fn, init_carry = transformer.make_batch_decode_step(lm,
+                                                            sampling=True)
+        args = (params, tokens[:, 0], rows > 0, init_carry(N), knobs)
+    else:
+        fn, init_carry = transformer.make_batch_verify_step(lm, width=L)
+        args = (params, tokens, rows, init_carry(N), knobs)
+    jax.eval_shape(fn, *args)
+    assert calls == [0, 1]
