@@ -118,7 +118,7 @@ import numpy as np
 from bigdl_tpu.parallel.block_store import (
     BlockStore, decode_array, encode_array,
 )
-from bigdl_tpu.serving.engine import ServingEngine
+from bigdl_tpu.serving.engine import DISPATCH_AHEAD, ServingEngine
 from bigdl_tpu.serving.faults import FaultError, default_clock
 from bigdl_tpu.serving.fences import fence
 from bigdl_tpu.serving.kv_tier import TieredKVStore
@@ -445,7 +445,7 @@ class PrefillWorker:
         # a requeue, or a finish disposition — the SRV206 invariant),
         # then it is popped (not finished) and its slot returns to the
         # free list for the next admission wave
-        payload = self.engine.pool.row_state(slot)
+        payload = self.engine.row_state(slot)
         del self.engine.scheduler.running[slot]
         req.slot = None
         self.engine.pool.free(slot)
@@ -769,6 +769,13 @@ class DisaggregatedEngine:
     drains-and-retires cold ones. ``kill_pool``/``drain_pool``/
     ``pool_states`` are the operator surface.
 
+    ``dispatch_ahead`` is every DECODE worker's window depth
+    (:data:`~bigdl_tpu.serving.engine.DISPATCH_AHEAD` = 1 by default:
+    one decode program in flight behind the host; the prefill pool
+    drains to handoff every pump and has no window). ``drain_pool``
+    reads back what the pool has in flight before it serializes a row;
+    a failed-over pool's window is dropped unfenced.
+
     Output parity with the monolithic engine is the module-level
     contract — through pool deaths included; the front end's own
     metrics add the handoff plane: ``serving/handoffs``,
@@ -798,7 +805,8 @@ class DisaggregatedEngine:
                  health: Optional[HealthConfig] = None,
                  transfer_retry: Optional[TransferRetryConfig] = None,
                  autoscaler=None, adapters=None, tier=None,
-                 autopilot=None, dispatch_ahead: int = 0) -> None:
+                 autopilot=None,
+                 dispatch_ahead: int = DISPATCH_AHEAD) -> None:
         if decode_pools < 1:
             raise ValueError(
                 f"decode_pools must be >= 1, got {decode_pools}")
@@ -1106,6 +1114,9 @@ class DisaggregatedEngine:
         w = self.decoders[i]
         t0 = self._clock()
         w.alive = False
+        # what the pool had in flight died with it: never fenced, never
+        # read (its rows replay or restore from their EMITTED prefixes)
+        w.engine._window.clear()
         self._pool_state[i] = POOL_DEAD
         self._health[i].force_dead()
         self.metrics.on_pool_death()
@@ -1167,6 +1178,11 @@ class DisaggregatedEngine:
                 "another first")
         w = self.decoders[i]
         self._pool_state[i] = POOL_STANDBY   # routing excludes it now
+        # the pool's device rows are ahead of their emitted prefixes by
+        # whatever it has in flight: read those tokens back (rows may
+        # finish here and then have nothing left to migrate) BEFORE any
+        # row is chosen or serialized
+        w.engine.flush_window()
         n = 0
         while True:                          # unconsumed wire payloads
             blob = w.transfer.recv()
@@ -1196,7 +1212,7 @@ class DisaggregatedEngine:
             # slot-holding rows serialize their LIVE carry — the
             # clean path failover cannot take (it never trusts a
             # dead device)
-            payload = w.engine.pool.row_state(slot)
+            payload = w.engine.row_state(slot)
             req.slot = None
             w.engine.pool.free(slot)
             w.engine._configured.discard(slot)

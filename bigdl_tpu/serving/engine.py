@@ -90,6 +90,16 @@ from bigdl_tpu.serving.scheduler import (
 )
 
 
+# THE depth of the dispatch-ahead window every engine runs at when the
+# caller passes nothing: ONE decode program stays in flight behind the
+# host (launch step k+1 on step k's device token, then read step k
+# back). Chosen on the chip (PERF.md section 6, PR 31): depth 2 read no
+# better than 1 in either serving cell — the host's whole per-step cost
+# is a quarter of the shorter decode program, and a deeper window only
+# adds discarded overshoot steps at every finish.
+DISPATCH_AHEAD = 1
+
+
 class _InFlight:
     """One dispatched-but-not-yet-fenced decode step in the engine's
     dispatch-ahead window: the device token/logprob handles the delayed
@@ -97,21 +107,20 @@ class _InFlight:
     facts frozen
     at dispatch time that its bookkeeping needs (the row snapshot, the
     pre-dispatch clock read the watchdog's elapsed is measured from,
-    the sampled/greedy split, and whether rows were already in flight —
-    the decode-gap anchor)."""
+    and whether rows were already in flight — the decode-gap
+    anchor)."""
 
     __slots__ = ("tok", "chosen", "active", "active_dev", "rows", "t0",
-                 "n_sampled", "had_running")
+                 "had_running")
 
     def __init__(self, tok, chosen, active, active_dev, rows, t0,
-                 n_sampled, had_running):
+                 had_running):
         self.tok = tok                  # device handle: next 0-based ids
         self.chosen = chosen            # device handle: chosen logprobs
         self.active = active            # host bool mask at dispatch
         self.active_dev = active_dev    # the mask's PLACED device twin
         self.rows = rows                # {slot: Request} at dispatch
         self.t0 = t0                    # clock at dispatch (pre-launch)
-        self.n_sampled = n_sampled      # sampled rows in the batch
         self.had_running = had_running  # decode-gap anchor flag
 
 
@@ -248,6 +257,24 @@ class ServingEngine:
       waiter evicts the longest-slack running row rather than miss).
       Every actuation is host bookkeeping over per-row runtime data —
       the compiled-program set is untouched.
+
+    ``dispatch_ahead`` is the depth W of the DISPATCH-AHEAD window
+    (docs/serving.md "Dispatch-ahead decode"), by default
+    :data:`DISPATCH_AHEAD` = 1: a plain decode step LAUNCHES program
+    k+1 on program k's device token and only then reads program k
+    back, so the host's per-step work hides behind the device. The
+    token of program k is therefore returned by the ``step()`` that
+    launched k+1 — the first ``step()`` after an admission or any
+    other flush can return ``{}`` while rows are running — and a row
+    that finishes has been stepped once more by the program in
+    flight (that token is thrown away and counted nowhere). Anything
+    the in-flight program assumed changing (admission, a finish, an
+    eviction, a ban flip, a constraint, a preemption spill, a fault)
+    flushes the window first; streams are byte-identical at every W
+    (tests/test_serving_async.py, tests/test_falcon_h1.py). ``0`` is
+    the classic dispatch-fence-bookkeep step, kept as the tests'
+    oracle. A speculative engine keeps its verify fence inline and
+    its window empty whatever the depth.
     """
 
     def __init__(self, model, n_slots: int = 8, compute_dtype=None,
@@ -271,7 +298,7 @@ class ServingEngine:
                  adapters=None,
                  tier=None,
                  autopilot=None,
-                 dispatch_ahead: int = 0) -> None:
+                 dispatch_ahead: int = DISPATCH_AHEAD) -> None:
         import jax
         import jax.numpy as jnp
 
@@ -311,8 +338,9 @@ class ServingEngine:
         if int(dispatch_ahead) < 0:
             raise ValueError(
                 f"dispatch_ahead must be >= 0, got {dispatch_ahead} "
-                "(0 = consume each decode readback immediately; W = keep "
-                "up to W decode dispatches in flight behind the fence)")
+                f"(default {DISPATCH_AHEAD}: W = keep up to W decode "
+                "dispatches in flight behind the fence; 0 = consume each "
+                "decode readback in the step that launched it)")
         if preemption and policy != "priority":
             raise ValueError(
                 "preemption=True requires policy='priority' — victim "
@@ -497,11 +525,17 @@ class ServingEngine:
         # previous dispatch's device token handle, so the decode-fence
         # readback of step N overlaps the device work of steps
         # N+1..N+W. The deque holds _InFlight entries oldest-first; the
-        # delayed consumer (_consume_window) pops them. W=0 keeps the
-        # deque depth at zero across step() calls — dispatch-then-
-        # consume within one step, byte-for-byte the pre-window engine.
+        # delayed consumer (_consume_window) pops them. The default is
+        # DISPATCH_AHEAD (one program in flight: launch k+1, then
+        # consume k); W=0 keeps the deque depth at zero across step()
+        # calls — dispatch-then-consume within one step, the pre-window
+        # engine and the W-sweep tests' oracle.
         self.dispatch_ahead = int(dispatch_ahead)
         self._window: deque = deque()
+        # engine-clock time the newest consumed entry's fence returned:
+        # an entry chained behind it had the device only from then on
+        # (see _consume_window's service sample)
+        self._last_fence_t = float("-inf")
         # watchdog cold-start grace: the step timeout arms only after
         # one healthy step has completed (see _timed_out)
         self._warm = False
@@ -1146,6 +1180,22 @@ class ServingEngine:
             return fn(*args)
         return self._faults.call(site, fn, *args)
 
+    def row_state(self, slot: int) -> Dict:
+        """``pool.row_state(slot)`` for a SLOT-HOLDING row — THE way a
+        row's device state leaves this engine (preemption spill, the
+        disaggregated handoff, a pool drain). With decode dispatches
+        in flight the device row is up to W tokens AHEAD of the
+        request's emitted prefix, and a payload taken then resumes
+        desynchronized (a token lost from the stream), so the window
+        must be empty here. The reader cannot flush on the caller's
+        behalf: a flush may FINISH the row or free its slot, so callers
+        flush (``_drain_window`` inside a step, ``flush_window()``
+        outside one) and THEN choose their rows."""
+        assert not self._window, (
+            f"row_state({slot}) with {len(self._window)} decode "
+            "dispatch(es) in flight — flush the window first")
+        return self.pool.row_state(slot)
+
     def _preempt_row(self, victim: Request) -> None:
         """Loss-free preemption of one RUNNING row: stash its FULL
         ``pool.row_state`` payload (KV + int8 scales + RNG lane +
@@ -1159,7 +1209,7 @@ class ServingEngine:
         the request at its ORIGINAL arrival key — preemption reorders
         latency, never tokens."""
         slot = victim.slot
-        payload = self.pool.row_state(slot)
+        payload = self.row_state(slot)
         if len(victim.prompt) + len(victim.output) > 1:
             self._spill_or_carry(victim, payload)
             if self.prefix_cache is not None:
@@ -1491,9 +1541,13 @@ class ServingEngine:
         token per row on the plain engine, up to ``k + 1`` on a
         speculative engine (draft-and-verify super-step —
         ``serving/speculative.py``). Returns ``{req_id: 1-based token}``
-        emitted this step (the LAST emitted token per request when a
-        super-step lands several; empty when the engine is idle or
-        every slot-holding row is still mid-prefill)."""
+        READ BACK this step: with a decode program in flight behind the
+        host (the default) those are the tokens of the program the
+        PREVIOUS step launched (the LAST token per request when a
+        super-step, or a flush at depth > 1, lands several; empty when
+        the engine is idle, every slot-holding row is still mid-
+        prefill, or this step only launched — the first after an
+        admission or any other flush of the window)."""
         return self._step_impl()
 
     @contextlib.contextmanager
@@ -1600,7 +1654,18 @@ class ServingEngine:
             # too, and a stall fault's clock advance at dispatch time is
             # inside it either way, so step_timeout_s keeps firing
             elapsed = now - entry.t0
-            self.metrics.add_phase("decode_step", elapsed)
+            # the estimator's sample is the part of that bracket in which
+            # this dispatch HAD the device: a chained dispatch sat queued
+            # behind the previous program until ITS fence returned, and
+            # counting the wait would price a token at up to W + 1 steps
+            # (feasibility admission and deadline preemption would then
+            # shed and spare what they should not). An entry dispatched
+            # after a flush (and every entry at W=0) started after the
+            # last fence, so its sample is the whole bracket, as before.
+            self.metrics.add_phase(
+                "decode_step", elapsed,
+                service_s=now - max(entry.t0, self._last_fence_t))
+            self._last_fence_t = now
             running = self.scheduler.running
             rows = {slot: req for slot, req in entry.rows.items()
                     if running.get(slot) is req}
@@ -1626,13 +1691,21 @@ class ServingEngine:
             # recency stamps feed the tier's cold-first victim selection:
             # a row decoded this step is never the LRU preemption victim
             self.scheduler.note_decoded(list(rows))
-            self.metrics.on_step(self.scheduler.queue_depth,
-                                 self.pool.occupancy(),
-                                 int(entry.active.sum()),
-                                 kv_used_share=self._kv_used_share(),
-                                 state_in_use_bytes=self._state_in_use())
-            self.metrics.on_sample_rows(entry.n_sampled,
-                                        len(entry.rows) - entry.n_sampled)
+            # what is counted is what is KEPT: a row that finished one
+            # consume earlier was stepped once more by this dispatch and
+            # its token is thrown away (the overshoot), so it is no
+            # emitted token of ``serving/batch_active`` and no sampled
+            # row; an entry none of whose rows still runs served nothing
+            # and leaves no step sample at all
+            if rows:
+                n_sampled = sum(not req.sampling.is_greedy
+                                for req in rows.values())
+                self.metrics.on_step(
+                    self.scheduler.queue_depth, self.pool.occupancy(),
+                    len(rows), kv_used_share=self._kv_used_share(),
+                    state_in_use_bytes=self._state_in_use())
+                self.metrics.on_sample_rows(n_sampled,
+                                            len(rows) - n_sampled)
             for slot, req in list(rows.items()):
                 tok0 = int(nxt[slot])
                 reason = self._account_token(slot, req, tok0,
@@ -1708,7 +1781,8 @@ class ServingEngine:
                 else:
                     self._last_decode_end = None
                 return out
-            if self._window_open(running):
+            chained = self._window_open(running)
+            if chained:
                 # STEADY-STATE window extension: nothing the in-flight
                 # dispatches assumed changed, so the next dispatch chains
                 # directly on the newest dispatch's device token handle —
@@ -1721,7 +1795,6 @@ class ServingEngine:
                 active = prev.active
                 active_dev = prev.active_dev
                 rows = dict(prev.rows)
-                n_sampled = prev.n_sampled
             else:
                 # the window's assumptions broke (admission, finish, evict,
                 # knob change) or it is empty: flush everything in flight
@@ -1741,7 +1814,6 @@ class ServingEngine:
                     N = self.pool.n_slots
                     tokens = np.zeros((N,), np.int32)
                     active = np.zeros((N,), bool)
-                    n_sampled = 0
                     for slot, req in list(running.items()):
                         if slot not in self._configured:
                             try:
@@ -1756,7 +1828,6 @@ class ServingEngine:
                                 continue
                         tokens[slot] = req.next_token
                         active[slot] = True
-                        n_sampled += not req.sampling.is_greedy
                     if not active.any():
                         self._last_decode_end = None
                         return emitted
@@ -1791,6 +1862,7 @@ class ServingEngine:
                 self._last_decode_end = None
                 return emitted
             self.pool.carry = carry
+            self.metrics.on_decode_dispatch(chained)
             # the (N, V) distribution never crosses to host — sampling is
             # fused into the step; only token ids + chosen log-probs will,
             # through ONE batched fence readback at this entry's DELAYED
@@ -1798,7 +1870,7 @@ class ServingEngine:
             # site, serving/fences.py). t0 rides the entry so the
             # watchdog's elapsed covers the device work, not the launch
             self._window.append(_InFlight(tok, chosen, active, active_dev,
-                                          rows, t0, n_sampled, had_running))
+                                          rows, t0, had_running))
             # delayed consumer: fence the oldest entry once the window
             # exceeds its DECLARED depth knob (fences.WINDOW_KNOBS —
             # ASY308 rejects any other bound). dispatch_ahead=0 consumes
@@ -1807,6 +1879,13 @@ class ServingEngine:
             while len(self._window) > self.dispatch_ahead:
                 if not self._consume_window(emitted):
                     break
+            if self._window and not self.scheduler.running:
+                # the last rows finished at that consume: what is still
+                # in flight is overshoot for rows that are gone. Read it
+                # back here, inside the step, so that no device handle
+                # outlives the batch (a caller that polls idle() and
+                # never calls drain() would keep it for ever)
+                self._drain_window(emitted)
             return emitted
 
     def drain(self) -> Dict[int, np.ndarray]:

@@ -382,6 +382,15 @@ class ServingMetrics:
         self.metrics.add("serving/actuations", 1.0)
         self.metrics.add(f"serving/actuation_{actuator}", 1.0)
 
+    def on_decode_dispatch(self, chained: bool) -> None:
+        """Per plain decode dispatch: 1.0 when it was CHAINED on the
+        in-flight dispatch's device token (launched before the previous
+        step was read back), 0.0 when the window was empty or had to be
+        flushed and the token rows were rebuilt on the host. The mean is
+        the share of decode steps the dispatch-ahead window hid the
+        host behind (``serving/decode_chained``)."""
+        self.metrics.add("serving/decode_chained", float(chained))
+
     def on_sample_rows(self, n_sampled: int, n_greedy: int) -> None:
         """Per decode step: how many active rows drew from a sampled
         distribution (temperature > 0) vs took the argmax."""
@@ -667,10 +676,17 @@ class ServingMetrics:
         own."""
         return span(name, self.metrics.clock, self.add_phase, phase, **ids)
 
-    def add_phase(self, name: str, seconds: float) -> None:
+    def add_phase(self, name: str, seconds: float,
+                  service_s: Optional[float] = None) -> None:
+        """``service_s`` (decode_step only) is what the service-time
+        estimator takes in place of ``seconds``: under the dispatch-
+        ahead window the dispatch-to-fence bracket of a chained
+        dispatch includes its wait behind the previous program, which
+        is no part of what a token costs."""
         self.metrics.add(f"serving/{name}_s", float(seconds))
         if name == "decode_step":
-            self._step_window.append(float(seconds))
+            self._step_window.append(
+                float(seconds if service_s is None else service_s))
             self._n_decode_steps += 1
         elif name == "draft":
             self._draft_window.append(float(seconds))

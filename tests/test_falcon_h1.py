@@ -356,6 +356,77 @@ def test_a_recycled_slot_serves_as_a_fresh_engine_would(lm, second):
     assert np.array_equal(both[1][1], alone[0][1])
 
 
+# the second family through the dispatch-ahead window: five requests of
+# staggered lengths through two slots, greedy and seeded-sampled, so that
+# rows finish while a decode is in flight and their slots are re-admitted
+_SWEEP_JOBS = [
+    (_tokens(21, 9), 5, None),
+    (_tokens(22, 4), 11, SamplingParams(temperature=0.8, top_k=6, seed=3)),
+    (_tokens(23, 6), 7, None),
+    (_tokens(24, 1), 9, SamplingParams(temperature=1.1, seed=12)),
+    (_tokens(25, 12), 6, None)]
+
+
+def _sweep(lm, jobs, **engine_kw):
+    """Serve ``jobs`` through two slots; beside the streams, what every
+    ``pool.free()`` left in the freed row's ``state`` and ``pos`` leaves
+    (read back at once: after whatever was in flight for the row)."""
+    eng = ServingEngine(lm, n_slots=2, **engine_kw)
+    freed, free = [], eng.pool.free
+
+    def checked_free(slot):
+        free(slot)
+        freed.append(all(
+            not np.asarray(leaf[slot]).any()
+            for key, leaf in eng.pool.carry.items()
+            if leaf_kind(key) in ("state", "pos")))
+
+    eng.pool.free = checked_free
+    rids = [eng.submit(list(map(int, p)), max_new_tokens=n, sampling=s)
+            for p, n, s in jobs]
+    outs = eng.drain()
+    return eng, [(outs[r], eng.logprobs(r)) for r in rids], freed
+
+
+@pytest.fixture(scope="module")
+def sweep_oracle(lm):
+    return _sweep(lm, _SWEEP_JOBS, dispatch_ahead=0)[1]
+
+
+@pytest.mark.parametrize("W", [0, 1, 2])
+def test_window_sweep_is_byte_identical_and_frees_clean(lm, sweep_oracle, W):
+    """Token streams AND log-probs at depth W are the W=0 ones to the
+    byte. A row that finishes at the consume of step k has had its scan
+    state, convolution window and K/V row advanced once more by the
+    step in flight: ``free()`` zeroes the state leaves AFTER it (the
+    reset is launched on the committed carry), so every freed row reads
+    back zeros before its slot's next prefill."""
+    eng, served, freed = _sweep(lm, _SWEEP_JOBS, dispatch_ahead=W)
+    for (out, logp), (want, want_logp) in zip(served, sweep_oracle):
+        assert np.asarray(out).tobytes() == np.asarray(want).tobytes()
+        assert np.asarray(logp).tobytes() == np.asarray(want_logp).tobytes()
+    assert len(freed) == len(_SWEEP_JOBS) and all(freed)
+    assert not eng._window and eng.pool.free_slots == 2
+    chained = eng.metrics.metrics.values("serving/decode_chained")
+    assert (sum(chained) > 0) == (W > 0)
+    # the overshoot is no emitted token and no step sample
+    m = eng.metrics.metrics
+    assert m.get("serving/batch_active")[0] == sum(n for _, n, _ in _SWEEP_JOBS)
+    assert min(m.values("serving/state_in_use_bytes")) > 0
+
+
+@pytest.mark.parametrize("W", [0, 1, 2])
+def test_window_sweep_next_occupant_serves_as_in_a_fresh_engine(lm, W):
+    """The third, fourth and fifth requests take slots whose previous
+    row was stepped once past its end: each serves the stream it
+    produces alone in a fresh engine."""
+    _, served, _ = _sweep(lm, _SWEEP_JOBS, dispatch_ahead=W)
+    for job, (out, logp) in list(zip(_SWEEP_JOBS, served))[2:]:
+        _, alone, _ = _sweep(lm, [job], dispatch_ahead=W)
+        assert np.array_equal(out, alone[0][0])
+        np.testing.assert_allclose(logp, alone[0][1], atol=LOGP_ATOL, rtol=0)
+
+
 def test_row_state_free_restore_is_byte_identical(lm):
     eng = ServingEngine(lm, n_slots=3)
     for seed, n in ((15, 12), (16, 7)):

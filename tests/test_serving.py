@@ -207,7 +207,10 @@ def test_engine_fifo_policy_runs_to_completion():
            eng.submit([7, 2], max_new_tokens=4)]
     eng.step()
     assert eng.active == 2 and eng.queue_depth == 1
-    eng.step(); eng.step()                 # first request finishes at 3
+    # the first request finishes at 3 tokens; with one decode program
+    # in flight behind the host (the default) the finish is SEEN by the
+    # step after the one that computed it
+    eng.step(); eng.step(); eng.step()
     # run-to-completion: the freed slot is NOT refilled mid-batch
     assert eng.active == 1 and eng.queue_depth == 1
     outs = eng.drain()
@@ -247,12 +250,16 @@ def test_cancel_waiting_and_running_requests():
     a = eng.submit([3, 7], max_new_tokens=4)
     b = eng.submit([5, 2], max_new_tokens=4)
     c = eng.submit([9], max_new_tokens=3)
-    eng.step()                               # a runs; b, c wait
+    # a runs; b, c wait. The second step launches a's second decode
+    # and only then reads its first token back (one program stays in
+    # flight behind the host by default)
+    eng.step(); eng.step()
     assert eng.cancel(b)
     assert not eng.cancel(b)                 # already cancelled: no-op
     assert eng.queue_depth == 1              # only c still waits
-    # RUNNING cancel: a has emitted one token; its slot frees NOW and
-    # its output freezes — c gets the slot on the next step
+    # RUNNING cancel, with a's second decode still in flight: a has
+    # emitted one token; its slot frees NOW, its output freezes (the
+    # in-flight token is thrown away) — c gets the slot on the next step
     assert eng.cancel(a)
     assert eng.request(a).state == "cancelled"
     out_a = list(eng.request(a).output)

@@ -1,4 +1,5 @@
-"""Dispatch-ahead decode window (ServingEngine ``dispatch_ahead=W``):
+"""Dispatch-ahead decode window (ServingEngine ``dispatch_ahead=W``;
+the engine's own default is ``DISPATCH_AHEAD`` = 1, W=0 is the oracle):
 the async-readiness ledger CASHED IN.  Byte-identity is the acceptance
 bar everywhere — W in {0, 1, 2} must produce identical streams across
 greedy + fixed-seed sampled traces, slot recycling, priority
@@ -97,7 +98,7 @@ def test_window_byte_identity(W, lm, baseline):
     assert eng.pool.free_slots == eng.pool.n_slots
 
 
-def test_window_zero_is_the_default_and_validated(lm, baseline):
+def test_window_zero_is_the_oracle_and_validated(lm, baseline):
     from bigdl_tpu.serving import ServingEngine
 
     eng, outs = _run(lm, dispatch_ahead=0)
@@ -105,6 +106,97 @@ def test_window_zero_is_the_default_and_validated(lm, baseline):
     assert eng.dispatch_ahead == 0
     with pytest.raises(ValueError, match="dispatch_ahead"):
         ServingEngine(lm, n_slots=2, dispatch_ahead=-1)
+
+
+# -- the default engine runs the window --------------------------------------
+
+def _plain_decode_run(lm, **kw):
+    """Two rows admitted together and decoded side by side for 24 and
+    30 tokens: one admission, one finish before the end, long runs of
+    plain decode steps between them."""
+    from bigdl_tpu.serving import ServingEngine
+
+    eng = ServingEngine(lm, n_slots=2, **kw)
+    rids = [eng.submit([3, 7, 2], max_new_tokens=24),
+            eng.submit([5, 1], max_new_tokens=30)]
+    outs = eng.drain()
+    return eng, [list(outs[r]) for r in rids]
+
+
+def test_default_engine_keeps_one_decode_in_flight(lm, baseline):
+    """Nothing passed: the engine and the disaggregated plane's decode
+    workers run at DISPATCH_AHEAD = 1, the streams are the W=0
+    streams, and nearly every dispatch of a plain-decode run chained on
+    the in-flight one (the flushes: the first dispatch and the one
+    after the 24-token row's finish)."""
+    from bigdl_tpu.serving import DisaggregatedEngine
+    from bigdl_tpu.serving.engine import DISPATCH_AHEAD
+
+    assert DISPATCH_AHEAD == 1
+    eng, outs = _run(lm)
+    assert eng.dispatch_ahead == DISPATCH_AHEAD and outs == baseline
+    d = DisaggregatedEngine(lm, prefill_slots=2, decode_slots=2)
+    assert [w.engine.dispatch_ahead for w in d.decoders] == [DISPATCH_AHEAD]
+
+    eng, plain = _plain_decode_run(lm)
+    chained = eng.metrics.metrics.values("serving/decode_chained")
+    assert len(chained) >= 30 and set(chained) == {0.0, 1.0}
+    assert sum(chained) / len(chained) > 0.9
+    assert chained.count(0.0) == 2
+    eng0, plain0 = _plain_decode_run(lm, dispatch_ahead=0)
+    assert plain == plain0
+    chained0 = eng0.metrics.metrics.values("serving/decode_chained")
+    assert len(chained0) == 30 and set(chained0) == {0.0}
+
+
+def test_speculative_engine_records_no_chained_sample(lm):
+    """The verify fence stays inline: a speculative engine makes no
+    plain decode dispatch, so the series stays empty there."""
+    from bigdl_tpu.serving import ServingEngine, SpeculativeConfig
+
+    eng = ServingEngine(lm, n_slots=2,
+                        speculative=SpeculativeConfig(_make_lm(seed=31),
+                                                      k=3))
+    eng.submit([3, 7, 2], max_new_tokens=6)
+    eng.drain()
+    assert eng.metrics.metrics.values("serving/decode_chained") == []
+    assert not eng._window
+
+
+@pytest.mark.parametrize("W", [0, 1, 2, 4])
+def test_overshoot_is_not_counted(W, lm):
+    """A row that finished one consume earlier is stepped once more by
+    the dispatch already in flight and its token thrown away: it is no
+    emitted token (``serving/batch_active`` sums to the tokens the
+    requests got, whatever the depth), no sampled or greedy row, and an
+    entry none of whose rows still runs leaves no step sample (no 0.0
+    in the occupancy series)."""
+    eng, outs = _run(lm, dispatch_ahead=W)
+    m = eng.metrics.metrics
+    n_tokens = sum(len(o) for o in outs)
+    assert m.get("serving/batch_active")[0] == n_tokens
+    sampled = sum(len(o) for o, (_, _, sp) in zip(outs, _trace())
+                  if sp is not None)
+    assert m.get("serving/rows_sampled")[0] == sampled
+    assert m.get("serving/rows_greedy")[0] == n_tokens - sampled
+    assert min(m.values("serving/slot_occupancy")) > 0.0
+    assert min(m.values("serving/batch_active")) >= 1.0
+    # every dispatch still leaves its paired split samples, kept or not
+    assert m.get("serving/decode_step_s")[1] \
+        == m.get("serving/decode_chained")[1]
+
+
+def test_default_window_zero_new_compiles(lm):
+    """The chained call has the shapes, dtypes and placement of the
+    classical one: the default engine after a W=0 engine adds no
+    program."""
+    from tests.compile_guards import compile_count
+
+    eng0, _ = _run(lm, dispatch_ahead=0)
+    n0 = compile_count(eng0._step_fn)
+    eng1, _ = _run(lm)
+    assert eng1.dispatch_ahead >= 1
+    assert compile_count(eng1._step_fn) == n0
 
 
 def test_window_zero_new_compiles(lm):
@@ -192,6 +284,148 @@ def test_window_disagg_byte_identity(lm, baseline):
     for w in d.decoders:
         assert w.engine.dispatch_ahead == 2
         assert not w.engine._window
+
+
+# -- every reader of a RUNNING row flushes the window first -------------------
+# (the device row is W tokens ahead of the emitted prefix while dispatches
+# are in flight: ``ServingEngine.row_state`` is the one reader and
+# refuses a window that is not empty)
+
+def test_row_state_refuses_a_window_in_flight(lm):
+    from bigdl_tpu.serving import ServingEngine
+
+    eng = ServingEngine(lm, n_slots=2)
+    eng.submit([3, 7, 2], max_new_tokens=12)
+    for _ in range(3):
+        eng.step()
+    assert eng._window
+    slot = next(iter(eng.scheduler.running))
+    with pytest.raises(AssertionError, match="flush the window"):
+        eng.row_state(slot)
+    eng.flush_window()
+    req = eng.scheduler.running[slot]
+    payload = eng.row_state(slot)
+    # settled: the row's device position is its emitted prefix's
+    assert int(np.asarray(payload["carry"]["pos"]).ravel()[0]) \
+        == len(req.prompt) + len(req.output) - 1
+
+
+@pytest.mark.parametrize("W", [1, 2])
+def test_window_tier_spill_byte_identity(W, lm, baseline):
+    """Priority preemption through the HOST TIER (the spill packs
+    ``row_state`` bytes): flushed before the victim is chosen, so the
+    spilled row resumes on its emitted prefix and no token is lost."""
+    from bigdl_tpu.serving import ServingEngine
+
+    trace = _trace()
+    eng = ServingEngine(lm, n_slots=2, policy="priority", tier=True,
+                        dispatch_ahead=W)
+    low = [eng.submit(p, max_new_tokens=n, sampling=sp)
+           for p, n, sp in trace[:2]]
+    for _ in range(4):
+        eng.step()
+    assert eng._window
+    hi = [eng.submit(p, max_new_tokens=n, sampling=sp, priority=5)
+          for p, n, sp in trace[2:]]
+    drained = eng.drain()
+    assert [list(drained[r]) for r in low + hi] == baseline
+    s = eng.metrics.summary()
+    assert s["serving/preempted"] >= 1 and s["serving/spills"] >= 1
+    assert s["serving/resumed_without_prefill"] >= 1
+
+
+@pytest.mark.disagg
+@pytest.mark.parametrize("W", [1, 2])
+def test_window_drain_pool_byte_identity(W, lm, baseline):
+    """A graceful pool drain with dispatches in flight migrates every
+    row on its emitted prefix: the tokens in flight are read back
+    first, and the migrated streams are the W=0 streams."""
+    from bigdl_tpu.serving import DisaggregatedEngine
+
+    d = DisaggregatedEngine(lm, prefill_slots=4, decode_slots=2,
+                            decode_pools=2, dispatch_ahead=W)
+    rids = [d.submit(p, max_new_tokens=n, sampling=sp)
+            for p, n, sp in _trace()]
+    for _ in range(5):
+        d.step()
+    busy = max(range(2), key=lambda i: len(d.decoders[i].engine._window))
+    assert d.decoders[busy].engine._window
+    assert d.drain_pool(busy) >= 1
+    assert not d.decoders[busy].engine._window
+    outs = d.drain()
+    assert [list(outs[r]) for r in rids] == baseline
+    assert d.metrics.summary()["serving/migrated_rows"] >= 1
+
+
+@pytest.mark.disagg
+def test_window_failover_discards_what_was_in_flight(lm, baseline):
+    """A pool killed with a dispatch in flight: nothing of it is read
+    (the window is dropped unfenced), its rows restore or replay from
+    their EMITTED prefixes on the survivor, byte-identically."""
+    from bigdl_tpu.serving import DisaggregatedEngine
+
+    d = DisaggregatedEngine(lm, prefill_slots=4, decode_slots=2,
+                            decode_pools=2)
+    rids = [d.submit(p, max_new_tokens=n, sampling=sp)
+            for p, n, sp in _trace()]
+    for _ in range(5):
+        d.step()
+    busy = max(range(2), key=lambda i: len(d.decoders[i].engine._window))
+    assert d.decoders[busy].engine._window
+    d.kill_pool(busy)
+    outs = d.drain()
+    assert not d.decoders[busy].engine._window
+    assert [list(outs[r]) for r in rids] == baseline
+    assert d.metrics.summary()["serving/pool_deaths"] == 1
+
+
+def test_cancel_mid_window_freezes_the_stream(lm, baseline):
+    """cancel() of a RUNNING row with its next token in flight: the
+    slot frees at once, the token in flight is thrown away, and the
+    next occupant of the slot serves its own stream."""
+    from bigdl_tpu.serving import ServingEngine
+
+    (p0, n0, s0), (p1, n1, s1) = _trace()[:2]
+    eng = ServingEngine(lm, n_slots=1)
+    a = eng.submit(p0, max_new_tokens=n0, sampling=s0)
+    b = eng.submit(p1, max_new_tokens=n1, sampling=s1)
+    for _ in range(4):
+        eng.step()
+    assert eng._window
+    kept = list(eng.scheduler.running[0].output)
+    assert eng.cancel(a)
+    outs = eng.drain()
+    assert list(eng.request(a).output) == kept == baseline[0][:len(kept)]
+    assert list(outs[b]) == baseline[1]
+    assert not eng._window
+
+
+def test_service_time_estimate_prices_a_token_at_one_step(lm):
+    """The dispatch-to-fence bracket of a CHAINED dispatch includes its
+    wait behind the previous program (``decode_step_s``: up to W + 1
+    steps long); what feasibility admission and deadline preemption
+    multiply by the tokens left is the part in which the dispatch had
+    the device. On a clock that ticks at every read: the estimate times
+    the tokens served never exceeds the time the run took, which the
+    bracket's median does under the window."""
+    from bigdl_tpu.serving import ServingEngine, SteppingClock
+
+    def run(W):
+        clk = SteppingClock(0.001)
+        eng = ServingEngine(lm, n_slots=1, clock=clk, dispatch_ahead=W)
+        eng.submit([3, 7, 2], max_new_tokens=30)
+        t0 = clk.t
+        eng.drain()
+        bracket = float(np.median(
+            eng.metrics.metrics.values("serving/decode_step_s")))
+        return eng.metrics.service_time_estimate(), bracket, clk.t - t0
+
+    est0, bracket0, _ = run(0)
+    assert est0 == pytest.approx(bracket0)       # W=0: the whole bracket
+    for W in (1, 2):
+        est, bracket, took = run(W)
+        assert est * 30 <= took < bracket * 30
+        assert est0 <= est < bracket
 
 
 # -- faults mid-window ------------------------------------------------------

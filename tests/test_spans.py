@@ -216,8 +216,10 @@ def test_queue_wait_one_sample_per_admission_on_the_engine_clock():
 
 
 def test_kv_used_share_is_sum_pos_over_reserved():
-    """Sampled from host state alone, it equals what the device holds:
-    sum of ``pos`` over the in-use slots / (slots x max_len)."""
+    """Sampled from host state alone at each consume, it equals what
+    the device holds once nothing is in flight (the device runs one
+    decode ahead of the host otherwise): sum of ``pos`` over the in-use
+    slots / (slots x max_len)."""
     from bigdl_tpu.serving import ServingEngine, VirtualClock
 
     eng = ServingEngine(_make_lm(), n_slots=4, clock=VirtualClock())
@@ -225,6 +227,7 @@ def test_kv_used_share_is_sum_pos_over_reserved():
         eng.submit(list(range(1, n + 1)), max_new_tokens=8)
     for _ in range(3):
         eng.step()
+        eng.flush_window()
         pos = np.asarray(eng.pool.carry["pos"])
         held = sum(int(pos[s]) for s in eng.scheduler.running)
         share = eng.metrics.metrics.values("serving/kv_used_share")[-1]

@@ -339,7 +339,7 @@ def test_srv206_real_tree_clean_and_mutation_caught(tmp_path):
     clean = analyze_paths([str(tmp_path)], select=["SRV206"])
     assert clean == [], [f.format() for f in clean]
     src = (tree / "disagg.py").read_text()
-    needle = "payload = self.engine.pool.row_state(slot)"
+    needle = "payload = self.engine.row_state(slot)"
     assert needle in src, "_release moved — update the census"
     (tree / "disagg.py").write_text(
         src.replace(needle, "payload = None", 1))
