@@ -29,6 +29,9 @@ from __future__ import annotations
 
 from typing import Dict, NamedTuple, Optional
 
+from bigdl_tpu.models.decoder_ops import rms_norm as _rms_norm
+from bigdl_tpu.models.decoder_ops import rope as _rope
+from bigdl_tpu.models.decoder_ops import swiglu
 from bigdl_tpu.nn.module import AbstractModule
 
 
@@ -96,31 +99,6 @@ class FalconH1Config(NamedTuple):
 
 
 # ------------------------------------------------------------ the layer
-
-
-def _rms_norm(x, weight, eps):
-    import jax.numpy as jnp
-    from jax import lax
-
-    x32 = x.astype(jnp.float32)
-    x32 = x32 * lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
-    return (x32 * weight.astype(jnp.float32)).astype(x.dtype)
-
-
-def _rope(x, pos, theta):
-    """Rotate-half rotary embedding over the whole head: ``x`` (B, T,
-    heads, d), ``pos`` (B, T) absolute positions."""
-    import jax.numpy as jnp
-
-    half = x.shape[-1] // 2
-    inv_freq = jnp.asarray(theta, jnp.float32) ** (
-        -jnp.arange(half, dtype=jnp.float32) / half)
-    ang = pos.astype(jnp.float32)[..., None, None] * inv_freq   # B,T,1,half
-    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)
-    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)
-    x32 = x.astype(jnp.float32)
-    rot = jnp.concatenate([-x32[..., half:], x32[..., :half]], -1)
-    return (x32 * cos + rot * sin).astype(x.dtype)
 
 
 def _attention(cfg, p, u, qpos, valid, cache, decode):
@@ -320,11 +298,7 @@ def _mixer(cfg, p, h, valid, cache, decode):
 
 
 def _mlp(cfg, p, u):
-    import jax
-
-    gate = jax.nn.silu((u @ p["gate"]) * cfg.mlp_multipliers[0])
-    return (((u @ p["up"]) * gate).astype(u.dtype) @ p["down"]) \
-        * cfg.mlp_multipliers[1]
+    return swiglu(u, p, cfg.mlp_multipliers[0]) * cfg.mlp_multipliers[1]
 
 
 def _block(cfg, p, x, qpos, valid, cache=None, decode=False):

@@ -27,7 +27,12 @@ shape-stable pipeline:
   requests — admission never compiles mid-flight after the buckets are
   warm. (Ballast rows cost padding FLOPs; on the MXU a small fixed B
   is the cheap side of that trade, and shape stability is the point —
-  it is also what keeps a future SHARDED prefill program reusable.);
+  it is also what keeps a future SHARDED prefill program reusable.)
+  A family may bound a wave in TOKENS instead (``prefill_token_bound``,
+  ``serving/family.py``): its rows then follow the bucket
+  (:meth:`AdmissionController.wave_rows`: the largest power of two
+  with ``rows x L`` within the bound, at most ``n_slots``), still ONE
+  program a bucket, and more arrivals than rows prefill in chunks;
 * every produced row is scattered into its :class:`KVPool` slot through
   the existing donated scatter (``write_prefill(..., row=j)``);
 * with a :class:`bigdl_tpu.serving.prefix_cache.PrefixCache` attached,
@@ -120,6 +125,9 @@ class AdmissionController:
         # shape per length bucket, independent of arrival grouping (an
         # admission round never has more than n_slots rows to fill)
         self.prefill_rows = int(prefill_rows) or engine.pool.n_slots
+        # a family's bound on a wave in tokens (wave_rows), or None
+        self.token_bound = getattr(engine._family, "prefill_token_bound",
+                                   None)
         # ONE shared fresh zero carry, built lazily and reused for every
         # admission (prefill never donates its carry and jax arrays are
         # immutable, so sharing the zero input is free)
@@ -182,7 +190,25 @@ class AdmissionController:
             return slot, req, None
         return slot, req, pf
 
-    def _zero_carry(self) -> dict:
+    def wave_rows(self, L: int) -> int:
+        """Rows of the prefill wave of bucket ``L``: ``prefill_rows``,
+        or under a family's token bound the largest power of two with
+        ``rows x L`` within it (at least one row, at most
+        ``prefill_rows``)."""
+        if self.token_bound is None:
+            return self.prefill_rows
+        rows = 1
+        while rows * 2 * L <= self.token_bound:
+            rows *= 2
+        return min(rows, self.prefill_rows)
+
+    def _zero_carry(self) -> Optional[dict]:
+        """The shared fresh carry a wave's prefill is handed; None for
+        a family under a token bound, whose prefill makes its fresh rows
+        inside the program (its waves have no one row count to share a
+        carry for)."""
+        if self.token_bound is not None:
+            return None
         if self._zero_carry_cache is None:
             self._zero_carry_cache = self.engine._pool_init(self.prefill_rows)
         return self._zero_carry_cache
@@ -242,8 +268,9 @@ class AdmissionController:
         for L in sorted(groups):
             rows = groups[L]
             # a bucket larger than the row block prefills in chunks
-            for lo in range(0, len(rows), self.prefill_rows):
-                chunk = rows[lo:lo + self.prefill_rows]
+            B = self.wave_rows(L)
+            for lo in range(0, len(rows), B):
+                chunk = rows[lo:lo + B]
                 try:
                     self._prefill_bucket(L, chunk)
                 except FaultError:
@@ -304,7 +331,7 @@ class AdmissionController:
 
         eng = self.engine
         k = len(rows)
-        B = self.prefill_rows
+        B = self.wave_rows(L)
         toks = np.zeros((B, L), np.int32)
         lengths = np.zeros((B,), np.int32)     # pad rows stay ballast (0)
         aids = np.zeros((B,), np.int32)        # pad rows: null adapter

@@ -111,12 +111,16 @@ class _InFlight:
     anchor)."""
 
     __slots__ = ("tok", "chosen", "active", "active_dev", "rows", "t0",
-                 "had_running")
+                 "had_running", "extra")
 
     def __init__(self, tok, chosen, active, active_dev, rows, t0,
-                 had_running):
+                 had_running, extra=()):
         self.tok = tok                  # device handle: next 0-based ids
         self.chosen = chosen            # device handle: chosen logprobs
+        # device handles a family's step returns after the carry (an
+        # expert family's per-expert token counts): read back at the
+        # SAME fence as the tokens
+        self.extra = extra
         self.active = active            # host bool mask at dispatch
         self.active_dev = active_dev    # the mask's PLACED device twin
         self.rows = rows                # {slot: Request} at dispatch
@@ -455,7 +459,8 @@ class ServingEngine:
             self._step_fn = None
             pool_init = self._spec.pool_init
         self._pool_init = pool_init
-        self.pool = (KVPool(pool_init, n_slots, kv_dtype=kv_dtype)
+        self.pool = (KVPool(pool_init, n_slots, kv_dtype=kv_dtype,
+                            max_len=family.max_len)
                      if self._plane is None
                      else self._plane.make_pool(model, pool_init, n_slots,
                                                 kv_quant=kv_quant,
@@ -1112,6 +1117,16 @@ class ServingEngine:
                     for slot in sched.partial)
         return used / (self.pool.n_slots * self.pool.max_len)
 
+    def _kv_held_bytes(self) -> int:
+        """Bytes of K/V the running rows really hold, from host state
+        alone: per row and layer ``min(pos, len_i)`` positions (a ring
+        holds at most its window, whatever the row's position)."""
+        sched, held = self.scheduler, self.pool.kv_held_bytes
+        return sum(held(len(r.prompt) + len(r.output))
+                   for r in sched.running.values()) \
+            + sum(held(int(self.pool.chunk_done[slot]))
+                  for slot in sched.partial)
+
     def _state_in_use(self) -> Optional[int]:
         """Bytes of per-slot ``state`` leaves the in-use slots hold
         (each holds all of its own, whatever its position), from host
@@ -1647,7 +1662,8 @@ class ServingEngine:
         # DEVICE_PHASES half of the host_step split.
         with self.metrics.span("consume"):
             with self.metrics.span("fence", phase="fence_wait"):
-                nxt, lps = fence("decode", entry.tok, entry.chosen)
+                nxt, lps, *extra = fence("decode", entry.tok, entry.chosen,
+                                         *entry.extra)
             now = self._clock()
             # the watchdog's elapsed spans dispatch → readback landed; at
             # W>0 that window covers host work on other in-flight steps
@@ -1703,7 +1719,10 @@ class ServingEngine:
                 self.metrics.on_step(
                     self.scheduler.queue_depth, self.pool.occupancy(),
                     len(rows), kv_used_share=self._kv_used_share(),
-                    state_in_use_bytes=self._state_in_use())
+                    state_in_use_bytes=self._state_in_use(),
+                    kv_held_bytes=self._kv_held_bytes())
+                if extra:
+                    self.metrics.on_expert_counts(extra[0])
                 self.metrics.on_sample_rows(n_sampled,
                                             len(rows) - n_sampled)
             for slot, req in list(rows.items()):
@@ -1845,7 +1864,7 @@ class ServingEngine:
                             k: self._place_rows(jnp.asarray(v))
                             for k, v in self._knobs.items()}
                     knobs = self._knobs_device
-                    tok, chosen, carry = self._dispatch(
+                    tok, chosen, carry, *extra = self._dispatch(
                         "decode", self._step_fn,
                         self.params, tokens_dev, active_dev,
                         self.pool.carry, knobs, *self._adapter_args())
@@ -1870,7 +1889,7 @@ class ServingEngine:
             # site, serving/fences.py). t0 rides the entry so the
             # watchdog's elapsed covers the device work, not the launch
             self._window.append(_InFlight(tok, chosen, active, active_dev,
-                                          rows, t0, had_running))
+                                          rows, t0, had_running, extra))
             # delayed consumer: fence the oldest entry once the window
             # exceeds its DECLARED depth knob (fences.WINDOW_KNOBS —
             # ASY308 rejects any other bound). dispatch_ahead=0 consumes

@@ -16,10 +16,22 @@ model class anywhere else:
   refuse: a family that refuses them all is only ever asked for the
   default);
 * ``refuses`` — engine option -> why the family cannot take it; the
-  engine raises a ``ValueError`` naming the option at construction.
+  engine raises a ``ValueError`` naming the option at construction;
+* ``prefill_token_bound`` (optional; absent for a family whose waves
+  are ``n_slots`` rows whatever the bucket) — the most tokens (rows x
+  bucket) one prefill wave may hold: the rows of a wave then follow its
+  bucket (``AdmissionController.wave_rows``). Such a family's prefill
+  makes its fresh cache rows inside the program and is handed no
+  carry; its K/V leaves may come back shorter than the pool's (the
+  bucket's columns), and the pool's scatter writes what it is given.
+
+A family's decode step may return more after ``(token, chosen_logp,
+carry)``: device values the engine reads back at the SAME decode fence
+and hands to ``ServingMetrics`` (a routed-expert family's per-expert
+token counts, ``on_expert_counts``).
 
 A model brings its family as ``model.serving_family()``
-(``models/falcon_h1.py``). A ``Sequential`` ``TransformerLM`` has no
+(``models/falcon_h1.py``, ``models/afmoe.py``). A ``Sequential`` ``TransformerLM`` has no
 such method and gets :class:`SequentialLMFamily`, which delegates to
 the step factories of ``models/transformer.py`` and refuses nothing.
 """

@@ -16,8 +16,15 @@ the slot as its first axis, and the pool treats each by WHAT IT IS
 
 * ``pos`` — the row's position counter;
 * ``kv`` — ``k{i}`` / ``v{i}``, position-indexed caches ``(n_slots,
-  max_len, heads*head_dim)``: admission scatters them, ``free()``
-  leaves them (stale rows are masked by ``pos``);
+  len_i, heads*head_dim)``: admission scatters them, ``free()``
+  leaves them (stale rows are masked by ``pos``). ``len_i`` is the
+  LAYER's: the family's cache window ``max_len`` for a layer that
+  attends over the whole context, less for a sliding-window layer,
+  whose leaf is a RING that holds position ``p`` at ``p % len_i`` and
+  is valid up to ``min(pos, len_i)`` entries (``models/afmoe.py``).
+  Everything here goes by each leaf's own length; the scatter writes
+  the columns a prefilled row brings, which may be fewer than the
+  leaf's (a bucket shorter than the window);
 * ``scale`` — ``k{i}_scale`` / ``v{i}_scale``, the int8 layout's
   per-(slot, head) dequant scales: scattered with their rows, reset to
   zero on ``free()`` (grow-only mid-flight);
@@ -102,7 +109,8 @@ class KVPool:
     """
 
     def __init__(self, init_carry, n_slots: int,
-                 kv_dtype: Optional[str] = None) -> None:
+                 kv_dtype: Optional[str] = None,
+                 max_len: Optional[int] = None) -> None:
         import jax
         import numpy as np
 
@@ -118,7 +126,12 @@ class KVPool:
         # k0, k1, ... — NOT k0_scale (the int8 layout's dequant scales)
         self.n_layers = sum(1 for k in self.carry
                             if k[0] == "k" and leaf_kind(k) == "kv")
-        self.max_len = int(self.carry["k0"].shape[1])
+        # the positions a slot may hold: the family's cache window
+        # where the engine hands it over, else the longest K/V leaf
+        # (``k0`` may be a ring shorter than the window)
+        self.max_len = int(max_len or max(
+            v.shape[1] for k, v in self.carry.items()
+            if leaf_kind(k) == "kv"))
         self.quantized = "k0_scale" in self.carry
         # the storage-format knob is declarative: the carry (built by
         # make_batch_decode_step's init_carry) is the ground truth, and
@@ -142,6 +155,15 @@ class KVPool:
                 for k, v in self.carry.items() if leaf_kind(k) in kinds))
 
         self.kv_bytes_per_slot = slot_bytes("kv", "scale")
+        # leaf length -> bytes a position, summed over the K/V leaves of
+        # that length: what a row at ``pos`` really holds of its slot
+        # (kv_held_bytes; one entry where every leaf is ``max_len``)
+        self._kv_position_bytes: Dict[int, int] = {}
+        for k, v in self.carry.items():
+            if leaf_kind(k) == "kv":
+                self._kv_position_bytes[int(v.shape[1])] = \
+                    self._kv_position_bytes.get(int(v.shape[1]), 0) \
+                    + v.dtype.itemsize * int(np.prod(v.shape[2:]))
         self.state_bytes_per_slot = slot_bytes("state")
         # LIFO free list: the most recently freed row is the most likely
         # to still be resident in cache/HBM
@@ -294,6 +316,13 @@ class KVPool:
         # into a ZeroDivisionError mid-serving
         return self.used_slots / self.n_slots if self.n_slots else 0.0
 
+    def kv_held_bytes(self, pos: int) -> int:
+        """Bytes of K/V a row at position ``pos`` holds: per leaf
+        ``min(pos, len_i)`` positions (a ring never holds more than its
+        window)."""
+        return sum(min(int(pos), length) * nbytes
+                   for length, nbytes in self._kv_position_bytes.items())
+
     def used_per_shard(self) -> List[int]:
         """Allocated-slot count per shard (one logical shard here; the
         mesh-aware subclass reports per-device counts — the imbalance
@@ -318,10 +347,13 @@ class KVPool:
         ``prompt_len`` times. ``prefill_carry`` may be the old B=1
         per-request carry (``row=0``) or a multi-row batched-admission
         carry (``make_batch_prefill_step`` output — ``row`` picks the
-        request's row). The full ``max_len`` row is copied — the tail
-        beyond ``prompt_len`` is invisible behind ``pos`` — via the
-        jitted donated scatter built in ``__init__`` (one trace per
-        prefill-carry row count; ``row`` rides as a traced argument)."""
+        request's row). Every column the carry's leaves bring is copied
+        (the full row for the families whose prefill fills a pool-shaped
+        carry; ``min(bucket, len_i)`` columns where the prefill makes
+        its fresh rows itself) — the tail beyond ``prompt_len`` is
+        invisible behind ``pos`` — via the jitted donated scatter built
+        in ``__init__`` (one trace per prefill-carry shape; ``row``
+        rides as a traced argument)."""
         import jax.numpy as jnp
 
         if slot not in self._in_use:

@@ -34,6 +34,17 @@ Counters (all under the ``serving/`` prefix in the backing Metrics):
   state, a convolution window): in-use slots x
   ``state_bytes_per_slot``, host state, no readback. A slot holds all
   of its state whatever its position
+* ``kv_held_bytes``     — sampled every engine step: bytes of K/V the
+  running rows really hold, per row and layer ``min(pos, len_i)``
+  positions (a sliding-window layer's ring holds at most its window),
+  host state, no readback; ``kv_used_share`` keeps its meaning (``pos``
+  over ``n_slots x max_len``)
+* ``expert_pairs`` / ``experts_hit`` / ``expert_load_max`` — per decode
+  step of a family with routed experts, from the per-expert token
+  counts its program returns with the tokens (read back at the SAME
+  decode fence): (token, expert) pairs this chip's experts computed,
+  summed over the expert layers; held experts with at least one token,
+  summed over layers; the busiest held expert's tokens
 * ``fence_wait_s``      — the time the host was BLOCKED in the step's
   one fence readback (span ``fence``; the ``DEVICE_PHASES`` half of the
   ``host_step_s`` split)
@@ -288,7 +299,8 @@ class ServingMetrics:
 
     def on_step(self, queue_depth: int, occupancy: float,
                 batch_active: int, kv_used_share: float,
-                state_in_use_bytes: Optional[int] = None) -> None:
+                state_in_use_bytes: Optional[int] = None,
+                kv_held_bytes: Optional[int] = None) -> None:
         # a declared CLOCK_SITES unit (serving/faults.py): the serve-
         # duration anchor timestamps (_t_start/_t_last span the whole
         # serve for summary()'s wall number) deliberately read the raw
@@ -306,6 +318,15 @@ class ServingMetrics:
         if state_in_use_bytes is not None:
             self.metrics.add("serving/state_in_use_bytes",
                              float(state_in_use_bytes))
+        if kv_held_bytes is not None:
+            self.metrics.add("serving/kv_held_bytes", float(kv_held_bytes))
+
+    def on_expert_counts(self, counts) -> None:
+        """One decode step's ``(n_expert_layers, held)`` token counts,
+        as the fence read them back."""
+        self.metrics.add("serving/expert_pairs", float(counts.sum()))
+        self.metrics.add("serving/experts_hit", float((counts > 0).sum()))
+        self.metrics.add("serving/expert_load_max", float(counts.max()))
 
     def on_first_token(self, ttft_s: float) -> None:
         self.metrics.add("serving/ttft_s", float(ttft_s))

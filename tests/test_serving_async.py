@@ -610,11 +610,9 @@ def test_window_census_clock_blind_consumer_detected(tmp_path):
     tree = _serving_tree(tmp_path)
     _mutate(
         tree,
-        "                nxt, lps = fence(\"decode\", entry.tok, "
-        "entry.chosen)\n"
+        "                                         *entry.extra)\n"
         "            now = self._clock()\n",
-        "                nxt, lps = fence(\"decode\", entry.tok, "
-        "entry.chosen)\n"
+        "                                         *entry.extra)\n"
         "            now = 0.0\n")
     found = _scan(tmp_path)
     assert [f.code for f in found] == ["ASY310"], (
