@@ -14,15 +14,16 @@ step with no per-token host dispatch in it), and report
 tokens/sec = batch * 256 / wall.
 
 ``--attention`` switches to the pooled decode-attention OP bench
-(``measure_attention``): Pallas kernel vs jnp reference step wall time
-at each model's serving geometry, float and int8-quantized layouts —
-the per-step bandwidth half of the int8-KV story (PR 6 measured
-capacity; this row measures time). CPU runs execute the kernel in
-interpret mode and say so in the row; run on TPU for real numbers.
+(``measure_attention``): the Pallas kernel against the whole-window
+folded sum over the STORED ``(N, L, G*D)`` cache, at the shapes the
+serving cells bring (``ATTENTION_SHAPES``), by how full the pool is
+(``--fills``: the share of ``L`` each decoding row holds) and how many
+rows decode (``--active``). CPU runs execute the kernel in interpret
+mode and say so in the row; run on TPU for real numbers.
 
     python -m benchmarks.decode_bench
     ... --models 137m --batches 1 8 --variants bf16 int8   # subset
-    ... --attention --models 137m 371m --variants bf16 int8
+    ... --attention --shapes trinity-ring --fills 0.15 1.0 --active 0.33
 """
 
 from __future__ import annotations
@@ -177,67 +178,102 @@ def measure(name: str, variant: str, batch: int, reps: int = 3) -> dict:
     }
 
 
-def measure_attention(name: str, batch: int, variant: str,
+#: the pooled decode attention of the serving cells, as stored: rows,
+#: window, query heads, K/V heads, head width (BENCHMARK.json's configs)
+ATTENTION_SHAPES = {
+    "gpt2m": dict(n=32, L=1024, h=16, g=16, d=64),
+    "falconh1": dict(n=32, L=1024, h=20, g=4, d=128),
+    "trinity-ring": dict(n=16, L=4096, h=48, g=8, d=128),
+    "trinity-full": dict(n=16, L=8192, h=48, g=8, d=128),
+}
+
+
+def measure_attention(shape: str, fill: float, active_share: float,
+                      variant: str = "bf16", block=None,
                       reps: int = 3) -> dict:
-    """Pooled decode-attention STEP wall time, Pallas kernel vs the jnp
-    reference (``ops/decode_attention.py``) at this model's serving
-    geometry — the unmeasured half of the int8-KV story: the fused
-    int8 dequant halves the bytes the kernel streams per step, and this
-    row is where that shows up as time. ``variant``: ``int8`` benches
-    the quantized layout (int8 K/V + per-(row, head) fp32 scales),
-    ``fp32``/``bf16`` the float cache. On a CPU host the "kernel" path
-    runs in Pallas INTERPRET mode (``compat.auto_interpret``) — a
-    functional dryrun whose time is emulation overhead, not kernel
-    speed; the row carries ``interpret`` so readers can tell (run on
-    TPU for the bandwidth numbers)."""
+    """One pooled decode-attention call, the Pallas kernel against the
+    folded whole-window sum (``ops/decode_attention.py``), over the
+    stored ``(N, L, G*D)`` cache of ``ATTENTION_SHAPES[shape]``: every
+    ``1 / active_share``-th row decodes and holds ``fill`` of ``L``.
+    ``variant`` ``int8`` benches the quantized layout against the jnp
+    reference (ungrouped shapes only). Each timing is one program of
+    ``CALLS`` dependent calls (a call's output feeds the next one's
+    query), so the launch is amortised. On a CPU host the kernel runs
+    in Pallas INTERPRET mode — emulation time, not kernel speed; the
+    row carries ``interpret`` so readers can tell."""
     import jax
     import jax.numpy as jnp
+    from jax import lax
 
-    from bigdl_tpu.ops.decode_attention import decode_attention
+    from bigdl_tpu.ops.decode_attention import (
+        auto_block_l, decode_attention, decode_attention_reference,
+        fetched_blocks, folded_decode_attention,
+    )
     from bigdl_tpu.utils.compat import auto_interpret
 
-    cfg = MODELS[name]
-    heads, hd = cfg["heads"], cfg["hidden"] // cfg["heads"]
-    L = PROMPT + GEN
+    CALLS = 16
+    dims = ATTENTION_SHAPES[shape]
+    n, L, h, g, d = (dims[key] for key in "nLhgd")
     rng = np.random.default_rng(0)
-    q = jnp.asarray(rng.standard_normal((batch, heads, hd)), jnp.float32)
-    pos = jnp.asarray(rng.integers(L // 2, L, size=(batch,)), jnp.int32)
+    q = jnp.asarray(rng.standard_normal((n, h, d)), jnp.bfloat16)
+    stride = max(1, round(1 / active_share))
+    active = np.arange(n) % stride == 0
+    pos = np.full((n,), max(1, round(fill * L)) - 1, np.int32)
+    ks = vs = None
     if variant == "int8":
-        k = jnp.asarray(rng.integers(-127, 128,
-                                     size=(batch, L, heads, hd)), jnp.int8)
-        v = jnp.asarray(rng.integers(-127, 128,
-                                     size=(batch, L, heads, hd)), jnp.int8)
-        ks = jnp.asarray(0.02 + 0.01 * rng.random((batch, heads)),
-                         jnp.float32)
-        vs = jnp.asarray(0.02 + 0.01 * rng.random((batch, heads)),
-                         jnp.float32)
+        k, v = (jnp.asarray(rng.integers(-127, 128, size=(n, L, g * d)),
+                            jnp.int8) for _ in range(2))
+        ks, vs = (jnp.asarray(0.02 + 0.01 * rng.random((n, h)),
+                              jnp.float32) for _ in range(2))
     else:
-        dt = {"fp32": jnp.float32, "bf16": jnp.bfloat16}[variant]
-        k = jnp.asarray(rng.standard_normal((batch, L, heads, hd)), dt)
-        v = jnp.asarray(rng.standard_normal((batch, L, heads, hd)), dt)
-        ks = vs = None
+        k, v = (jnp.asarray(rng.standard_normal((n, L, g * d)),
+                            jnp.bfloat16) for _ in range(2))
+    if block is None:
+        block = auto_block_l(L, g * d * k.dtype.itemsize)
+    args = (q, k, v, jnp.asarray(pos), jnp.asarray(active))
 
-    def timed(impl: str) -> float:
-        fn = jax.jit(lambda *a: decode_attention(
-            *a, k_scale=ks, v_scale=vs, impl=impl))
-        jax.block_until_ready(fn(q, k, v, pos))     # compile + warm
+    def whole_window(q, k, v, pos, active):
+        if ks is not None:
+            return decode_attention_reference(q, k, v, pos, k_scale=ks,
+                                              v_scale=vs)
+        return folded_decode_attention(q, k, v, pos)
+
+    def kernel(q, k, v, pos, active):
+        return decode_attention(q, k, v, pos, k_scale=ks, v_scale=vs,
+                                active=active, block=block, impl="kernel")
+
+    def timed(fn) -> float:
+        def many(q, *rest):
+            return lax.fori_loop(
+                0, CALLS,
+                lambda _, qq: qq + (fn(qq, *rest) * 1e-3).astype(qq.dtype),
+                q)
+
+        many = jax.jit(many)
+        jax.block_until_ready(many(*args))          # compile + warm
         best = float("inf")
         for _ in range(reps):
             t0 = time.perf_counter()
-            jax.block_until_ready(fn(q, k, v, pos))
+            jax.block_until_ready(many(*args))
             best = min(best, time.perf_counter() - t0)
-        return best
+        return best / CALLS
 
-    ref_s = timed("reference")
-    kern_s = timed("kernel")
+    want = np.asarray(jax.jit(whole_window)(*args), np.float32)[active]
+    got = np.asarray(jax.jit(kernel)(*args), np.float32)[active]
+    whole_s = timed(whole_window)
+    kern_s = timed(kernel)
+    position_bytes = 2 * g * d * k.dtype.itemsize
     return {
-        "metric": "decode_attention_step_ms", "model": name,
-        "variant": variant, "rows": batch, "heads": heads,
-        "head_dim": hd, "window": L,
-        "interpret": bool(auto_interpret()),
-        "reference_ms": round(1e3 * ref_s, 3),
-        "kernel_ms": round(1e3 * kern_s, 3),
-        "kernel_vs_reference": round(ref_s / max(kern_s, 1e-9), 3),
+        "metric": "decode_attention_call_ms", "shape": shape, **dims,
+        "variant": variant, "fill": fill, "rows_decoding": int(active.sum()),
+        "block": block, "interpret": bool(auto_interpret()),
+        "fetched_mb": round(1e-6 * block * position_bytes * int(
+            fetched_blocks(pos, active, L, block).sum()), 2),
+        "stored_mb": round(1e-6 * n * L * position_bytes, 2),
+        "max_abs_diff": float(np.abs(got - want).max()),
+        "whole_window_ms": round(1e3 * whole_s, 4),
+        "kernel_ms": round(1e3 * kern_s, 4),
+        "kernel_vs_whole_window": round(whole_s / max(kern_s, 1e-9), 3),
     }
 
 
@@ -251,8 +287,16 @@ def main(argv=None) -> None:
     p.add_argument("--reps", type=int, default=3)
     p.add_argument("--attention", action="store_true",
                    help="bench the pooled decode-attention op (Pallas "
-                        "kernel vs jnp reference) instead of the full "
-                        "decode loop")
+                        "kernel vs the whole-window sum) instead of the "
+                        "full decode loop")
+    p.add_argument("--shapes", nargs="+", default=sorted(ATTENTION_SHAPES),
+                   choices=sorted(ATTENTION_SHAPES))
+    p.add_argument("--fills", nargs="+", type=float,
+                   default=[0.02, 0.15, 1.0])
+    p.add_argument("--active", nargs="+", type=float, default=[1 / 3, 1.0])
+    p.add_argument("--blocks", nargs="+", type=int, default=[None],
+                   help="KV tile lengths to try (default: the kernel's "
+                        "own choice)")
     args = p.parse_args(argv)
 
     from bigdl_tpu.utils.compile_cache import enable_compile_cache
@@ -261,12 +305,17 @@ def main(argv=None) -> None:
     # a row that fails fails the run — a kernel the compiler refuses
     # must not end up as an "error" field under exit status 0
     if args.attention:
-        for name in args.models:
-            for b in args.batches:
-                for v in args.variants:
-                    print(json.dumps(measure_attention(name, b, v,
-                                                       args.reps)),
-                          flush=True)
+        for shape in args.shapes:
+            dims = ATTENTION_SHAPES[shape]
+            for v in args.variants:
+                if v == "fp32" or (v == "int8" and dims["g"] != dims["h"]):
+                    continue
+                for fill in args.fills:
+                    for share in args.active:
+                        for block in args.blocks:
+                            print(json.dumps(measure_attention(
+                                shape, fill, share, v, block, args.reps)),
+                                flush=True)
         return
 
     rows = []

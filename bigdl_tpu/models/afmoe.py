@@ -194,7 +194,7 @@ def _attention(cfg, p, a, qpos, valid, sliding, cache, fresh_len):
     import jax
     import jax.numpy as jnp
 
-    from bigdl_tpu.ops.decode_attention import folded_decode_attention
+    from bigdl_tpu.ops.decode_attention import decode_attention
 
     B, T, _ = a.shape
     nq, nkv, d = cfg.num_attention_heads, cfg.num_key_value_heads, \
@@ -222,9 +222,9 @@ def _attention(cfg, p, a, qpos, valid, sliding, cache, fresh_len):
         kc = cache["k"].at[rows, wpos].set(k_wr)
         vc = cache["v"].at[rows, wpos].set(v_wr)
         # a ring that has wrapped is valid whole; before, up to pos
-        ctx = folded_decode_attention(
+        ctx = decode_attention(
             q[:, 0], kc, vc, jnp.minimum(pos, length - 1), scale=scale,
-            out_dtype=a.dtype).reshape(B, 1, nq * d)
+            out_dtype=a.dtype, active=on).reshape(B, 1, nq * d)
         new_cache = {"k": kc, "v": vc}
     else:
         ctx = _blocked_attention(q, k, v.reshape(B, T, nkv, d),

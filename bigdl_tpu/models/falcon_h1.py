@@ -110,7 +110,7 @@ def _attention(cfg, p, u, qpos, valid, cache, decode):
     import jax
     import jax.numpy as jnp
 
-    from bigdl_tpu.ops.decode_attention import folded_decode_attention
+    from bigdl_tpu.ops.decode_attention import decode_attention
 
     B, T, _ = u.shape
     nq, nkv, d = cfg.num_attention_heads, cfg.num_key_value_heads, \
@@ -133,8 +133,8 @@ def _attention(cfg, p, u, qpos, valid, cache, decode):
                          cache["v"][rows, wpos])
         kc = cache["k"].at[rows, wpos].set(k_wr)
         vc = cache["v"].at[rows, wpos].set(v_wr)
-        ctx = folded_decode_attention(q[:, 0], kc, vc, wpos, scale=scale,
-                                      out_dtype=u.dtype)
+        ctx = decode_attention(q[:, 0], kc, vc, wpos, scale=scale,
+                               out_dtype=u.dtype, active=on)
         ctx = ctx.reshape(B, 1, nq * d)
         return ctx @ p["wo"], {"k": kc, "v": vc}
     qg = (q * scale).astype(u.dtype).reshape(B, T, nkv, nq // nkv, d)

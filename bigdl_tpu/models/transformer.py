@@ -784,18 +784,25 @@ def _block(blk, bp, i, x, proj, row_proj, attend):
 
 
 def _token_view(new_carry, active, max_len, scale, cache_dtype,
-                kv_quant=False):
+                kv_quant=False, rows_over=None):
     """View *token*: one query a row at the row's ``pos``, read through
     the single-query attention over the stored cache. ``active`` None:
     lockstep rows at the uniform ``pos[0]`` (:func:`make_decode_step`).
     ``active`` (N,) bool: pooled rows, each at its own ``pos[r]``, the
-    inactive ones pure ballast (:func:`make_batch_decode_step`)."""
+    inactive ones pure ballast (:func:`make_batch_decode_step`).
+    ``rows_over`` ``(mesh, axis)``: the program is a plain jit whose
+    rows XLA shards over that mesh axis by itself. On a TPU the pooled
+    attention is a Mosaic kernel, which XLA refuses to partition
+    ("cannot be automatically partitioned"): there each device runs it
+    over the rows it holds, under a ``shard_map`` by rows (rows never
+    interact: no collective)."""
     import jax.numpy as jnp
     from jax import lax
 
     from bigdl_tpu.ops.decode_attention import (
         decode_attention, folded_decode_attention,
     )
+    from bigdl_tpu.utils.compat import auto_interpret, shard_map
 
     pos = new_carry["pos"]
     n = pos.shape[0]
@@ -826,6 +833,18 @@ def _token_view(new_carry, active, max_len, scale, cache_dtype,
 
     def pos_rows(pos_w):
         return jnp.take(pos_w, wpos, axis=0)
+
+    def attend_rows(q, kc, vc, at, on, *scales):
+        return decode_attention(q, kc, vc, at, *scales, scale=scale,
+                                out_dtype=q.dtype, active=on)
+
+    if rows_over is not None and not auto_interpret():
+        from jax.sharding import PartitionSpec
+
+        mesh, axis = rows_over
+        attend_rows = shard_map(
+            attend_rows, mesh=mesh, in_specs=PartitionSpec(axis),
+            out_specs=PartitionSpec(axis), check_vma=False)
 
     def attend(i, q, k_new, v_new):
         kc_prev, vc_prev = new_carry[f"k{i}"], new_carry[f"v{i}"]
@@ -861,22 +880,17 @@ def _token_view(new_carry, active, max_len, scale, cache_dtype,
         kc = kc_prev.at[rows, wpos].set(k_wr)
         vc = vc_prev.at[rows, wpos].set(v_wr)
         new_carry[f"k{i}"], new_carry[f"v{i}"] = kc, vc
-        if kv_quant:
-            # the pooled decode op: Pallas kernel on TPU (int8 K/V
-            # loads, dequant fused as two scalar factors), jnp
-            # reference elsewhere — per-row masked single-query
-            # attention over cols 0..wpos[r]
-            ctx = decode_attention(
-                q, kc, vc, wpos, k_scale=ks_new, v_scale=vs_new,
-                scale=scale, out_dtype=q.dtype)
-        else:
-            # per-row causal mask over the row's own cache prefix, read
-            # from the stored 3-D array (a 4-D view here costs two
-            # pool-sized copies per tensor per token on the TPU);
-            # scores accumulate fp32 regardless of the serving dtype
-            ctx = folded_decode_attention(
-                q, kc, vc, wpos, scale=scale, out_dtype=q.dtype)
-        return ctx.reshape(k_new.shape)
+        # the pooled decode op, per-row masked single-query attention
+        # over cols 0..wpos[r] of the stored 3-D array (a 4-D view here
+        # costs two pool-sized copies per tensor per token on the TPU):
+        # on a TPU the Pallas kernel, which fetches only the blocks an
+        # ACTIVE row holds (int8 K/V loaded raw, dequant fused as two
+        # scalar factors); elsewhere the whole-window jnp sums. Scores
+        # accumulate fp32 regardless of the serving dtype
+        scales = (new_carry[f"k{i}_scale"],
+                  new_carry[f"v{i}_scale"]) if kv_quant else ()
+        return attend_rows(q, kc, vc, wpos, active,
+                           *scales).reshape(k_new.shape)
 
     return pos_rows, attend
 
@@ -1497,7 +1511,7 @@ def make_decode_step(model: Sequential, compute_dtype=None):
 def make_batch_decode_step(model: Sequential, compute_dtype=None,
                            sampling: bool = False, mesh=None,
                            data_axis: str = "data",
-                           model_axis: str = "model",
+                           model_axis: Optional[str] = "model",
                            kv_quant: bool = False,
                            adapter=None):
     """Per-ROW-position decode step for continuous batching
@@ -1565,9 +1579,15 @@ def make_batch_decode_step(model: Sequential, compute_dtype=None,
     ``mlp_ratio*hidden`` divisible by the model-axis size, float (non-
     quantized) weights, and no layer_scan. Per-row math is unchanged —
     only the two closing psums reorder float sums, so outputs match the
-    unsharded step to round-off (slot-data-parallel-only meshes skip
-    shard_map entirely and stay bitwise identical; pinned by
-    tests/test_serving_sharded.py).
+    unsharded step to round-off. A mesh whose model axis is ONE wide
+    (a slot-data-parallel-only plane), or ``model_axis=None`` (weights
+    replicated over every axis: the speculative DRAFT's step on any
+    plane), skips that ``shard_map``: the step is the bare jit, which
+    XLA partitions by rows, bitwise identical to the unsharded step
+    (pinned by tests/test_serving_sharded.py); the mesh only tells the
+    pooled attention which rows a device holds (:func:`_token_view`,
+    ``rows_over``). Every pooled decode step of a plane takes the
+    plane's mesh: without it the step does not lower for a TPU mesh.
 
     ``kv_quant=True`` stores the per-layer K/V caches as INT8 with one
     fp32 scale per (slot, head) (carry keys ``k{i}_scale``/
@@ -1596,6 +1616,13 @@ def make_batch_decode_step(model: Sequential, compute_dtype=None,
     import jax
     import jax.numpy as jnp
 
+    # no model axis, or one that is one wide: the mesh shards the slot
+    # rows alone. The program stays a plain jit that XLA partitions,
+    # and only the pooled attention is told which rows a device holds
+    rows_over = None
+    if mesh is not None and (model_axis is None
+                             or int(mesh.shape[model_axis]) == 1):
+        rows_over, mesh = (mesh, data_axis), None
     m = _serving_meta(model, compute_dtype, mesh, model_axis)
     init_carry = _serving_init_carry(m.n_layers, m.max_len, m.heads, m.hd,
                                      m.cache_dtype, kv_quant, sampling,
@@ -1609,7 +1636,8 @@ def make_batch_decode_step(model: Sequential, compute_dtype=None,
                                    model_axis)
         new_carry = dict(carry)
         pos_rows, attend = _token_view(new_carry, active, m.max_len,
-                                       m.scale, m.cache_dtype, kv_quant)
+                                       m.scale, m.cache_dtype, kv_quant,
+                                       rows_over)
         x = _embed(lookup_w, pos_w, tokens, pos_rows)     # (N, Hid)
         for i, (blk, bp) in enumerate(blocks):
             x = _block(blk, bp, i, x, proj, row_proj, attend)
@@ -1935,7 +1963,7 @@ def get_prefill_step(model: Sequential, compute_dtype=None,
 def get_batch_decode_step(model: Sequential, compute_dtype=None,
                           sampling: bool = False, mesh=None,
                           data_axis: str = "data",
-                          model_axis: str = "model",
+                          model_axis: Optional[str] = "model",
                           kv_quant: bool = False, adapter=None):
     """Cached :func:`make_batch_decode_step` (the serving engine's step).
     ``sampling=True`` selects the sampled-epilogue variant (its own

@@ -1,25 +1,30 @@
 """Pooled decode attention as a Pallas TPU kernel (+ jnp reference).
 
 The serving engine's decode step is memory-bandwidth-bound: every token
-re-reads the whole pooled KV cache, stored ``(n_slots, max_len,
-heads*head_dim)`` (head-major lanes), to score ONE query per row. This
-module owns that inner loop:
+reads the pooled KV cache, stored ``(n_slots, max_len,
+kv_heads*head_dim)`` (head-major lanes), to score ONE query per row.
+This module owns that inner loop:
 
 * :func:`decode_attention_reference` — the plain jnp spelling: masked
   single-query attention over each row's own cache prefix
   ``0..pos[r]``, fp32 score/softmax accumulation, per-head einsums over
-  the ``(N, L, H, D)`` view;
+  the ``(N, L, G, D)`` view (``G <= H`` K/V heads: grouped queries);
 * :func:`folded_decode_attention` — the same sum computed against the
-  STORED ``(N, L, H*D)`` array (block-diagonal query, no 4-D view), so
-  the program that holds it never re-lays the pool out: the float
-  decode steps' path;
-* :func:`pooled_decode_attention` — the Pallas kernel (grid
-  ``(n_rows, kv_blocks)``, online softmax in VMEM scratch, one
-  ``(block_l, heads*head_dim)`` K/V tile resident per step) with the
-  same ``interpret``-mode pattern off-TPU as ``ops.flash_attention``
-  (the dispatch probe is shared: ``utils.compat.auto_interpret``). On
-  a TPU it compiles or raises; it never drops to the interpreter or
-  the reference.
+  STORED ``(N, L, G*D)`` array (block-diagonal query, no 4-D view), so
+  the program that holds it never re-lays the pool out: the whole
+  window of every row is scored, then masked. The lockstep decode step
+  runs it, and the pooled ones off the TPU;
+* :func:`pooled_decode_attention` — the Pallas kernel, which fetches
+  and scores only the blocks a DECODING row holds: one flat grid
+  compacted over those blocks through scalar prefetch
+  (:func:`_decode_schedule`), online softmax in VMEM scratch, one
+  ``(block_l, G*D)`` K/V tile resident per step; the same
+  ``interpret``-mode pattern off-TPU as ``ops.flash_attention`` (the
+  dispatch probe is shared: ``utils.compat.auto_interpret``). On a TPU
+  it compiles or raises; it never drops to the interpreter or the
+  reference;
+* :func:`decode_attention` — the pooled decode programs' dispatch
+  between them.
 
 Quantized KV (the int8 serving path — see docs/serving.md "Quantized KV
 cache"): K/V arrive as int8 with ONE fp32 scale per (row, head)
@@ -57,22 +62,30 @@ def _auto_interpret() -> bool:
     return auto_interpret()
 
 
-def _check_qkv(q, k, v, k_scale, v_scale):
-    """K/V come as the stored ``(N, L, H*D)`` array or its
-    ``(N, L, H, D)`` view — the same bytes, head-major lanes."""
+def _check_qkv(q, k, v, k_scale, v_scale) -> int:
+    """K/V come as the stored ``(N, L, G*D)`` array or its
+    ``(N, L, G, D)`` view — the same bytes, head-major lanes — with
+    ``G`` K/V heads, each read by ``H / G`` query heads. Returns ``G``."""
     if q.ndim != 3 or k.ndim not in (3, 4) or v.ndim != k.ndim:
         raise ValueError(
-            f"expected q (N, H, D) and k/v (N, L, H*D) or (N, L, H, D), "
+            f"expected q (N, H, D) and k/v (N, L, G*D) or (N, L, G, D), "
             f"got {q.shape} / {k.shape} / {v.shape}")
     n, h, d = q.shape
-    tail = (h, d) if k.ndim == 4 else (h * d,)
-    if k.shape != v.shape or k.shape[0] != n or k.shape[2:] != tail:
+    g = k.shape[2] if k.ndim == 4 else k.shape[2] // d
+    tail = (g, d) if k.ndim == 4 else (g * d,)
+    if k.shape != v.shape or k.shape[0] != n or k.shape[2:] != tail \
+            or g == 0 or h % g:
         raise ValueError(
-            f"k/v {k.shape}/{v.shape} do not match q {q.shape}")
+            f"k/v {k.shape}/{v.shape} do not match q {q.shape}: no whole "
+            f"number of K/V heads of {d} that divides its {h} heads")
     if (k_scale is None) != (v_scale is None):
         raise ValueError(
             "quantized KV needs BOTH k_scale and v_scale (or neither)")
     if k_scale is not None:
+        if g != h:
+            raise ValueError(
+                f"quantized K/V of {g} heads under {h} query heads: the "
+                "int8 cache is not read through grouped queries")
         if k_scale.shape != (n, h) or v_scale.shape != (n, h):
             raise ValueError(
                 f"per-(row, head) scales must be ({n}, {h}), got "
@@ -80,6 +93,13 @@ def _check_qkv(q, k, v, k_scale, v_scale):
         if k.dtype != jnp.int8 or v.dtype != jnp.int8:
             raise ValueError(
                 f"scaled K/V must be int8, got {k.dtype}/{v.dtype}")
+    return g
+
+
+def _own_heads(h: int, g: int):
+    """``own[j, c]``: query head ``j`` of ``h`` reads K/V head ``c`` of
+    ``g`` (head ``j // (h // g)``)."""
+    return jnp.arange(h)[:, None] // (h // g) == jnp.arange(g)[None, :]
 
 
 # --------------------------------------------------------------- reference
@@ -92,9 +112,10 @@ def decode_attention_reference(q, k, v, pos, k_scale=None, v_scale=None,
     contract the kernel is tested against AND the CPU serving path.
 
     ``q``: (N, H, D) one query per pooled row; ``k``/``v``:
-    (N, L, H*D) per-row caches as stored, or their (N, L, H, D) view
-    (float, or int8 with (N, H) fp32 ``k_scale``/``v_scale``);
-    ``pos``: (N,) int32 — row ``r`` attends
+    (N, L, G*D) per-row caches as stored, or their (N, L, G, D) view
+    (float, or int8 with (N, H) fp32 ``k_scale``/``v_scale``); with
+    ``G < H`` K/V heads, query head ``j`` reads K/V head
+    ``j // (H // G)``; ``pos``: (N,) int32 — row ``r`` attends
     over its own cache columns ``0..pos[r]`` INCLUSIVE (the decode
     step's ``wpos``, where the new K/V was just written). Scores and
     softmax accumulate fp32 regardless of input dtype; the int8 path
@@ -102,11 +123,13 @@ def decode_attention_reference(q, k, v, pos, k_scale=None, v_scale=None,
     f32) and applies the per-(row, head) scales as factored-out scalar
     multiplies — exactly the kernel's fused-dequant math. Returns
     (N, H, D) in ``out_dtype`` (default: q's dtype)."""
-    _check_qkv(q, k, v, k_scale, v_scale)
+    g = _check_qkv(q, k, v, k_scale, v_scale)
     n, h, d = q.shape
     L = k.shape[1]
-    k = k.reshape(n, L, h, d)
-    v = v.reshape(n, L, h, d)
+    k = k.reshape(n, L, g, d)
+    v = v.reshape(n, L, g, d)
+    if g != h:
+        k, v = (jnp.repeat(x, h // g, axis=2) for x in (k, v))
     if scale is None:
         scale = d ** -0.5
     if out_dtype is None:
@@ -161,16 +184,9 @@ def folded_decode_attention(q, k, v, pos, scale: Optional[float] = None,
     n, h, d = q.shape
     if k.ndim != 3:
         raise ValueError(
-            f"the folded form reads the stored (N, L, H*D) cache, got "
+            f"the folded form reads the stored (N, L, G*D) cache, got "
             f"{k.shape}")
-    g = k.shape[-1] // d
-    if g == h:
-        _check_qkv(q, k, v, None, None)
-    elif k.shape != v.shape or k.shape[0] != n or k.shape[-1] != g * d \
-            or g == 0 or h % g:
-        raise ValueError(
-            f"k/v {k.shape}/{v.shape} hold no whole number of K/V heads "
-            f"that divides q's {h} heads of {d}")
+    g = _check_qkv(q, k, v, None, None)
     L = k.shape[1]
     if scale is None:
         scale = d ** -0.5
@@ -181,9 +197,7 @@ def folded_decode_attention(q, k, v, pos, scale: Optional[float] = None,
         q_bd = (qs[:, :, :, None] * jnp.eye(h, dtype=k.dtype)[:, None, :]
                 ).reshape(n, h * d, h)
     else:
-        # own[j, c]: query head j reads K/V head c
-        own = (jnp.arange(h)[:, None] // (h // g)
-               == jnp.arange(g)[None, :])
+        own = _own_heads(h, g)
         q_bd = (qs[:, :, None, :] * own.astype(k.dtype)[None, :, :, None]
                 ).transpose(0, 2, 3, 1).reshape(n, g * d, h)
     s = jnp.einsum("nlc,nch->nhl", k, q_bd,
@@ -205,72 +219,113 @@ def folded_decode_attention(q, k, v, pos, scale: Optional[float] = None,
 # ------------------------------------------------------------------ kernel
 
 
-def _decode_kernel(*refs, scale, quantized, skip, heads, head_dim):
-    """Grid (N, n_l) — one pooled row per outer step, the KV-position
-    axis INNER, so one ``(block_l, H*D)`` K tile and one V tile are
-    VMEM-resident per step and the online-softmax state carries across
-    the position blocks in scratch (the flash-forward recipe).
+def fetched_blocks(pos, active, length: int, block: int):
+    """K/V blocks of ``block`` positions the kernel fetches for each row
+    of one cache leaf ``length`` long: the blocks that hold columns
+    ``0..min(pos, length - 1)`` of a row that decodes, none of a row
+    that does not. ``pos``: (N,) inclusive last column; ``active``:
+    (N,) bool. Plain arithmetic on numpy or jax arrays alike:
+    :func:`pooled_decode_attention` lays its grid out from it, and the
+    serving engine's ``serving/kv_fetched_bytes`` counts the same blocks
+    from host state (``KVPool.kv_fetched_bytes``)."""
+    held = (pos + 1).clip(0, length)
+    return (held + (block - 1)) // block * active
+
+
+#: what a grid step does, by bit of its ``flag``
+_FIRST, _RUN, _LAST = 1, 2, 4
+
+
+def _decode_schedule(pos, active, length: int, block: int):
+    """The kernel's grid, compacted over the blocks the rows hold: step
+    ``t`` works on block ``blk[t]`` of row ``row[t]``, the rows that
+    decode in order and each one's :func:`fetched_blocks` in order, so
+    no step addresses a block past a row's ``pos`` or any block of a
+    row that does not decode. ``flag[t]``: ``_FIRST`` on a row's first
+    block (reset the running softmax), ``_RUN`` (score the block),
+    ``_LAST`` on its last (write the row out). Steps from ``total`` on
+    (a static grid has ``N * length / block`` of them) re-address the
+    last one with no flag: an unchanged block index, so no DMA. With no
+    row decoding, step 0 resets and writes the last row, as zeros.
+    Returns ``(row, blk, flag, total)``."""
+    n = pos.shape[0]
+    nb = fetched_blocks(pos, active, length, block).astype(jnp.int32)
+    ends = jnp.cumsum(nb)
+    total = ends[-1]
+    steps = jnp.arange(n * (length // block), dtype=jnp.int32)
+    t = jnp.minimum(steps, jnp.maximum(total - 1, 0))
+    done = t[:, None] >= ends[None, :]        # rows wholly before step t
+    row = jnp.minimum(jnp.sum(done, axis=1, dtype=jnp.int32), n - 1)
+    blk = t - jnp.sum(jnp.where(done, nb[None, :], 0), axis=1)
+    last = jnp.any(t[:, None] + 1 == ends[None, :], axis=1)
+    flag = jnp.where(steps < total,
+                     _RUN + _FIRST * (blk == 0) + _LAST * last, 0)
+    flag = jnp.where((total == 0) & (steps == 0), _FIRST + _LAST, flag)
+    return row, blk, flag.astype(jnp.int32), total
+
+
+def _decode_kernel(*refs, scale):
+    """One grid step of :func:`_decode_schedule`: one ``(block_l, C)``
+    K tile and one V tile of one row are VMEM-resident (``C = G*D``,
+    the K/V heads folded into the lanes), and the online-softmax state
+    of the row carries across its blocks in scratch (the flash-forward
+    recipe).
 
     Every head of a row is computed from the SAME lane-dense tile: the
-    row's query is spread into a block-diagonal ``(H, H*D)`` matrix
-    (row ``h`` holds ``q[h]`` in lanes ``h*D..(h+1)*D`` and zeros
-    elsewhere), so ``q_bd . k_tile^T`` IS the per-head score matrix
-    ``(H, block_l)`` and the diagonal ``D``-wide blocks of
-    ``p . v_tile`` are the per-head contexts — two plain 2-D MXU
-    matmuls, no in-kernel reshape or per-head strided load. Mosaic
-    wants blocks whose last two dims are tile-aligned or whole;
-    ``(block_l, H*D)`` is, where the per-head ``(block_l, 1, D)`` tile
-    it replaces was refused by the compiler. The off-diagonal products
-    are redundant MXU work; whether the MXU or the HBM read bounds the
-    step has not been measured (ROADMAP S2).
+    wrapper spreads the row's query into a block-diagonal ``(H, C)``
+    matrix (row ``j`` holds ``q[j]`` in the lanes of its K/V head and
+    zeros elsewhere), so ``q_bd . k_tile^T`` IS the per-head score
+    matrix ``(H, block_l)`` and row ``j``'s own ``D``-wide block of
+    ``p . v_tile`` is head ``j``'s context — two plain 2-D MXU matmuls,
+    no in-kernel reshape or per-head strided load. Mosaic wants blocks
+    whose last two dims are tile-aligned or whole; ``(block_l, C)`` is,
+    where the per-head ``(block_l, 1, D)`` tile it replaces was refused
+    by the compiler. The off-diagonal products cost the MXU nothing
+    while ``H`` fits one pass of its columns.
 
-    ``pos`` and each row's last needed block index arrive by scalar
-    prefetch (SMEM): the first gates the block skip here, the second
-    clamps the K/V index maps, so blocks past a row's ``pos`` are
-    neither computed nor fetched.
+    ``pos`` and the schedule arrive by scalar prefetch (SMEM): the
+    index maps read the step's row and block from them, so only held
+    blocks are ever fetched.
 
     Quantized layout: int8 K/V tiles are loaded RAW; the (row, head)
     scales enter as ``(H, 1)`` column factors — k_scale folds into the
     score scaling, v_scale multiplies the accumulated context once at
     the end (exact: both are constant over the contracted axes)."""
+    (pos_ref, row_ref, blk_ref, flag_ref, q_ref, k_ref, v_ref, *scale_refs,
+     _, o_ref, m_scr, l_scr, acc_scr) = refs
+    quantized = bool(scale_refs)
     if quantized:
-        (pos_ref, _, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
-         m_scr, l_scr, acc_scr) = refs
-    else:
-        (pos_ref, _, q_ref, k_ref, v_ref, o_ref,
-         m_scr, l_scr, acc_scr) = refs
-    j = pl.program_id(1)
-    n_l = pl.num_programs(1)
+        ks_ref, vs_ref = scale_refs
+    t = pl.program_id(0)
+    flag = flag_ref[t]
     bl = k_ref.shape[1]
-    hd = heads * head_dim
-    pos = pos_ref[pl.program_id(0)]
+    # a bf16 product has one precision, and Mosaic refuses a process-
+    # wide jax_default_matmul_precision that asks it for more
+    precision = jax.lax.Precision.DEFAULT \
+        if k_ref.dtype == jnp.bfloat16 else None
 
-    def _head_diag():
-        # (H, H*D) mask of each head's own D-wide lane block
-        lo = jax.lax.broadcasted_iota(jnp.int32, (heads, hd), 0) * head_dim
-        col = jax.lax.broadcasted_iota(jnp.int32, (heads, hd), 1)
-        return jnp.logical_and(col >= lo, col < lo + head_dim)
-
-    @pl.when(j == 0)
+    @pl.when(flag & _FIRST != 0)
     def _init():
         m_scr[...] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
+    @pl.when(flag & _RUN != 0)
     def _step():
-        k = k_ref[0]                                    # (BL, H*D)
+        k = k_ref[0]                                    # (BL, C)
         v = v_ref[0]
-        q_bd = jnp.where(_head_diag(), q_ref[0].astype(jnp.float32), 0.0)
+        q_bd = q_ref[0]                                 # (H, C)
         if quantized:
             s = jax.lax.dot_general(
                 q_bd, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * (scale * ks_ref[0])
         else:
             s = jax.lax.dot_general(
-                q_bd.astype(k.dtype), k, (((1,), (1,)), ((), ())),
+                q_bd, k, (((1,), (1,)), ((), ())), precision=precision,
                 preferred_element_type=jnp.float32) * scale
-        cols = j * bl + jax.lax.broadcasted_iota(jnp.int32, (1, bl), 1)
-        s = jnp.where(cols <= pos, s, _NEG_INF)         # (H, BL)
+        cols = blk_ref[t] * bl + jax.lax.broadcasted_iota(
+            jnp.int32, (1, bl), 1)
+        s = jnp.where(cols <= pos_ref[row_ref[t]], s, _NEG_INF)  # (H, BL)
         m = m_scr[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)                          # (H, BL) f32
@@ -282,30 +337,16 @@ def _decode_kernel(*refs, scale, quantized, skip, heads, head_dim):
             pv = jnp.dot(p, v.astype(jnp.float32),
                          preferred_element_type=jnp.float32)
         else:
-            pv = jnp.dot(p.astype(v.dtype), v,
+            pv = jnp.dot(p.astype(v.dtype), v, precision=precision,
                          preferred_element_type=jnp.float32)
-        acc_scr[...] = acc_scr[...] * alpha + pv        # (H, H*D)
+        acc_scr[...] = acc_scr[...] * alpha + pv        # (H, C)
 
-    if skip:
-        # compiled path: key blocks entirely past the row's pos
-        # contribute nothing — skip their gemms (most of the grid when
-        # the pool is young). Interpret mode runs unconditionally: a
-        # traced pl.when predicate is rejected there under shard_map
-        # (same constraint the flash kernel documents).
-        pl.when(j * bl <= pos)(_step)
-    else:
-        _step()
-
-    @pl.when(j == n_l - 1)
+    @pl.when(flag & _LAST != 0)
     def _finish():
         l_safe = jnp.maximum(l_scr[...], 1e-30)
         out = acc_scr[...] / l_safe
         if quantized:
             out = out * vs_ref[0]
-        # row h's own lane block is head h's context; the rest of the
-        # row is the redundant cross-head product
-        out = jnp.sum(jnp.where(_head_diag(), out, 0.0), axis=0,
-                      keepdims=True)
         o_ref[0] = out.astype(o_ref.dtype)
 
 
@@ -315,10 +356,10 @@ def _decode_kernel(*refs, scale, quantized, skip, heads, head_dim):
 _KV_TILE_BUDGET = 4 * 1024 * 1024
 
 
-def _auto_block_l(L: int, row_bytes: int) -> int:
+def auto_block_l(L: int, row_bytes: int) -> int:
     """KV-position tile length: the LARGEST of 512/384/256/128 that
     divides the 128-padded cache window and keeps the four resident
-    ``(block, H*D)`` K/V tiles inside ``_KV_TILE_BUDGET`` (bigger tiles
+    ``(block, G*D)`` K/V tiles inside ``_KV_TILE_BUDGET`` (bigger tiles
     amortize grid-step overhead on the short-query decode grid).
     Divisibility is the load-bearing part: a non-dividing block forces
     :func:`pooled_decode_attention` to ``jnp.pad`` the K/V operands,
@@ -338,102 +379,130 @@ def pooled_decode_attention(q, k, v, pos, k_scale=None, v_scale=None,
                             scale: Optional[float] = None,
                             block: Optional[int] = None,
                             interpret: Optional[bool] = None,
-                            out_dtype=None):
+                            out_dtype=None, active=None):
     """Pallas pooled decode attention over slot-indexed KV.
 
     Same contract as :func:`decode_attention_reference` (q ``(N, H, D)``,
-    k/v ``(N, L, H*D)`` as stored or their ``(N, L, H, D)`` view, float
+    k/v ``(N, L, G*D)`` as stored or their ``(N, L, G, D)`` view, float
     or int8-with-``(N, H)``-scales, per-row inclusive ``pos``), computed
-    by the tiled online-softmax kernel.
+    by the tiled online-softmax kernel, which fetches and scores only
+    the blocks a row holds (:func:`_decode_schedule`). ``active``
+    (N,) bool, default all: a row that does not decode costs no fetch
+    and no grid step, and its output row is zeros. Compiled, it is a
+    Mosaic kernel, which XLA does not partition by itself: a program
+    that shards the rows over a mesh calls it under a ``shard_map`` by
+    rows (``models/transformer.py:_token_view``).
     ``block`` is the KV-position tile length (None = auto);
     ``interpret=None`` auto-selects Pallas interpreter mode off-TPU via
     the shared ``utils.compat.auto_interpret`` probe. The cache window
     is right-padded to a block multiple when needed — padded columns
     sit beyond every row's ``pos`` and are masked like any other
     out-of-window position. The kernel reads the cache as
-    ``(N, L, H*D)`` (heads folded into the lane axis — see
+    ``(N, L, G*D)`` (heads folded into the lane axis — see
     :func:`_decode_kernel`), which is how the pool stores it."""
     from jax.experimental.pallas import tpu as pltpu
 
     from bigdl_tpu.utils.compat import pallas_tpu_compiler_params
 
-    _check_qkv(q, k, v, k_scale, v_scale)
+    g = _check_qkv(q, k, v, k_scale, v_scale)
     n, h, d = q.shape
-    L = k.shape[1]
-    if scale is None:
-        scale = d ** -0.5
-    if out_dtype is None:
-        out_dtype = q.dtype
+    L, c = k.shape[1], g * d
     if interpret is None:
         interpret = _auto_interpret()
     if block is None:
-        block = _auto_block_l(L, h * d * k.dtype.itemsize)
-    quantized = k_scale is not None
-    k = k.reshape(n, L, h * d)
-    v = v.reshape(n, L, h * d)
+        block = auto_block_l(L, c * k.dtype.itemsize)
+    scale = float(d ** -0.5 if scale is None else scale)
+    if out_dtype is None:
+        out_dtype = q.dtype
+    k, v = k.reshape(n, L, c), v.reshape(n, L, c)
+    pos = jnp.asarray(pos, jnp.int32).reshape(n)
+    if active is None:
+        active = jnp.ones((n,), bool)
+    scales = () if k_scale is None else (k_scale, v_scale)
     pad = (-L) % block
     if pad:
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0)))
-    pos1 = jnp.asarray(pos, jnp.int32).reshape(n)
-    last_blk = pos1 // block
+    row, blk, flag, total = _decode_schedule(pos, active, L + pad, block)
+    # own[j, c]: query head j reads K/V head c; row j of the
+    # block-diagonal query holds q[j] in that head's lanes
+    own = _own_heads(h, g)
+    q_bd = jnp.where(
+        own[None, :, :, None],
+        q.astype(jnp.float32 if scales else k.dtype)[:, :, None, :],
+        0).reshape(n, h, c)
+
+    def at_row(t, pos_, row_, blk_, flag_):
+        return (row_[t], 0, 0)
+
     # every block's last two dims are whole array dims or (block_l:
-    # a 128-multiple, H*D: whole) — what the Mosaic lowering accepts
-    qblk = pl.BlockSpec((1, 1, h * d), lambda n_, j, pos_, last_: (n_, 0, 0))
-    # blocks past the row's pos re-address the last needed one: the
-    # pipeline sees an unchanged block index and issues no DMA
+    # a 128-multiple, C: whole) — what the Mosaic lowering accepts
+    qblk = pl.BlockSpec((1, h, c), at_row)
     kblk = pl.BlockSpec(
-        (1, block, h * d),
-        lambda n_, j, pos_, last_: (n_, jnp.minimum(j, last_[n_]), 0))
-    sblk = pl.BlockSpec((1, h, 1), lambda n_, j, pos_, last_: (n_, 0, 0))
-    operands = [q.reshape(n, 1, h * d), k, v]
-    in_specs = [qblk, kblk, kblk]
-    if quantized:
-        operands += [k_scale.astype(jnp.float32).reshape(n, h, 1),
-                     v_scale.astype(jnp.float32).reshape(n, h, 1)]
-        in_specs += [sblk, sblk]
+        (1, block, c), lambda t, pos_, row_, blk_, flag_: (row_[t], blk_[t], 0))
+    sblk = pl.BlockSpec((1, h, 1), at_row)
+    operands = [q_bd, k, v] + [
+        s.astype(jnp.float32).reshape(n, h, 1) for s in scales]
+    in_specs = [qblk, kblk, kblk] + [sblk] * len(scales)
+    # the output starts as zeros and aliases them: the rows no step
+    # writes (those that do not decode) stay zeros
+    operands.append(jnp.zeros((n, h, c), out_dtype))
+    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+    n_prefetch = 4
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=float(scale),
-                          quantized=quantized, skip=not interpret,
-                          heads=h, head_dim=d),
+        functools.partial(_decode_kernel, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(n, (L + pad) // block),
+            num_scalar_prefetch=n_prefetch,
+            # compiled, the grid is as long as the schedule; the
+            # interpreter takes no dynamic bound and runs the flagless
+            # tail
+            grid=(row.shape[0] if interpret else jnp.maximum(total, 1),),
             in_specs=in_specs,
             out_specs=qblk,
             scratch_shapes=[
                 pltpu.VMEM((h, 1), jnp.float32),
                 pltpu.VMEM((h, 1), jnp.float32),
-                pltpu.VMEM((h, h * d), jnp.float32),
+                pltpu.VMEM((h, c), jnp.float32),
             ]),
-        out_shape=_out_struct((n, 1, h * d), out_dtype, pos1, *operands),
+        out_shape=_out_struct((n, h, c), out_dtype, pos, *operands),
+        input_output_aliases={n_prefetch + len(operands) - 1: 0},
         compiler_params=None if interpret else pallas_tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="pooled_decode_attention",
-    )(pos1, last_blk, *operands)
-    return out.reshape(n, h, d)
+    )(pos, row, blk, flag, *operands)
+    # head j's context is its own K/V head's D-wide block of row j
+    return jnp.sum(jnp.where(own[None, :, :, None],
+                             out.reshape(n, h, g, d), 0), axis=2)
 
 
 def decode_attention(q, k, v, pos, k_scale=None, v_scale=None,
                      scale: Optional[float] = None,
                      block: Optional[int] = None,
                      interpret: Optional[bool] = None,
-                     impl: str = "auto", out_dtype=None):
-    """The serving steps' dispatch point: ``impl="auto"`` runs the
-    compiled Pallas kernel on TPU and the jnp reference elsewhere
-    (interpret-mode Pallas is an emulator — correct but far too slow
-    for the CPU CI serving loop); ``"kernel"``/``"reference"`` force a
-    path (tests pin kernel-vs-reference numerics with
-    ``impl="kernel", interpret=True``)."""
+                     impl: str = "auto", out_dtype=None, active=None):
+    """The pooled decode steps' dispatch point: ``impl="auto"`` runs
+    the compiled Pallas kernel on a TPU. Elsewhere (interpret-mode
+    Pallas is an emulator — correct but far too slow for the CPU CI
+    serving loop) it runs the whole-window jnp sums, which compute the
+    rows that do not decode too: :func:`folded_decode_attention` over a
+    stored float cache, :func:`decode_attention_reference` over an int8
+    one or a 4-D view. ``"kernel"``/``"reference"`` force a path
+    (tests pin kernel-vs-reference numerics with ``impl="kernel",
+    interpret=True``). ``active`` (N,) bool: the rows that decode; the
+    output rows of the others are ballast."""
     if impl not in ("auto", "kernel", "reference"):
         raise ValueError(f"unknown impl {impl!r}")
-    if impl == "auto":
-        impl = "reference" if _auto_interpret() else "kernel"
-    if impl == "reference":
-        return decode_attention_reference(
+    if impl == "auto" and not _auto_interpret():
+        impl = "kernel"
+    if impl == "kernel":
+        return pooled_decode_attention(
             q, k, v, pos, k_scale=k_scale, v_scale=v_scale, scale=scale,
-            out_dtype=out_dtype)
-    return pooled_decode_attention(
+            block=block, interpret=interpret, out_dtype=out_dtype,
+            active=active)
+    if impl == "auto" and k_scale is None and k.ndim == 3:
+        return folded_decode_attention(q, k, v, pos, scale=scale,
+                                       out_dtype=out_dtype)
+    return decode_attention_reference(
         q, k, v, pos, k_scale=k_scale, v_scale=v_scale, scale=scale,
-        block=block, interpret=interpret, out_dtype=out_dtype)
+        out_dtype=out_dtype)

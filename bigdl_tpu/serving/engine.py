@@ -447,9 +447,11 @@ class ServingEngine:
         tp = self._plane is not None and self._plane.tensor_parallel
         if speculative is None:
             self._spec = None
+            # the decode step takes the mesh of a data-only plane
+            # too: its attention kernel has to know the rows' axis
             self._step_fn, pool_init = family.decode_step(
-                compute_dtype, mesh=self.mesh if tp else None,
-                kv_quant=kv_quant, adapter=self._adapter_spec)
+                compute_dtype, mesh=self.mesh, kv_quant=kv_quant,
+                adapter=self._adapter_spec)
         else:
             from bigdl_tpu.serving.speculative import Speculator
 
@@ -1127,6 +1129,18 @@ class ServingEngine:
             + sum(held(int(self.pool.chunk_done[slot]))
                   for slot in sched.partial)
 
+    def _kv_fetched_bytes(self) -> int:
+        """Bytes of K/V the decode program's attention fetches for the
+        running rows (a mid-prefill row does not decode: none), from
+        host state alone: a row feeds its last token at position
+        ``prompt + output - 1`` and attends up to it. (Sampled by the
+        plain decode step only: a speculative engine's super-step,
+        whose verify program reads the whole window, takes its own
+        step sample without it.)"""
+        return self.pool.kv_fetched_bytes(
+            [len(r.prompt) + len(r.output) - 1
+             for r in self.scheduler.running.values()])
+
     def _state_in_use(self) -> Optional[int]:
         """Bytes of per-slot ``state`` leaves the in-use slots hold
         (each holds all of its own, whatever its position), from host
@@ -1720,7 +1734,8 @@ class ServingEngine:
                     self.scheduler.queue_depth, self.pool.occupancy(),
                     len(rows), kv_used_share=self._kv_used_share(),
                     state_in_use_bytes=self._state_in_use(),
-                    kv_held_bytes=self._kv_held_bytes())
+                    kv_held_bytes=self._kv_held_bytes(),
+                    kv_fetched_bytes=self._kv_fetched_bytes())
                 if extra:
                     self.metrics.on_expert_counts(extra[0])
                 self.metrics.on_sample_rows(n_sampled,

@@ -41,7 +41,7 @@ the slot as its first axis, and the pool treats each by WHAT IT IS
 from __future__ import annotations
 
 import re
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 from bigdl_tpu.serving.metrics import span
 
@@ -114,6 +114,8 @@ class KVPool:
         import jax
         import numpy as np
 
+        from bigdl_tpu.ops.decode_attention import auto_block_l
+
         if n_slots <= 0:
             raise ValueError(f"n_slots must be positive, got {n_slots}")
         self.n_slots = int(n_slots)
@@ -155,15 +157,18 @@ class KVPool:
                 for k, v in self.carry.items() if leaf_kind(k) in kinds))
 
         self.kv_bytes_per_slot = slot_bytes("kv", "scale")
-        # leaf length -> bytes a position, summed over the K/V leaves of
-        # that length: what a row at ``pos`` really holds of its slot
-        # (kv_held_bytes; one entry where every leaf is ``max_len``)
-        self._kv_position_bytes: Dict[int, int] = {}
+        # (leaf length, the decode kernel's block over it) -> bytes a
+        # position, summed over the K/V leaves of that length: what a
+        # row at ``pos`` really holds of its slot (kv_held_bytes) and
+        # what a decoding row has fetched of it (kv_fetched_bytes); one
+        # entry where every leaf is ``max_len``
+        self._kv_position_bytes: Dict[Tuple[int, int], int] = {}
         for k, v in self.carry.items():
             if leaf_kind(k) == "kv":
-                self._kv_position_bytes[int(v.shape[1])] = \
-                    self._kv_position_bytes.get(int(v.shape[1]), 0) \
-                    + v.dtype.itemsize * int(np.prod(v.shape[2:]))
+                nbytes = v.dtype.itemsize * int(np.prod(v.shape[2:]))
+                key = (int(v.shape[1]), auto_block_l(int(v.shape[1]), nbytes))
+                self._kv_position_bytes[key] = \
+                    self._kv_position_bytes.get(key, 0) + nbytes
         self.state_bytes_per_slot = slot_bytes("state")
         # LIFO free list: the most recently freed row is the most likely
         # to still be resident in cache/HBM
@@ -321,7 +326,25 @@ class KVPool:
         ``min(pos, len_i)`` positions (a ring never holds more than its
         window)."""
         return sum(min(int(pos), length) * nbytes
-                   for length, nbytes in self._kv_position_bytes.items())
+                   for (length, _), nbytes in self._kv_position_bytes.items())
+
+    def kv_fetched_bytes(self, pos) -> int:
+        """Bytes of K/V the decode program's attention fetches for rows
+        decoding at the (inclusive) positions ``pos``: per leaf the
+        whole blocks that hold columns ``0..min(pos, len_i - 1)``, by
+        the function the kernel lays its grid out from
+        (``ops.decode_attention.fetched_blocks``). COMPUTED from shapes
+        and host state, not measured: it is what the kernel fetches on
+        a TPU at its automatic block (``auto_block_l``: no decode
+        program passes another), whatever runs elsewhere."""
+        import numpy as np
+
+        from bigdl_tpu.ops.decode_attention import fetched_blocks
+
+        pos = np.asarray(pos, np.int64)
+        return int(sum(
+            fetched_blocks(pos, True, length, block).sum() * block * nbytes
+            for (length, block), nbytes in self._kv_position_bytes.items()))
 
     def used_per_shard(self) -> List[int]:
         """Allocated-slot count per shard (one logical shard here; the

@@ -39,6 +39,13 @@ Counters (all under the ``serving/`` prefix in the backing Metrics):
   positions (a sliding-window layer's ring holds at most its window),
   host state, no readback; ``kv_used_share`` keeps its meaning (``pos``
   over ``n_slots x max_len``)
+* ``kv_fetched_bytes``  — sampled every engine step: bytes of K/V the
+  decode program's attention FETCHES for the running rows, per row and
+  K/V leaf the whole kernel blocks up to its position
+  (``KVPool.kv_fetched_bytes``). COMPUTED from shapes and host state,
+  not measured: what the Pallas kernel fetches on a TPU (off it the
+  whole window is read); not sampled by a speculative engine, whose
+  verify step reads the whole window
 * ``expert_pairs`` / ``experts_hit`` / ``expert_load_max`` — per decode
   step of a family with routed experts, from the per-expert token
   counts its program returns with the tokens (read back at the SAME
@@ -300,7 +307,8 @@ class ServingMetrics:
     def on_step(self, queue_depth: int, occupancy: float,
                 batch_active: int, kv_used_share: float,
                 state_in_use_bytes: Optional[int] = None,
-                kv_held_bytes: Optional[int] = None) -> None:
+                kv_held_bytes: Optional[int] = None,
+                kv_fetched_bytes: Optional[int] = None) -> None:
         # a declared CLOCK_SITES unit (serving/faults.py): the serve-
         # duration anchor timestamps (_t_start/_t_last span the whole
         # serve for summary()'s wall number) deliberately read the raw
@@ -320,6 +328,9 @@ class ServingMetrics:
                              float(state_in_use_bytes))
         if kv_held_bytes is not None:
             self.metrics.add("serving/kv_held_bytes", float(kv_held_bytes))
+        if kv_fetched_bytes is not None:
+            self.metrics.add("serving/kv_fetched_bytes",
+                             float(kv_fetched_bytes))
 
     def on_expert_counts(self, counts) -> None:
         """One decode step's ``(n_expert_layers, held)`` token counts,

@@ -22,7 +22,11 @@ is that step. Two composable axes over one
   token-identical, not merely close (pinned by
   tests/test_serving_sharded.py). XLA's SPMD partitioner does the
   splitting: no shard_map, no new program per occupancy, ONE compiled
-  step per engine regardless of mesh size.
+  step per engine regardless of mesh size. (One exception, on a TPU:
+  the decode step's Pallas attention kernel cannot be partitioned
+  automatically, so the step — the target's, and a speculative
+  draft's on every plane — takes the mesh and calls it under a
+  ``shard_map`` by rows: ``models/transformer.py:_token_view``.)
 * **tensor parallelism** (``model`` axis) — attention heads + MLP
   hidden shard Megatron-style through
   :mod:`bigdl_tpu.parallel.tensor_parallel`'s column/row-parallel

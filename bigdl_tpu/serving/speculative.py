@@ -161,11 +161,12 @@ class Speculator:
             engine.model, dtype, width=self.width, mesh=mesh,
             kv_quant=kv_quant, adapter=engine._adapter_spec)
         # draft plane: weights REPLICATED (a model small enough to
-        # draft with is small enough to replicate — on data-sharded
-        # meshes XLA partitions the per-row step over the carry's slot
-        # sharding), plain float cache, greedy proposals
+        # draft with is small enough to replicate — on a mesh XLA
+        # partitions the per-row step over the carry's slot sharding,
+        # so the step takes the plane's mesh with NO model axis, for
+        # its pooled attention), plain float cache, greedy proposals
         self._draft_step_fn, self._draft_init = get_batch_decode_step(
-            draft, dtype)
+            draft, dtype, mesh=engine.mesh, model_axis=None)
         self._draft_prefill_fn = get_batch_prefill_step(draft, dtype)
         self._draft_params = jax.device_put(serving_params(draft, dtype))
         # shared fresh B=1 carry for draft prefills (immutable, reused)

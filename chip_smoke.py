@@ -427,6 +427,16 @@ def phase_kernels(size, interpret):
     out["decode_folded"] = excess(
         jax.jit(folded_decode_attention)(q, stored(k), stored(v), pos), want,
         KERNEL_ATOL)
+    # grouped queries over a third of the K/V heads, every third row not
+    # decoding: those rows cost no fetch and come back as zeros
+    g, on = max(1, h // 3), np.arange(n) % 3 != 1
+    kg, vg = (x[:, :, :g].reshape(n, L, g * d) for x in (k, v))
+    got = np.asarray(kernel(q, kg, vg, pos, active=jnp.asarray(on)),
+                     np.float32)
+    out["decode_grouped"] = excess(
+        got[on], np.asarray(ref(q, kg, vg, pos), np.float32)[on],
+        KERNEL_ATOL)
+    assert not got[~on].any()
     # per-(row, head) symmetric int8: the serving carry's layout
     k32, v32 = k.astype(jnp.float32), v.astype(jnp.float32)
     ks = jnp.max(jnp.abs(k32), axis=(1, 3)) / 127.0

@@ -686,7 +686,7 @@ def test_the_expert_series_and_held_bytes_ride_the_step(lm):
     m = eng.metrics.metrics
     steps = len(m.values("serving/batch_active"))
     for name in ("expert_pairs", "experts_hit", "expert_load_max",
-                 "kv_held_bytes"):
+                 "kv_held_bytes", "kv_fetched_bytes"):
         assert len(m.values(f"serving/{name}")) == steps, name
     pairs = np.asarray(m.values("serving/expert_pairs"))
     active = np.asarray(m.values("serving/batch_active"))
@@ -700,6 +700,10 @@ def test_the_expert_series_and_held_bytes_ride_the_step(lm):
     held = np.asarray(m.values("serving/kv_held_bytes"))
     assert held.max() <= 2 * eng.pool.kv_held_bytes(40)
     assert held.max() > eng.pool.kv_held_bytes(30)
+    # the kernel's block is 128 positions here and a shorter leaf is
+    # padded up to one: a decoding row fetches one block a leaf
+    fetched = set(m.values("serving/kv_fetched_bytes"))
+    assert fetched <= {3 * 128 * row, 2 * 3 * 128 * row} and fetched
 
 
 REFUSED = {"prefix_cache": True, "speculative": object(),

@@ -67,3 +67,21 @@ def test_pooled_decode_attention_lowers_for_tpu(kv_dtype, kv_shape):
         _lower_for_tpu(
             lambda q, k, v, pos: pooled_decode_attention(
                 q, k, v, pos, interpret=False), q, kv, kv, pos)
+
+
+@pytest.mark.parametrize("n,length,h,g,d", [
+    (32, 1024, 16, 16, 64),       # gpt2m-serve-chat
+    (32, 1024, 20, 4, 128),       # falconh1-serve-reason
+    (16, 4096, 48, 8, 128),       # trinity-serve-mixed, a window's ring
+    (16, 8192, 48, 8, 128),       # ... and its full-attention layer
+], ids=["h16", "h20g4", "h48g8_ring", "h48g8_full"])
+def test_pooled_decode_attention_lowers_at_the_cells_shapes(n, length, h,
+                                                            g, d):
+    """Grouped queries over the stored bf16 cache with the rows that
+    decode passed in, at the shapes the three serving cells bring."""
+    kv = _sds((n, length, g * d), jnp.bfloat16)
+    _lower_for_tpu(
+        lambda q, k, v, pos, active: pooled_decode_attention(
+            q, k, v, pos, active=active, interpret=False),
+        _sds((n, h, d), jnp.bfloat16), kv, kv, _sds((n,), jnp.int32),
+        _sds((n,), bool))

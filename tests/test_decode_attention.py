@@ -12,8 +12,8 @@ import numpy as np
 import pytest
 
 from bigdl_tpu.ops.decode_attention import (
-    decode_attention, decode_attention_reference, folded_decode_attention,
-    pooled_decode_attention,
+    _decode_schedule, decode_attention, decode_attention_reference,
+    fetched_blocks, folded_decode_attention, pooled_decode_attention,
 )
 
 
@@ -183,6 +183,286 @@ def test_stored_shape_equals_its_view(quantized):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+# -- grouped queries, rows that do not decode, the compacted grid -----------
+
+GL, GBLOCK = 384, 128
+
+
+def _grouped(h, g, d, pos_value, dtype=jnp.float32, n=4, seed=0):
+    """A stored (n, GL, g*d) cache under h query heads; every row at
+    ``pos_value``; row 1 and the last row do not decode."""
+    rng = np.random.default_rng(seed)
+    q = jnp.asarray(rng.standard_normal((n, h, d)), dtype)
+    k = jnp.asarray(rng.standard_normal((n, GL, g * d)), dtype)
+    v = jnp.asarray(rng.standard_normal((n, GL, g * d)), dtype)
+    pos = jnp.full((n,), pos_value, jnp.int32)
+    active = np.ones((n,), bool)
+    active[[1, n - 1]] = False
+    return q, k, v, pos, active
+
+
+@pytest.mark.parametrize("pos_value", [0, 200, 255, 256, GL - 1],
+                         ids=["first", "mid_block", "block_end",
+                              "block_start", "whole_ring"])
+@pytest.mark.parametrize("h,g,d", [(16, 16, 64), (20, 4, 128),
+                                   (48, 8, 128)])
+def test_grouped_kernel_matches_reference(h, g, d, pos_value):
+    """The heads the serving cells bring (ungrouped, 5 and 6 query
+    heads a K/V head), a row's first column, the middle, the last and
+    the first column of a block and ``L - 1`` (what a wrapped ring
+    passes), with rows that decode beside rows that do not: those that
+    do read the reference's sum, the others come back as zeros."""
+    q, k, v, pos, active = _grouped(h, g, d, pos_value)
+    ref = decode_attention_reference(q, k, v, pos)
+    ker = pooled_decode_attention(q, k, v, pos, block=GBLOCK,
+                                  interpret=True,
+                                  active=jnp.asarray(active))
+    assert ker.shape == q.shape
+    np.testing.assert_allclose(np.asarray(ker)[active],
+                               np.asarray(ref)[active],
+                               atol=2e-5, rtol=2e-5)
+    assert not np.asarray(ker)[~active].any()
+
+
+@pytest.mark.parametrize("h,g,d", [(20, 4, 128), (6, 2, 16)])
+def test_grouped_reference_and_folded_agree_with_repeated_heads(h, g, d):
+    """A grouped cache reads as the ungrouped one whose K/V head ``c``
+    is repeated for its ``h / g`` query heads."""
+    q, k, v, pos, _ = _grouped(h, g, d, 200)
+    pos = pos.at[0].set(0).at[2].set(GL - 1)
+
+    def repeated(x):
+        return jnp.repeat(x.reshape(4, GL, g, d), h // g, axis=2)
+
+    want = decode_attention_reference(q, repeated(k), repeated(v), pos)
+    for fn in (decode_attention_reference, folded_decode_attention):
+        np.testing.assert_allclose(np.asarray(fn(q, k, v, pos)),
+                                   np.asarray(want), atol=2e-6, rtol=2e-6)
+
+
+def _pr32_kernel(q, k, v, pos, block, k_scale=None, v_scale=None):
+    """The ungrouped kernel as it stood before grouped queries and the
+    compacted grid (PR 32's ``_decode_kernel`` and wrapper, interpret
+    mode: grid ``(N, L / block)``, the query spread and the head pick
+    inside the kernel): the oracle for "bit for bit what it was"."""
+    import functools
+
+    import jax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, h, d = q.shape
+    L, hd = k.shape[1], h * d
+    quantized = k_scale is not None
+
+    def kernel(*refs):
+        if quantized:
+            (pos_ref, _, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
+             m_scr, l_scr, acc_scr) = refs
+        else:
+            (pos_ref, _, q_ref, k_ref, v_ref, o_ref,
+             m_scr, l_scr, acc_scr) = refs
+        j = pl.program_id(1)
+        row_pos = pos_ref[pl.program_id(0)]
+        lo = jax.lax.broadcasted_iota(jnp.int32, (h, hd), 0) * d
+        col = jax.lax.broadcasted_iota(jnp.int32, (h, hd), 1)
+        diag = jnp.logical_and(col >= lo, col < lo + d)
+
+        @pl.when(j == 0)
+        def _init():
+            m_scr[...] = jnp.full(m_scr.shape, -1e30, jnp.float32)
+            l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+            acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+        kt, vt = k_ref[0], v_ref[0]
+        q_bd = jnp.where(diag, q_ref[0].astype(jnp.float32), 0.0)
+        if quantized:
+            s = jax.lax.dot_general(
+                q_bd, kt.astype(jnp.float32), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * (
+                    d ** -0.5 * ks_ref[0])
+        else:
+            s = jax.lax.dot_general(
+                q_bd.astype(kt.dtype), kt, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * d ** -0.5
+        cols = j * block + jax.lax.broadcasted_iota(jnp.int32,
+                                                    (1, block), 1)
+        s = jnp.where(cols <= row_pos, s, -1e30)
+        m = m_scr[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        m_scr[...] = m_new
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jnp.dot(p, vt.astype(jnp.float32),
+                     preferred_element_type=jnp.float32) if quantized \
+            else jnp.dot(p.astype(vt.dtype), vt,
+                         preferred_element_type=jnp.float32)
+        acc_scr[...] = acc_scr[...] * alpha + pv
+
+        @pl.when(j == pl.num_programs(1) - 1)
+        def _finish():
+            out = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+            if quantized:
+                out = out * vs_ref[0]
+            o_ref[0] = jnp.sum(jnp.where(diag, out, 0.0), axis=0,
+                               keepdims=True).astype(o_ref.dtype)
+
+    qblk = pl.BlockSpec((1, 1, hd), lambda n_, j, pos_, last_: (n_, 0, 0))
+    kblk = pl.BlockSpec(
+        (1, block, hd),
+        lambda n_, j, pos_, last_: (n_, jnp.minimum(j, last_[n_]), 0))
+    sblk = pl.BlockSpec((1, h, 1), lambda n_, j, pos_, last_: (n_, 0, 0))
+    operands, in_specs = [q.reshape(n, 1, hd), k, v], [qblk, kblk, kblk]
+    if quantized:
+        operands += [k_scale.reshape(n, h, 1), v_scale.reshape(n, h, 1)]
+        in_specs += [sblk, sblk]
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(n, L // block),
+            in_specs=in_specs, out_specs=qblk,
+            scratch_shapes=[pltpu.VMEM((h, 1), jnp.float32),
+                            pltpu.VMEM((h, 1), jnp.float32),
+                            pltpu.VMEM((h, hd), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((n, 1, hd), q.dtype),
+        interpret=True)(pos, pos // block, *operands)
+    return out.reshape(n, h, d)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("quantized", [False, True], ids=["float", "int8"])
+def test_ungrouped_kernel_is_bit_for_bit_what_it_was(dtype, quantized):
+    """``G == H`` — the int8 pooled decode's path, and GPT-2's — gives
+    the bits it gave before the kernel took grouped queries and the
+    compacted grid: the same blocks in the same order, the same sums."""
+    q, k, v, pos = _pooled(n=5, L=64, dtype=dtype)
+    pos = jnp.asarray([0, 15, 16, 40, 63], jnp.int32)
+    ks = vs = None
+    if quantized:
+        k, v, ks, vs = _quantize(k, v)
+    k, v = _folded(k), _folded(v)
+    want = _pr32_kernel(q, k, v, pos, 16, ks, vs)
+    got = pooled_decode_attention(q, k, v, pos, k_scale=ks, v_scale=vs,
+                                  block=16, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_fetched_blocks_against_a_hand_count():
+    """Whole blocks up to ``min(pos, length - 1)`` for a row that
+    decodes, none for one that does not; numpy in, numpy out (the
+    serving counter), and the same numbers from jax arrays (the
+    kernel's wrapper)."""
+    pos = np.asarray([0, 127, 128, 300, 383, 500, 9000, 200])
+    active = np.asarray([1, 1, 1, 1, 1, 1, 1, 0], bool)
+    want = [1, 1, 2, 3, 3, 3, 3, 0]        # a ring of 384 holds 3 blocks
+    got = fetched_blocks(pos, active, 384, 128)
+    assert isinstance(got, np.ndarray) and got.tolist() == want
+    on_device = fetched_blocks(jnp.asarray(pos, jnp.int32),
+                               jnp.asarray(active), 384, 128)
+    assert np.asarray(on_device).tolist() == want
+    assert fetched_blocks(pos, True, 512, 512).tolist() == [1] * 8
+
+
+def test_pool_counts_fetched_bytes_in_whole_blocks_of_each_leaf():
+    """``KVPool.kv_fetched_bytes`` (the ``serving/kv_fetched_bytes``
+    sample): per decoding row and K/V leaf the kernel's whole blocks up
+    to the row's position, a ring never past its length."""
+    from bigdl_tpu.serving import KVPool
+
+    def init_carry(n):
+        carry = {"pos": jnp.zeros((n,), jnp.int32)}
+        for i, length in enumerate((1024, 2048)):   # a ring, a window
+            carry[f"k{i}"] = jnp.zeros((n, length, 256), jnp.bfloat16)
+            carry[f"v{i}"] = jnp.zeros((n, length, 256), jnp.bfloat16)
+        return carry
+
+    pool = KVPool(init_carry, 2)
+    tile = 512 * 256 * 2            # one K or V block of 512 positions
+    assert pool.kv_fetched_bytes([]) == 0
+    assert pool.kv_fetched_bytes([0]) == (1 + 1) * 2 * tile
+    assert pool.kv_fetched_bytes([511]) == (1 + 1) * 2 * tile
+    assert pool.kv_fetched_bytes([512]) == (2 + 2) * 2 * tile
+    assert pool.kv_fetched_bytes([1500]) == (2 + 3) * 2 * tile
+    assert pool.kv_fetched_bytes([0, 1500]) == (2 + 5) * 2 * tile
+    assert pool.kv_fetched_bytes([5000]) == (2 + 4) * 2 * tile
+    assert pool.kv_fetched_bytes([5000]) == pool.kv_bytes_per_slot
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, 3])
+def test_schedule_addresses_only_held_blocks_of_rows_that_decode(seed):
+    """The prefetch arrays the kernel's index maps read: every step,
+    the flagless tail included, addresses a block at or under its
+    row's ``pos`` of a row that decodes; each such row's blocks appear
+    once, in order, between one FIRST and one LAST flag."""
+    rng = np.random.default_rng(seed)
+    n, length, block = 6, 1024, 128
+    pos = rng.integers(0, length, size=n)
+    active = rng.random(n) < 0.6
+    active[seed % n] = True
+    row, blk, flag, total = (np.asarray(x) for x in _decode_schedule(
+        jnp.asarray(pos, jnp.int32), jnp.asarray(active), length, block))
+    nb = fetched_blocks(pos, active, length, block)
+    assert total == nb.sum() and len(row) == n * length // block
+    assert active[row].all()
+    assert (blk * block <= pos[row]).all() and (blk >= 0).all()
+    steps = [(r, b) for r in range(n) for b in range(nb[r])]
+    assert list(zip(row[:total], blk[:total])) == steps
+    first, run, last = (flag & bit != 0 for bit in (1, 2, 4))
+    assert run[:total].all() and not flag[total:].any()
+    assert (first[:total] == (blk[:total] == 0)).all()
+    assert (last[:total] == (blk[:total] == nb[row[:total]] - 1)).all()
+    # the tail re-addresses the last step's block: no new DMA
+    assert (row[total:] == row[total - 1]).all()
+    assert (blk[total:] == blk[total - 1]).all()
+
+
+def test_schedule_with_no_row_decoding_writes_one_row_of_zeros():
+    row, blk, flag, total = (np.asarray(x) for x in _decode_schedule(
+        jnp.asarray([5, 700], jnp.int32), jnp.zeros((2,), bool), 1024, 512))
+    assert total == 0 and flag.tolist() == [1 + 4, 0, 0, 0]
+    assert not blk.any() and (row == 1).all()
+    q, k, v, pos, _ = _grouped(4, 2, 16, 200)
+    out = pooled_decode_attention(q, k, v, pos, block=GBLOCK,
+                                  interpret=True,
+                                  active=jnp.zeros((4,), bool))
+    assert not np.asarray(out).any()
+
+
+def test_kernel_runs_by_rows_under_a_data_mesh():
+    """A data-parallel serving plane is a plain jit over a pool whose
+    slots XLA shards by itself, and a Mosaic kernel refuses to be
+    partitioned automatically: the decode step calls it under a
+    ``shard_map`` by rows (``models/transformer.py:_token_view``).
+    There the kernel runs on each device over the rows it holds (their
+    own schedule) with no collective, to the bits of the whole call."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from bigdl_tpu.utils.compat import shard_map
+
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(4, 1),
+                ("data", "model"))
+    q, k, v, pos, active = _grouped(4, 2, 16, 200, n=8)
+    pos = pos.at[0].set(0).at[3].set(GL - 1)
+    operands = (q, k, v, pos, jnp.asarray(active))
+
+    def attend(q, k, v, pos, active):
+        return decode_attention(q, k, v, pos, active=active, block=GBLOCK,
+                                impl="kernel", interpret=True)
+
+    placed = [jax.device_put(x, NamedSharding(
+        mesh, P("data", *[None] * (x.ndim - 1)))) for x in operands]
+    split = jax.jit(shard_map(attend, mesh=mesh, in_specs=P("data"),
+                              out_specs=P("data"), check_vma=False))
+    got = split(*placed)
+    assert got.sharding.spec[0] == "data"
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(attend(*operands)))
+    text = split.lower(*placed).compile().as_text()
+    assert "all-gather" not in text and "all-reduce" not in text
+
+
 # -- dispatch + validation -------------------------------------------------
 
 def test_auto_impl_uses_reference_off_tpu():
@@ -197,6 +477,23 @@ def test_auto_impl_uses_reference_off_tpu():
     auto = decode_attention(q, k, v, pos, impl="auto")
     ref = decode_attention(q, k, v, pos, impl="reference")
     np.testing.assert_array_equal(np.asarray(auto), np.asarray(ref))
+    kq, vq, ks, vs = _quantize(k, v)
+    auto = decode_attention(q, _folded(kq), _folded(vq), pos, k_scale=ks,
+                            v_scale=vs, active=pos > 0)
+    ref = decode_attention_reference(q, kq, vq, pos, k_scale=ks, v_scale=vs)
+    np.testing.assert_array_equal(np.asarray(auto), np.asarray(ref))
+
+
+def test_auto_impl_keeps_the_folded_sum_over_a_stored_float_cache():
+    """What the three pooled decode programs ran before they went
+    through the dispatch is what they run off the TPU: the folded
+    whole-window sum, rows that do not decode included."""
+    q, k, v, pos = _pooled(n=3, L=16)
+    got = decode_attention(q, _folded(k), _folded(v), pos, scale=0.2,
+                           active=jnp.asarray([True, False, True]))
+    want = folded_decode_attention(q, _folded(k), _folded(v), pos,
+                                   scale=0.2)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_validation_errors():
@@ -210,9 +507,12 @@ def test_validation_errors():
         decode_attention_reference(q, kq, vq, pos, k_scale=ks[:1],
                                    v_scale=vs[:1])
     with pytest.raises(ValueError, match="do not match q"):
-        decode_attention_reference(q, k[:, :, :2], v[:, :, :2], pos)
+        decode_attention_reference(q, k[:, :, :3], v[:, :, :3], pos)
     with pytest.raises(ValueError, match="do not match q"):
         decode_attention_reference(q, k.reshape(2, 16, -1)[:, :, :-1],
                                    v.reshape(2, 16, -1)[:, :, :-1], pos)
+    with pytest.raises(ValueError, match="not read through grouped"):
+        decode_attention_reference(q, kq[:, :, :2], vq[:, :, :2], pos,
+                                   k_scale=ks, v_scale=vs)
     with pytest.raises(ValueError, match="unknown impl"):
         decode_attention(q, k, v, pos, impl="magic")

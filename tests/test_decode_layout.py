@@ -8,7 +8,9 @@ pool-sized ``copy`` instructions per tensor per token (PERF.md, PR 27:
 30 ms of a 44.5 ms step at GPT-2-medium). Nothing on the CPU shows
 that: the local libtpu compiles the real step for a described
 ``v5e:2x2`` (XLA:TPU and Mosaic, no device), and the test reads the
-compiled HLO."""
+compiled HLO. On the chip every pooled decode program reads the pool
+through ``pooled_decode_attention``; on this host the dispatch probe
+would pick the whole-window jnp sums, so the tests turn it."""
 
 import math
 import os
@@ -29,7 +31,7 @@ POOL_ELEMS = N_SLOTS * MAX_LEN * HEADS * HD
 
 
 @pytest.fixture(scope="module")
-def v5e_device():
+def v5e_devices():
     from jax.experimental import topologies
 
     # what libtpu reads when it is loaded with no chip behind it
@@ -44,7 +46,12 @@ def v5e_device():
                                                 topology_name="v5e:2x2")
         except Exception as e:      # no libtpu, or one that cannot describe
             pytest.skip(f"libtpu gives no v5e topology here: {e}")
-    return topo.devices[0]
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def v5e_device(v5e_devices):
+    return v5e_devices[0]
 
 
 def _compile_decode_step(device, kv_quant):
@@ -68,22 +75,25 @@ def _compile_decode_step(device, kv_quant):
                       knobs).compile()
 
 
+@pytest.fixture
+def on_the_chip(monkeypatch):
+    monkeypatch.setattr("bigdl_tpu.utils.compat.auto_interpret",
+                        lambda: False)
+
+
+def _pool_sized_copies(text, sizes):
+    return [m.group(0)
+            for m in re.finditer(r"= \w+\[([\d,]+)\]\S* copy\(", text)
+            if math.prod(map(int, m.group(1).split(","))) in sizes]
+
+
 @pytest.mark.parametrize("kv_quant", [False, True], ids=["bf16", "int8"])
-def test_decode_step_never_copies_the_pool(v5e_device, kv_quant, monkeypatch):
-    if kv_quant:
-        # the chip's int8 read is the Pallas kernel; on this host the
-        # dispatch probe would pick the jnp reference
-        monkeypatch.setattr("bigdl_tpu.utils.compat.auto_interpret",
-                            lambda: False)
+def test_decode_step_never_copies_the_pool(v5e_device, kv_quant,
+                                           on_the_chip):
     compiled = _compile_decode_step(v5e_device, kv_quant)
     text = compiled.as_text()
-    if kv_quant:
-        assert "pooled_decode_attention" in text
-    pool_copies = [
-        m.group(0)
-        for m in re.finditer(r"= \w+\[([\d,]+)\]\S* copy\(", text)
-        if math.prod(map(int, m.group(1).split(","))) == POOL_ELEMS]
-    assert not pool_copies, pool_copies
+    assert "pooled_decode_attention" in text
+    assert not _pool_sized_copies(text, {POOL_ELEMS})
     # the donated carry comes back in the layout it arrived in
     carry_in = compiled.input_formats[0][3]
     carry_out = compiled.output_formats[2]
@@ -92,8 +102,94 @@ def test_decode_step_never_copies_the_pool(v5e_device, kv_quant, monkeypatch):
         assert carry_in[key].layout.major_to_minor == (0, 1, 2), key
 
 
+def test_data_mesh_decode_step_runs_the_kernel_on_each_chips_rows(
+        v5e_devices, on_the_chip):
+    """A data-only plane (four chips, the slots sharded over them, the
+    program a plain jit that XLA partitions): the decode kernel, which
+    XLA cannot partition, runs per chip over the rows the chip holds
+    (``_token_view``'s ``shard_map`` by rows); nothing is gathered or
+    reduced across chips."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from bigdl_tpu.models.transformer import serving_carry_specs
+    from bigdl_tpu.serving.sampling import knob_partition_specs
+
+    mesh = Mesh(np.array(v5e_devices).reshape(4, 1), ("data", "model"))
+
+    def sds(x, spec):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    lm = TransformerLM(VOCAB, hidden_size=HEADS * HD, n_heads=HEADS,
+                       n_layers=2, max_len=MAX_LEN, output="logits")
+    step, init_carry = make_batch_decode_step(
+        lm, jnp.bfloat16, sampling=True, mesh=mesh)
+    params = jax.tree.map(lambda x: sds(x, P()), jax.eval_shape(
+        lambda: serving_params(lm, jnp.bfloat16)))
+    carry = jax.tree.map(
+        sds, jax.eval_shape(lambda: init_carry(N_SLOTS)),
+        serving_carry_specs(lm, sampling=True, data_axis="data",
+                            model_axis=None))
+    knobs = jax.tree.map(sds, make_knob_rows(N_SLOTS, vocab=VOCAB),
+                         knob_partition_specs("data"))
+    text = step.lower(
+        params, sds(jnp.zeros((N_SLOTS,), jnp.int32), P("data")),
+        sds(jnp.zeros((N_SLOTS,), bool), P("data")), carry,
+        knobs).compile().as_text()
+    # each chip's kernel sees its own two of the eight rows
+    assert re.search(r"pooled_decode_attention\S* = bf16\[2,", text)
+    for collective in ("all-gather", "all-reduce", "all-to-all",
+                       "collective-permute"):
+        assert f" {collective}(" not in text, collective
+
+
+@pytest.mark.parametrize("shape", [(4, 1), (2, 2)], ids=["4x1", "2x2"])
+def test_draft_decode_step_lowers_on_every_serving_mesh(
+        v5e_devices, on_the_chip, shape):
+    """The speculative DRAFT's decode step (``serving/speculative.py``:
+    weights replicated, the carry's rows sharded over ``data`` and whole
+    per chip over ``model``, a plain jit on the data-only and on the
+    DP x TP plane alike) takes the plane's mesh with no model axis, so
+    its kernel too runs per chip over the rows the chip holds. Built
+    without the mesh it does not lower for a TPU at all."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from bigdl_tpu.models.transformer import serving_carry_specs
+
+    mesh = Mesh(np.array(v5e_devices).reshape(shape), ("data", "model"))
+
+    def sds(x, spec):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    lm = TransformerLM(VOCAB, hidden_size=HEADS * HD, n_heads=HEADS,
+                       n_layers=2, max_len=MAX_LEN, output="logits")
+    lm._ensure_params()
+    params = jax.tree.map(lambda x: sds(x, P()), jax.eval_shape(
+        lambda: serving_params(lm, jnp.bfloat16)))
+
+    def lower(**plane):
+        step, init_carry = make_batch_decode_step(lm, jnp.bfloat16, **plane)
+        carry = jax.tree.map(
+            sds, jax.eval_shape(lambda: init_carry(N_SLOTS)),
+            serving_carry_specs(lm, sampling=False, data_axis="data",
+                                model_axis=None))
+        return step.lower(
+            params, sds(jnp.zeros((N_SLOTS,), jnp.int32), P("data")),
+            sds(jnp.zeros((N_SLOTS,), bool), P("data")), carry)
+
+    text = lower(mesh=mesh, model_axis=None).compile().as_text()
+    rows = N_SLOTS // shape[0]
+    assert re.search(rf"pooled_decode_attention\S* = bf16\[{rows},", text)
+    assert not _pool_sized_copies(text, {POOL_ELEMS // shape[0]})
+    with pytest.raises(Exception, match="automatically partitioned"):
+        lower().compile()
+
+
 def test_recurrent_family_decode_step_copies_neither_cache_nor_state(
-        v5e_device):
+        v5e_device, on_the_chip):
     """The Falcon-H1 family's decode step at head and state widths of
     the published model (128-wide heads, a 128 x 256 state a head): the
     grouped-query read of the stored 3-D K/V and the in-place update of
@@ -129,14 +225,57 @@ def test_recurrent_family_decode_step_copies_neither_cache_nor_state(
     compiled = step.lower(params, sds(jnp.zeros((N_SLOTS,), jnp.int32)),
                           sds(jnp.zeros((N_SLOTS,), bool)), carry,
                           knobs).compile()
-    pooled = {math.prod(carry[key].shape) for key in ("k0", "ssm0")}
-    copies = [
-        m.group(0)
-        for m in re.finditer(r"= \w+\[([\d,]+)\]\S* copy\(",
-                             compiled.as_text())
-        if math.prod(map(int, m.group(1).split(","))) in pooled]
-    assert not copies, copies
+    text = compiled.as_text()
+    assert "pooled_decode_attention" in text
+    assert not _pool_sized_copies(
+        text, {math.prod(carry[key].shape) for key in ("k0", "ssm0")})
     carry_in = compiled.input_formats[0][3]
     carry_out = compiled.output_formats[2]
     for key in ("k0", "v1", "ssm0", "conv1"):
+        assert carry_in[key].layout == carry_out[key].layout, key
+
+
+def test_routed_family_decode_step_reads_rings_and_windows_in_place(
+        v5e_device, on_the_chip):
+    """The ``afmoe`` family's decode step at the published head width
+    (128, four query heads a K/V head): a sliding layer's ring and a
+    full layer's window, leaves of two lengths, go through the kernel
+    where they are stored."""
+    from jax.sharding import SingleDeviceSharding
+
+    from bigdl_tpu.models.afmoe import AfmoeLM
+
+    sh = SingleDeviceSharding(v5e_device)
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh)
+
+    config = dict(
+        vocab_size=VOCAB, hidden_size=384, intermediate_size=512,
+        moe_intermediate_size=128, num_hidden_layers=3, num_dense_layers=1,
+        num_attention_heads=8, num_key_value_heads=2, head_dim=128,
+        num_experts=4, num_experts_per_tok=2, num_shared_experts=1,
+        route_norm=True, route_scale=2.448, score_func="sigmoid",
+        sliding_window=128,
+        layer_types=["sliding_attention", "full_attention",
+                     "sliding_attention"],
+        rms_norm_eps=1e-5, rope_theta=10000, mup_enabled=True,
+        expert_share={"index": 1, "of": 4})
+    lm = AfmoeLM(config, max_len=MAX_LEN, param_dtype="bfloat16")
+    step, init_carry = lm.serving_family().decode_step(jnp.bfloat16)
+    params = jax.tree.map(sds, jax.eval_shape(
+        lm.init_params, jax.random.PRNGKey(0)))
+    carry = jax.tree.map(sds, jax.eval_shape(lambda: init_carry(N_SLOTS)))
+    assert carry["k0"].shape[1] == 128 and carry["k1"].shape[1] == MAX_LEN
+    knobs = jax.tree.map(sds, make_knob_rows(N_SLOTS, vocab=VOCAB))
+    compiled = step.lower(params, sds(jnp.zeros((N_SLOTS,), jnp.int32)),
+                          sds(jnp.zeros((N_SLOTS,), bool)), carry,
+                          knobs).compile()
+    text = compiled.as_text()
+    assert text.count("pooled_decode_attention") >= 3
+    assert not _pool_sized_copies(
+        text, {math.prod(carry[key].shape) for key in ("k0", "k1")})
+    carry_in = compiled.input_formats[0][3]
+    carry_out = compiled.output_formats[2]
+    for key in ("k0", "v0", "k1", "v2"):
         assert carry_in[key].layout == carry_out[key].layout, key

@@ -427,6 +427,11 @@ def test_spec_metrics_accounting(lm, good_draft):
     assert s["serving/tokens_per_step"] > 1.0   # weight-tied draft
     assert s["serving/tokens_per_step"] == pytest.approx(
         (n_acc + n_rows) / n_rows)
+    # the target side is the verify step, which reads the whole window:
+    # what the pooled decode kernel would fetch is not sampled
+    m = eng.metrics.metrics
+    assert m.values("serving/batch_active")
+    assert not m.values("serving/kv_fetched_bytes")
 
 
 # -- sharded plane ----------------------------------------------------------
@@ -457,6 +462,28 @@ def test_sharded_speculative_parity(lm, good_draft):
         got = run(par)
         for a, b in zip(base, got):
             np.testing.assert_array_equal(a, b, err_msg=str(par))
+
+
+@pytest.mark.mesh
+@pytest.mark.parametrize("par", [{"data": 4}, {"data": 2, "model": 2}],
+                         ids=["data4", "dp2tp2"])
+def test_draft_step_is_built_for_the_planes_mesh(lm, good_draft, par):
+    """The draft's decode step is a plain jit that XLA partitions by
+    rows on every plane, and on a TPU its attention kernel has to be
+    told which rows a chip holds: the speculator builds it with the
+    plane's mesh and no model axis (tests/test_decode_layout.py compiles
+    that step for both meshes of a v5e; without the mesh it does not
+    lower there)."""
+    from bigdl_tpu.models.transformer import get_batch_decode_step
+    from bigdl_tpu.serving import ServingEngine
+
+    eng = ServingEngine(lm, n_slots=4, parallelism=par,
+                        speculative=_spec(good_draft))
+    step, _ = get_batch_decode_step(good_draft, eng.compute_dtype,
+                                    mesh=eng.mesh, model_axis=None)
+    assert eng._spec._draft_step_fn is step
+    assert step is not get_batch_decode_step(good_draft,
+                                             eng.compute_dtype)[0]
 
 
 # -- bench registration smoke (tier-1, small/CPU) ---------------------------
