@@ -179,12 +179,15 @@ def measure(name: str, variant: str, batch: int, reps: int = 3) -> dict:
 
 
 #: the pooled decode attention of the serving cells, as stored: rows,
-#: window, query heads, K/V heads, head width (BENCHMARK.json's configs)
+#: window, query heads, K/V heads, head width (BENCHMARK.json's configs);
+#: ``v_width``: a latent cache, ONE leaf whose leading columns are the
+#: values (GLM-4.7-Flash's 576-value row in its 640 stored columns)
 ATTENTION_SHAPES = {
     "gpt2m": dict(n=32, L=1024, h=16, g=16, d=64),
     "falconh1": dict(n=32, L=1024, h=20, g=4, d=128),
     "trinity-ring": dict(n=16, L=4096, h=48, g=8, d=128),
     "trinity-full": dict(n=16, L=8192, h=48, g=8, d=128),
+    "glm-latent": dict(n=32, L=16384, h=20, g=1, d=640, v_width=512),
 }
 
 
@@ -200,7 +203,10 @@ def measure_attention(shape: str, fill: float, active_share: float,
     ``CALLS`` dependent calls (a call's output feeds the next one's
     query), so the launch is amortised. On a CPU host the kernel runs
     in Pallas INTERPRET mode — emulation time, not kernel speed; the
-    row carries ``interpret`` so readers can tell."""
+    row carries ``interpret`` so readers can tell. A latent shape
+    (``v_width``) has no V array: the kernel fetches each block once
+    for both products, and ``two_fetch_ms`` times the grouped kernel
+    handed the leaf as keys AND as values beside it."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -214,6 +220,7 @@ def measure_attention(shape: str, fill: float, active_share: float,
     CALLS = 16
     dims = ATTENTION_SHAPES[shape]
     n, L, h, g, d = (dims[key] for key in "nLhgd")
+    v_width = dims.get("v_width")
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.standard_normal((n, h, d)), jnp.bfloat16)
     stride = max(1, round(1 / active_share))
@@ -230,24 +237,34 @@ def measure_attention(shape: str, fill: float, active_share: float,
                             jnp.bfloat16) for _ in range(2))
     if block is None:
         block = auto_block_l(L, g * d * k.dtype.itemsize)
+    if v_width is not None:
+        v = None
     args = (q, k, v, jnp.asarray(pos), jnp.asarray(active))
 
     def whole_window(q, k, v, pos, active):
         if ks is not None:
             return decode_attention_reference(q, k, v, pos, k_scale=ks,
                                               v_scale=vs)
-        return folded_decode_attention(q, k, v, pos)
+        return folded_decode_attention(q, k, v, pos, v_width=v_width)
 
     def kernel(q, k, v, pos, active):
         return decode_attention(q, k, v, pos, k_scale=ks, v_scale=vs,
-                                active=active, block=block, impl="kernel")
+                                active=active, block=block, impl="kernel",
+                                v_width=v_width)
+
+    def two_fetches(q, k, v, pos, active):
+        return decode_attention(q, k, k, pos, active=active, block=block,
+                                impl="kernel")[..., :v_width]
 
     def timed(fn) -> float:
+        def fed_back(qq, out):       # a context may be narrower than q
+            out = (out * 1e-3).astype(qq.dtype)
+            return qq + jnp.pad(out, [(0, 0), (0, 0),
+                                      (0, qq.shape[-1] - out.shape[-1])])
+
         def many(q, *rest):
             return lax.fori_loop(
-                0, CALLS,
-                lambda _, qq: qq + (fn(qq, *rest) * 1e-3).astype(qq.dtype),
-                q)
+                0, CALLS, lambda _, qq: fed_back(qq, fn(qq, *rest)), q)
 
         many = jax.jit(many)
         jax.block_until_ready(many(*args))          # compile + warm
@@ -262,7 +279,9 @@ def measure_attention(shape: str, fill: float, active_share: float,
     got = np.asarray(jax.jit(kernel)(*args), np.float32)[active]
     whole_s = timed(whole_window)
     kern_s = timed(kernel)
-    position_bytes = 2 * g * d * k.dtype.itemsize
+    position_bytes = (1 if v_width else 2) * g * d * k.dtype.itemsize
+    extra = {} if v_width is None else {
+        "two_fetch_ms": round(1e3 * timed(two_fetches), 4)}
     return {
         "metric": "decode_attention_call_ms", "shape": shape, **dims,
         "variant": variant, "fill": fill, "rows_decoding": int(active.sum()),
@@ -274,6 +293,7 @@ def measure_attention(shape: str, fill: float, active_share: float,
         "whole_window_ms": round(1e3 * whole_s, 4),
         "kernel_ms": round(1e3 * kern_s, 4),
         "kernel_vs_whole_window": round(whole_s / max(kern_s, 1e-9), 3),
+        **extra,
     }
 
 

@@ -62,10 +62,24 @@ def _auto_interpret() -> bool:
     return auto_interpret()
 
 
-def _check_qkv(q, k, v, k_scale, v_scale) -> int:
+def _check_qkv(q, k, v, k_scale, v_scale, v_width=None) -> int:
     """K/V come as the stored ``(N, L, G*D)`` array or its
     ``(N, L, G, D)`` view — the same bytes, head-major lanes — with
-    ``G`` K/V heads, each read by ``H / G`` query heads. Returns ``G``."""
+    ``G`` K/V heads, each read by ``H / G`` query heads. Returns ``G``.
+    With ``v_width`` there is ONE stored array ``k`` (N, L, D) of one
+    head that every query head reads, whose leading ``v_width`` columns
+    are the values (``v`` is None): returns 1."""
+    if v_width is not None:
+        if v is not None or k_scale is not None or v_scale is not None \
+                or q.ndim != 3 or k.ndim != 3 \
+                or (k.shape[0], k.shape[2]) != (q.shape[0], q.shape[2]) \
+                or not 0 < v_width <= k.shape[2]:
+            raise ValueError(
+                f"v_width={v_width}: expected q (N, H, D), ONE float cache "
+                f"k (N, L, D) whose leading v_width columns are the values, "
+                f"and no v, got {q.shape} / {k.shape} / "
+                f"{None if v is None else v.shape}")
+        return 1
     if q.ndim != 3 or k.ndim not in (3, 4) or v.ndim != k.ndim:
         raise ValueError(
             f"expected q (N, H, D) and k/v (N, L, G*D) or (N, L, G, D), "
@@ -107,7 +121,7 @@ def _own_heads(h: int, g: int):
 
 def decode_attention_reference(q, k, v, pos, k_scale=None, v_scale=None,
                                scale: Optional[float] = None,
-                               out_dtype=None):
+                               out_dtype=None, v_width=None):
     """Masked single-query pooled attention, plain jnp — the numerics
     contract the kernel is tested against AND the CPU serving path.
 
@@ -122,12 +136,15 @@ def decode_attention_reference(q, k, v, pos, k_scale=None, v_scale=None,
     runs the q.k and p.v contractions on the RAW int8 values (cast to
     f32) and applies the per-(row, head) scales as factored-out scalar
     multiplies — exactly the kernel's fused-dequant math. Returns
-    (N, H, D) in ``out_dtype`` (default: q's dtype)."""
-    g = _check_qkv(q, k, v, k_scale, v_scale)
+    (N, H, D) in ``out_dtype`` (default: q's dtype). ``v_width``: the
+    values are the leading ``v_width`` columns of the ONE cache ``k``
+    (N, L, D) that all heads read (``v`` None); returns (N, H,
+    v_width)."""
+    g = _check_qkv(q, k, v, k_scale, v_scale, v_width)
     n, h, d = q.shape
     L = k.shape[1]
     k = k.reshape(n, L, g, d)
-    v = v.reshape(n, L, g, d)
+    v = k[..., :v_width] if v_width is not None else v.reshape(n, L, g, d)
     if g != h:
         k, v = (jnp.repeat(x, h // g, axis=2) for x in (k, v))
     if scale is None:
@@ -157,7 +174,7 @@ def decode_attention_reference(q, k, v, pos, k_scale=None, v_scale=None,
 
 
 def folded_decode_attention(q, k, v, pos, scale: Optional[float] = None,
-                            out_dtype=None):
+                            out_dtype=None, v_width=None):
     """:func:`decode_attention_reference`'s float sum against the STORED
     cache ``(N, L, H*D)``, never through a 4-D view of it — what the
     float decode steps call every token.
@@ -180,20 +197,27 @@ def folded_decode_attention(q, k, v, pos, scale: Optional[float] = None,
     GROUPED queries: a cache of ``G < H`` heads ``(N, L, G*D)`` serves
     query head ``j`` from K/V head ``j // (H // G)`` the same way:
     column ``j`` of the query matrix holds ``q[j]`` in its K/V head's
-    lanes, and head ``j``'s context is that head's block of row ``j``."""
+    lanes, and head ``j``'s context is that head's block of row ``j``.
+
+    ``v_width``: ONE cache ``k`` (N, L, D) that every head reads, whose
+    leading ``v_width`` columns are the values (``v`` None): the query
+    matrix is the queries themselves and the values a slice of the
+    keys; returns (N, H, v_width)."""
     n, h, d = q.shape
     if k.ndim != 3:
         raise ValueError(
             f"the folded form reads the stored (N, L, G*D) cache, got "
             f"{k.shape}")
-    g = _check_qkv(q, k, v, None, None)
+    g = _check_qkv(q, k, v, None, None, v_width)
     L = k.shape[1]
     if scale is None:
         scale = d ** -0.5
     if out_dtype is None:
         out_dtype = q.dtype
     qs = (q * scale).astype(k.dtype)
-    if g == h:
+    if v_width is not None:
+        q_bd, v = qs.transpose(0, 2, 1), k[..., :v_width]
+    elif g == h:
         q_bd = (qs[:, :, :, None] * jnp.eye(h, dtype=k.dtype)[:, None, :]
                 ).reshape(n, h * d, h)
     else:
@@ -208,7 +232,9 @@ def folded_decode_attention(q, k, v, pos, scale: Optional[float] = None,
     full = jnp.einsum("nhl,nlc->nhc", p.astype(v.dtype), v,
                       preferred_element_type=jnp.float32)
     # head j's context is its own K/V head's D-wide block of row j
-    if g == h:
+    if v_width is not None:
+        ctx = full
+    elif g == h:
         ctx = jnp.einsum("nhhd->nhd", full.reshape(n, h, h, d))
     else:
         ctx = jnp.einsum("nhgd,hg->nhd", full.reshape(n, h, g, d),
@@ -264,7 +290,7 @@ def _decode_schedule(pos, active, length: int, block: int):
     return row, blk, flag.astype(jnp.int32), total
 
 
-def _decode_kernel(*refs, scale):
+def _decode_kernel(*refs, scale, v_width=None):
     """One grid step of :func:`_decode_schedule`: one ``(block_l, C)``
     K tile and one V tile of one row are VMEM-resident (``C = G*D``,
     the K/V heads folded into the lanes), and the online-softmax state
@@ -290,9 +316,14 @@ def _decode_kernel(*refs, scale):
     Quantized layout: int8 K/V tiles are loaded RAW; the (row, head)
     scales enter as ``(H, 1)`` column factors — k_scale folds into the
     score scaling, v_scale multiplies the accumulated context once at
-    the end (exact: both are constant over the contracted axes)."""
-    (pos_ref, row_ref, blk_ref, flag_ref, q_ref, k_ref, v_ref, *scale_refs,
-     _, o_ref, m_scr, l_scr, acc_scr) = refs
+    the end (exact: both are constant over the contracted axes).
+
+    ``v_width`` (a latent cache): there is no V tile. The ONE fetched
+    tile is the keys, and its leading ``v_width`` lanes are the values
+    of the second product."""
+    pos_ref, row_ref, blk_ref, flag_ref, q_ref, k_ref, *rest = refs
+    v_ref = rest.pop(0) if v_width is None else None
+    *scale_refs, _, o_ref, m_scr, l_scr, acc_scr = rest
     quantized = bool(scale_refs)
     if quantized:
         ks_ref, vs_ref = scale_refs
@@ -313,7 +344,7 @@ def _decode_kernel(*refs, scale):
     @pl.when(flag & _RUN != 0)
     def _step():
         k = k_ref[0]                                    # (BL, C)
-        v = v_ref[0]
+        v = k[:, :v_width] if v_width is not None else v_ref[0]
         q_bd = q_ref[0]                                 # (H, C)
         if quantized:
             s = jax.lax.dot_general(
@@ -379,7 +410,7 @@ def pooled_decode_attention(q, k, v, pos, k_scale=None, v_scale=None,
                             scale: Optional[float] = None,
                             block: Optional[int] = None,
                             interpret: Optional[bool] = None,
-                            out_dtype=None, active=None):
+                            out_dtype=None, active=None, v_width=None):
     """Pallas pooled decode attention over slot-indexed KV.
 
     Same contract as :func:`decode_attention_reference` (q ``(N, H, D)``,
@@ -399,14 +430,21 @@ def pooled_decode_attention(q, k, v, pos, k_scale=None, v_scale=None,
     sit beyond every row's ``pos`` and are masked like any other
     out-of-window position. The kernel reads the cache as
     ``(N, L, G*D)`` (heads folded into the lane axis — see
-    :func:`_decode_kernel`), which is how the pool stores it."""
+    :func:`_decode_kernel`), which is how the pool stores it.
+
+    ``v_width`` (a latent cache): ``k`` (N, L, D) is the ONE stored
+    array, read by every query head, and its leading ``v_width``
+    columns are the values (``v`` None). Each held block is fetched
+    ONCE and used for both products (the kernel's name in a profile is
+    then ``latent_decode_attention``); returns (N, H, v_width)."""
     from jax.experimental.pallas import tpu as pltpu
 
     from bigdl_tpu.utils.compat import pallas_tpu_compiler_params
 
-    g = _check_qkv(q, k, v, k_scale, v_scale)
+    g = _check_qkv(q, k, v, k_scale, v_scale, v_width)
     n, h, d = q.shape
     L, c = k.shape[1], g * d
+    c_out = c if v_width is None else v_width     # lanes of the context
     if interpret is None:
         interpret = _auto_interpret()
     if block is None:
@@ -414,15 +452,14 @@ def pooled_decode_attention(q, k, v, pos, k_scale=None, v_scale=None,
     scale = float(d ** -0.5 if scale is None else scale)
     if out_dtype is None:
         out_dtype = q.dtype
-    k, v = k.reshape(n, L, c), v.reshape(n, L, c)
+    kv = [k.reshape(n, L, c)] + ([] if v is None else [v.reshape(n, L, c)])
     pos = jnp.asarray(pos, jnp.int32).reshape(n)
     if active is None:
         active = jnp.ones((n,), bool)
     scales = () if k_scale is None else (k_scale, v_scale)
     pad = (-L) % block
     if pad:
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0)))
+        kv = [jnp.pad(x, ((0, 0), (0, pad), (0, 0))) for x in kv]
     row, blk, flag, total = _decode_schedule(pos, active, L + pad, block)
     # own[j, c]: query head j reads K/V head c; row j of the
     # block-diagonal query holds q[j] in that head's lanes
@@ -438,19 +475,20 @@ def pooled_decode_attention(q, k, v, pos, k_scale=None, v_scale=None,
     # every block's last two dims are whole array dims or (block_l:
     # a 128-multiple, C: whole) — what the Mosaic lowering accepts
     qblk = pl.BlockSpec((1, h, c), at_row)
+    oblk = pl.BlockSpec((1, h, c_out), at_row)
     kblk = pl.BlockSpec(
         (1, block, c), lambda t, pos_, row_, blk_, flag_: (row_[t], blk_[t], 0))
     sblk = pl.BlockSpec((1, h, 1), at_row)
-    operands = [q_bd, k, v] + [
+    operands = [q_bd, *kv] + [
         s.astype(jnp.float32).reshape(n, h, 1) for s in scales]
-    in_specs = [qblk, kblk, kblk] + [sblk] * len(scales)
+    in_specs = [qblk] + [kblk] * len(kv) + [sblk] * len(scales)
     # the output starts as zeros and aliases them: the rows no step
     # writes (those that do not decode) stay zeros
-    operands.append(jnp.zeros((n, h, c), out_dtype))
+    operands.append(jnp.zeros((n, h, c_out), out_dtype))
     in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
     n_prefetch = 4
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=scale),
+        functools.partial(_decode_kernel, scale=scale, v_width=v_width),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=n_prefetch,
             # compiled, the grid is as long as the schedule; the
@@ -458,19 +496,22 @@ def pooled_decode_attention(q, k, v, pos, k_scale=None, v_scale=None,
             # tail
             grid=(row.shape[0] if interpret else jnp.maximum(total, 1),),
             in_specs=in_specs,
-            out_specs=qblk,
+            out_specs=oblk,
             scratch_shapes=[
                 pltpu.VMEM((h, 1), jnp.float32),
                 pltpu.VMEM((h, 1), jnp.float32),
-                pltpu.VMEM((h, c), jnp.float32),
+                pltpu.VMEM((h, c_out), jnp.float32),
             ]),
-        out_shape=_out_struct((n, h, c), out_dtype, pos, *operands),
+        out_shape=_out_struct((n, h, c_out), out_dtype, pos, *operands),
         input_output_aliases={n_prefetch + len(operands) - 1: 0},
         compiler_params=None if interpret else pallas_tpu_compiler_params(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name="pooled_decode_attention",
+        name="pooled_decode_attention" if v_width is None
+        else "latent_decode_attention",
     )(pos, row, blk, flag, *operands)
+    if v_width is not None:
+        return out
     # head j's context is its own K/V head's D-wide block of row j
     return jnp.sum(jnp.where(own[None, :, :, None],
                              out.reshape(n, h, g, d), 0), axis=2)
@@ -480,7 +521,8 @@ def decode_attention(q, k, v, pos, k_scale=None, v_scale=None,
                      scale: Optional[float] = None,
                      block: Optional[int] = None,
                      interpret: Optional[bool] = None,
-                     impl: str = "auto", out_dtype=None, active=None):
+                     impl: str = "auto", out_dtype=None, active=None,
+                     v_width=None):
     """The pooled decode steps' dispatch point: ``impl="auto"`` runs
     the compiled Pallas kernel on a TPU. Elsewhere (interpret-mode
     Pallas is an emulator — correct but far too slow for the CPU CI
@@ -490,7 +532,13 @@ def decode_attention(q, k, v, pos, k_scale=None, v_scale=None,
     one or a 4-D view. ``"kernel"``/``"reference"`` force a path
     (tests pin kernel-vs-reference numerics with ``impl="kernel",
     interpret=True``). ``active`` (N,) bool: the rows that decode; the
-    output rows of the others are ballast."""
+    output rows of the others are ballast.
+
+    ``v_width``: a LATENT cache. ``k`` (N, L, D) is the one stored
+    array of one K/V head that all ``H`` query heads (N, H, D) read,
+    the values are its leading ``v_width`` columns, and ``v`` is None:
+    the kernel fetches each held block once for both products, the
+    folded sum slices the keys. Returns (N, H, v_width)."""
     if impl not in ("auto", "kernel", "reference"):
         raise ValueError(f"unknown impl {impl!r}")
     if impl == "auto" and not _auto_interpret():
@@ -499,10 +547,10 @@ def decode_attention(q, k, v, pos, k_scale=None, v_scale=None,
         return pooled_decode_attention(
             q, k, v, pos, k_scale=k_scale, v_scale=v_scale, scale=scale,
             block=block, interpret=interpret, out_dtype=out_dtype,
-            active=active)
+            active=active, v_width=v_width)
     if impl == "auto" and k_scale is None and k.ndim == 3:
         return folded_decode_attention(q, k, v, pos, scale=scale,
-                                       out_dtype=out_dtype)
+                                       out_dtype=out_dtype, v_width=v_width)
     return decode_attention_reference(
         q, k, v, pos, k_scale=k_scale, v_scale=v_scale, scale=scale,
-        out_dtype=out_dtype)
+        out_dtype=out_dtype, v_width=v_width)

@@ -499,7 +499,8 @@ class ServingEngine:
         # KV-format observability: bytes one slot owns + the derived
         # effective-capacity number (slots a GiB of HBM would hold)
         self.metrics.set_kv_format(kv_dtype, self.pool.kv_bytes_per_slot,
-                                   self.pool.state_bytes_per_slot)
+                                   self.pool.state_bytes_per_slot,
+                                   self.pool.kv_position_bytes)
         self.admission = admission
         self.keep_finished = keep_finished
         self.seed = int(seed)

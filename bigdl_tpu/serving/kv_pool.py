@@ -17,7 +17,10 @@ the slot as its first axis, and the pool treats each by WHAT IT IS
 * ``pos`` — the row's position counter;
 * ``kv`` — ``k{i}`` / ``v{i}``, position-indexed caches ``(n_slots,
   len_i, heads*head_dim)``: admission scatters them, ``free()``
-  leaves them (stale rows are masked by ``pos``). ``len_i`` is the
+  leaves them (stale rows are masked by ``pos``). A ``kv`` leaf need
+  not have a twin: a latent-attention family keeps ONE leaf ``k{i}`` a
+  layer, whose leading columns are its values, and no ``v{i}``
+  (``models/glm_moe_lite.py``); nothing here pairs the two. ``len_i`` is the
   LAYER's: the family's cache window ``max_len`` for a layer that
   attends over the whole context, less for a sliding-window layer,
   whose leaf is a RING that holds position ``p`` at ``p % len_i`` and
@@ -169,6 +172,9 @@ class KVPool:
                 key = (int(v.shape[1]), auto_block_l(int(v.shape[1]), nbytes))
                 self._kv_position_bytes[key] = \
                     self._kv_position_bytes.get(key, 0) + nbytes
+        # bytes ONE position holds over all K/V leaves of a slot, as
+        # stored (a latent row's lane padding included)
+        self.kv_position_bytes = sum(self._kv_position_bytes.values())
         self.state_bytes_per_slot = slot_bytes("state")
         # LIFO free list: the most recently freed row is the most likely
         # to still be resident in cache/HBM

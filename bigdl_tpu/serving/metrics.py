@@ -212,6 +212,11 @@ KV-format counters (``serving/kv_pool.py`` — set once at construction):
   payload + per-(slot, head) scales on the quantized path)
 * ``state_bytes_per_slot`` — one slot's other per-slot state in bytes
   (``KVPool.state_bytes_per_slot``; 0 for a family that keeps none)
+* ``kv_position_bytes``  — bytes ONE cached position holds over all K/V
+  leaves of a slot, as stored (``KVPool.kv_position_bytes``: K and V of
+  every layer, or a latent family's one padded row a layer); a constant,
+  set at construction and repeated with every step's sample, so that a
+  reader of a window's slice of the series finds it
 * ``kv_slots_per_gib``   — derived effective capacity: concurrent
   slots per GiB of HBM at this format (the int8 path's ~2x headline)
 """
@@ -292,6 +297,9 @@ class ServingMetrics:
         # recovery paths too — without re-summing the backing lists
         self._device_s = 0.0
         self._n_decode_steps = 0
+        # set_kv_format's constant, repeated beside every step's
+        # kv_held_bytes sample
+        self._kv_position_bytes: Optional[int] = None
 
     # -- engine hooks ------------------------------------------------------
 
@@ -328,6 +336,9 @@ class ServingMetrics:
                              float(state_in_use_bytes))
         if kv_held_bytes is not None:
             self.metrics.add("serving/kv_held_bytes", float(kv_held_bytes))
+            if self._kv_position_bytes is not None:
+                self.metrics.add("serving/kv_position_bytes",
+                                 float(self._kv_position_bytes))
         if kv_fetched_bytes is not None:
             self.metrics.add("serving/kv_fetched_bytes",
                              float(kv_fetched_bytes))
@@ -455,7 +466,8 @@ class ServingMetrics:
         self.metrics.set("serving/mesh_model_shards", float(model_shards))
 
     def set_kv_format(self, kv_dtype: str, bytes_per_slot: int,
-                      state_bytes_per_slot: int = 0) -> None:
+                      state_bytes_per_slot: int = 0,
+                      position_bytes: Optional[int] = None) -> None:
         """Record the pooled cache's storage format (once, at
         construction): bits per stored K/V element, the per-slot KV
         footprint in bytes (int8 payload + dequant scales, or the float
@@ -467,6 +479,10 @@ class ServingMetrics:
         self.metrics.set("serving/kv_bytes_per_slot", float(bytes_per_slot))
         self.metrics.set("serving/state_bytes_per_slot",
                          float(state_bytes_per_slot))
+        self._kv_position_bytes = position_bytes
+        if position_bytes is not None:
+            self.metrics.set("serving/kv_position_bytes",
+                             float(position_bytes))
         self.metrics.set("serving/kv_slots_per_gib",
                          float((1 << 30) // max(int(bytes_per_slot), 1)))
 

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bigdl_tpu.models import afmoe
+from bigdl_tpu.models import afmoe, decoder_ops
 from bigdl_tpu.models.afmoe import AfmoeLM
 from bigdl_tpu.parallel.moe import route_top_k, routed_experts
 from bigdl_tpu.serving import SamplingParams, ServingEngine
@@ -140,7 +140,7 @@ def test_queries_attend_in_blocks_over_the_span_they_can_see(monkeypatch,
     block over everything."""
     toks = _tokens(1, 1, 41)
     want = _ref_logits(lm.params, toks[0])
-    monkeypatch.setattr(afmoe, "QUERY_BLOCK", 8)
+    monkeypatch.setattr(decoder_ops, "QUERY_BLOCK", 8)
     got, _ = lm.apply(lm.params, toks)
     assert np.abs(np.asarray(got[0]) - want).max() <= F32_OF_STD * want.std()
 

@@ -85,3 +85,21 @@ def test_pooled_decode_attention_lowers_at_the_cells_shapes(n, length, h,
             q, k, v, pos, active=active, interpret=False),
         _sds((n, h, d), jnp.bfloat16), kv, kv, _sds((n,), jnp.int32),
         _sds((n,), bool))
+
+
+@pytest.mark.parametrize("n,length,h,d,v_width", [
+    (32, 16384, 20, 640, 512),    # glm47flash-serve-longctx, as stored
+    (8, 256, 4, 128, 32),
+], ids=["h20_640", "toy"])
+def test_latent_decode_attention_lowers_for_tpu(n, length, h, d, v_width):
+    """A latent cache: ONE stored bf16 leaf of one K/V head under all
+    query heads, its leading ``v_width`` columns the values."""
+    from bigdl_tpu.ops.decode_attention import decode_attention
+
+    text = _lower_for_tpu(
+        lambda q, k, pos, active: decode_attention(
+            q, k, None, pos, active=active, impl="kernel", interpret=False,
+            v_width=v_width),
+        _sds((n, h, d), jnp.bfloat16), _sds((n, length, d), jnp.bfloat16),
+        _sds((n,), jnp.int32), _sds((n,), bool))
+    assert text.count("tpu_custom_call") == 1

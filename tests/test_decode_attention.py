@@ -516,3 +516,104 @@ def test_validation_errors():
                                    k_scale=ks, v_scale=vs)
     with pytest.raises(ValueError, match="unknown impl"):
         decode_attention(q, k, v, pos, impl="magic")
+
+
+# -- a latent cache: the values are the leading columns of the keys ---------
+
+
+def _latent(h, d, pos_value, dtype=jnp.float32, n=4, seed=0):
+    """ONE stored (n, GL, d) cache of one K/V head under h query heads;
+    every row at ``pos_value``; row 1 and the last row do not decode."""
+    rng = np.random.default_rng(seed)
+    q = jnp.asarray(rng.standard_normal((n, h, d)), dtype)
+    k = jnp.asarray(rng.standard_normal((n, GL, d)), dtype)
+    pos = jnp.full((n,), pos_value, jnp.int32)
+    active = np.ones((n,), bool)
+    active[[1, n - 1]] = False
+    return q, k, pos, active
+
+
+def _latent_oracle(q, k, pos, v_width, scale):
+    """Independent dense spelling: the keys are the whole row, the
+    values its leading ``v_width`` columns."""
+    q64, k64 = np.asarray(q, np.float64), np.asarray(k, np.float64)
+    out = np.zeros(q64.shape[:2] + (v_width,))
+    for r in range(q64.shape[0]):
+        w = int(pos[r]) + 1
+        s = q64[r] @ k64[r, :w].T * scale
+        p = np.exp(s - s.max(-1, keepdims=True))
+        out[r] = (p / p.sum(-1, keepdims=True)) @ k64[r, :w, :v_width]
+    return out
+
+
+@pytest.mark.parametrize("active_rows", ["some", "all"])
+@pytest.mark.parametrize("pos_value", [0, 200, 255, 256, GL - 1],
+                         ids=["first", "mid_block", "block_end",
+                              "block_start", "last"])
+@pytest.mark.parametrize("h,d,v_width", [(20, 576, 512), (20, 640, 512),
+                                         (4, 128, 32)])
+def test_latent_kernel_matches_reference(h, d, v_width, pos_value,
+                                         active_rows):
+    """``v_width``: GLM-4.7-Flash's row as published (576 values, the
+    leading 512 the latent), as the pool stores it (640 columns) and a
+    toy; one K/V head under all query heads; rows that decode read the
+    oracle's sum from ONE fetched tile, the others come back as zeros."""
+    q, k, pos, active = _latent(h, d, pos_value)
+    if active_rows == "all":
+        active[:] = True
+    want = _latent_oracle(q, k, pos, v_width, 1 / 16)
+    ref = decode_attention_reference(q, k, None, pos, scale=1 / 16,
+                                     v_width=v_width)
+    fold = folded_decode_attention(q, k, None, pos, scale=1 / 16,
+                                   v_width=v_width)
+    ker = decode_attention(q, k, None, pos, scale=1 / 16, block=GBLOCK,
+                           impl="kernel", interpret=True,
+                           active=jnp.asarray(active), v_width=v_width)
+    assert ker.shape == ref.shape == fold.shape == (4, h, v_width)
+    for got in (ref, fold):
+        np.testing.assert_allclose(np.asarray(got), want, atol=2e-5,
+                                   rtol=2e-5)
+    np.testing.assert_allclose(np.asarray(ker)[active], want[active],
+                               atol=2e-5, rtol=2e-5)
+    assert not np.asarray(ker)[~active].any()
+
+
+def test_latent_kernel_is_the_grouped_kernel_handed_the_leaf_twice():
+    """The single fetch against the least that counts as support: the
+    leaf as keys AND as values through the grouped kernel (G = 1), the
+    context cut to ``v_width``."""
+    q, k, pos, active = _latent(20, 640, 300, dtype=jnp.bfloat16)
+    once = decode_attention(q, k, None, pos, block=GBLOCK, impl="kernel",
+                            interpret=True, active=jnp.asarray(active),
+                            v_width=512)
+    twice = pooled_decode_attention(q, k, k, pos, block=GBLOCK,
+                                    interpret=True,
+                                    active=jnp.asarray(active))[..., :512]
+    np.testing.assert_array_equal(np.asarray(once), np.asarray(twice))
+
+
+def test_auto_impl_slices_the_keys_off_the_tpu():
+    q, k, pos, _ = _latent(4, 128, 200)
+    got = decode_attention(q, k, None, pos, v_width=32)
+    want = folded_decode_attention(q, k, None, pos, v_width=32)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("case", ["a_v_array", "a_view", "too_wide",
+                                  "another_width", "scales"])
+def test_latent_validation_errors(case):
+    q, k, pos, _ = _latent(4, 128, 10)
+    kw = dict(v_width=32)
+    v = None
+    if case == "a_v_array":
+        v = k
+    elif case == "a_view":
+        k = k.reshape(4, GL, 1, 128)
+    elif case == "too_wide":
+        kw = dict(v_width=129)
+    elif case == "another_width":
+        q = q[..., :64]
+    else:
+        kw.update(k_scale=jnp.ones((4, 4)), v_scale=jnp.ones((4, 4)))
+    with pytest.raises(ValueError, match="v_width="):
+        decode_attention_reference(q, k, v, pos, **kw)

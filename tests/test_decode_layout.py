@@ -279,3 +279,57 @@ def test_routed_family_decode_step_reads_rings_and_windows_in_place(
     carry_out = compiled.output_formats[2]
     for key in ("k0", "v0", "k1", "v2"):
         assert carry_in[key].layout == carry_out[key].layout, key
+
+
+def test_latent_family_decode_step_reads_its_one_leaf_in_place(
+        v5e_device, on_the_chip):
+    """The ``glm4_moe_lite`` family's bf16 decode step at the published
+    head sizes (20 heads, a 512-wide latent beside a 64-wide rotary
+    key, heads of 192 + 64 and 256), hidden size and depth cut: the one
+    cache leaf a layer goes through ``latent_decode_attention`` where it
+    is stored (640 columns, row-major), no pool-sized ``copy``, no
+    tensor of per-head keys or values of cached positions, and the
+    carry comes back in the layout it came in."""
+    from jax.sharding import SingleDeviceSharding
+
+    from bigdl_tpu.models.glm_moe_lite import GlmMoeLiteLM
+
+    sh = SingleDeviceSharding(v5e_device)
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh)
+
+    heads = 20
+    config = dict(
+        vocab_size=VOCAB, hidden_size=256, intermediate_size=512,
+        moe_intermediate_size=128, num_hidden_layers=3,
+        first_k_dense_replace=1, num_attention_heads=heads,
+        num_key_value_heads=heads, q_lora_rank=128, kv_lora_rank=512,
+        qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+        n_routed_experts=4, num_experts_per_tok=2, n_shared_experts=1,
+        norm_topk_prob=True, routed_scaling_factor=1.8, rms_norm_eps=1e-5,
+        rope_theta=1000000, expert_share={"index": 1, "of": 4})
+    lm = GlmMoeLiteLM(config, max_len=MAX_LEN, param_dtype="bfloat16")
+    step, init_carry = lm.serving_family().decode_step(jnp.bfloat16)
+    params = jax.tree.map(sds, jax.eval_shape(
+        lm.init_params, jax.random.PRNGKey(0)))
+    carry = jax.tree.map(sds, jax.eval_shape(lambda: init_carry(N_SLOTS)))
+    assert carry["k0"].shape == (N_SLOTS, MAX_LEN, 640) and "v0" not in carry
+    knobs = jax.tree.map(sds, make_knob_rows(N_SLOTS, vocab=VOCAB))
+    compiled = step.lower(params, sds(jnp.zeros((N_SLOTS,), jnp.int32)),
+                          sds(jnp.zeros((N_SLOTS,), bool)), carry,
+                          knobs).compile()
+    text = compiled.as_text()
+    assert text.count("latent_decode_attention") >= 3
+    assert "%pooled_decode_attention" not in text     # no second fetch
+    assert not _pool_sized_copies(text, {math.prod(carry["k0"].shape)})
+    # nothing as large as (rows, L, heads, 256): expanded keys or values
+    expanded = N_SLOTS * MAX_LEN * heads * 256
+    sizes = [math.prod(map(int, m.group(1).split(",")))
+             for m in re.finditer(r"= \w+\[([\d,]+)\]", text)]
+    assert max(sizes) < expanded, max(sizes)
+    carry_in = compiled.input_formats[0][3]
+    carry_out = compiled.output_formats[2]
+    for key in ("k0", "k1", "k2"):
+        assert carry_in[key].layout == carry_out[key].layout, key
+        assert carry_in[key].layout.major_to_minor == (0, 1, 2), key
