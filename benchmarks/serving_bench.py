@@ -92,6 +92,14 @@ decode-program compiles (every knob mix is runtime data of ONE compiled
 sampled step), greedy rows unperturbed by sampled neighbors, and
 reporting the fused epilogue's tokens/sec overhead.
 
+``--scenario sampler`` times :func:`~bigdl_tpu.serving.sampling.
+sample_rows` ALONE (one jitted call, no model) at the serving cells'
+``(n_slots, vocab)`` shapes under three knob mixes: all greedy, half
+``temperature 0.8, top_k 50`` (the cells' traffic), and that mix with
+one nucleus-only row (``top_p 0.9``, no ``top_k``: the row that makes
+the step sort the vocabulary). What the sampling epilogue costs a
+decode step, and what one wide row adds to it.
+
 The mixed-arrival question decode_bench.py leaves open: decode_bench
 measures a FIXED batch decoded in lockstep, but production traffic is
 independent requests arriving at staggered times with different
@@ -501,6 +509,82 @@ def run_sampling(model: str = "tiny", variant: str = "fp32",
                      / max(mixed_stats["tokens_per_sec"], 1e-9) - 1.0),
             1),
     }
+
+
+#: ``(n_slots, vocab)`` of the benchmark's serving cells (gpt2m-serve-chat,
+#: glm47flash-serve-longctx, trinity-serve-mixed, falconh1-serve-reason)
+SAMPLER_SHAPES = ((32, 50257), (32, 19360), (16, 25024), (32, 32640))
+
+
+def _device_ms(fn, args, reps: int):
+    """Mean device time of one call of the jitted ``fn``, from a
+    profiler trace of ``reps`` calls; None where the trace holds no
+    device plane (the CPU)."""
+    import tempfile
+
+    import jax
+
+    from benchmark import trace_reduce
+
+    with tempfile.TemporaryDirectory() as trace_dir:
+        with jax.profiler.trace(trace_dir):
+            for _ in range(reps):
+                jax.block_until_ready(fn(*args))
+        reduced = trace_reduce.reduce_dir(trace_dir)
+    if reduced is None:
+        return None
+    (program,) = reduced["programs"].values()
+    return program["mean_ms"]
+
+
+def run_sampler(shapes=SAMPLER_SHAPES, reps: int = 10, sample_rows=None,
+                seed: int = 13) -> dict:
+    """Milliseconds of one jitted ``sample_rows`` call by shape and knob
+    mix, every row active, after three warm calls: ``device_ms`` from a
+    profiler trace of ``reps`` calls (on the chip a call's dispatch, 0.2
+    ms, is longer than the program), ``wall_ms`` the median of ``reps``
+    blocked calls. ``sample_rows`` defaults to the tree's; a caller
+    comparing two trees hands in the other's."""
+    import jax
+    import jax.numpy as jnp
+
+    from bigdl_tpu.serving import sampling
+
+    fn = jax.jit(sample_rows or sampling.sample_rows)
+    rng = np.random.RandomState(seed)
+    device_ms, wall_ms = {}, {}
+    for n, v in shapes:
+        logp = jax.nn.log_softmax(
+            jnp.asarray(rng.randn(n, v).astype(np.float32) * 3.0), axis=-1)
+        keys = jax.vmap(jax.random.PRNGKey)(jnp.arange(n))
+        counts = jnp.zeros((n, v), jnp.int32)
+        pmask = jnp.zeros((n, v), bool)
+        active = jnp.ones((n,), bool)
+        for mix in ("greedy", "half_top_k", "one_nucleus"):
+            knobs = sampling.make_knob_rows(n, vocab=v)
+            if mix != "greedy":
+                knobs["temperature"][::2] = 0.8
+                knobs["top_k"][::2] = 50
+            if mix == "one_nucleus":
+                knobs["temperature"][1] = 0.8
+                knobs["top_p"][1] = 0.9
+            knobs = {k: jnp.asarray(a) for k, a in knobs.items()}
+            args = (logp, keys, knobs, counts, pmask, active)
+            for _ in range(3):
+                jax.block_until_ready(fn(*args))
+            times = []
+            for _ in range(reps):
+                t0 = time.perf_counter()
+                jax.block_until_ready(fn(*args))
+                times.append(time.perf_counter() - t0)
+            wall_ms[f"{n}x{v}/{mix}"] = round(1e3 * float(np.median(times)), 4)
+            ms = _device_ms(fn, args, reps)
+            if ms is not None:
+                device_ms[f"{n}x{v}/{mix}"] = round(ms, 4)
+    dev = jax.devices()[0]
+    return {"metric": "sample_rows_ms", "device": dev.device_kind,
+            "platform": dev.platform, "reps": reps,
+            "device_ms": device_ms, "wall_ms": wall_ms}
 
 
 def make_spec_trace(cfg, n_requests: int, gen_tokens: int, seed: int = 23):
@@ -2071,7 +2155,7 @@ def main() -> None:
                     choices=["mixed", "admission", "sampling", "sharded",
                              "kv_quant", "speculative", "slo", "chunked",
                              "disagg", "failover", "multitenant",
-                             "tiered", "autopilot", "async"])
+                             "tiered", "autopilot", "async", "sampler"])
     ap.add_argument("--model", default="tiny", choices=sorted(MODELS))
     ap.add_argument("--variant", default="fp32", choices=["fp32", "bf16"])
     # requests/gen_tokens/slots default per scenario: mixed 12/48/12,
@@ -2116,6 +2200,9 @@ def main() -> None:
                          "it — virtual time, no wall clock)")
     ap.add_argument("--tick_ms", type=float, default=2.0,
                     help="autopilot: SteppingClock tick per clock read")
+    ap.add_argument("--shapes", default=None,
+                    help="sampler: comma-separated <rows>x<vocab> in "
+                         "place of the serving cells' four shapes")
     args = ap.parse_args()
 
     from bigdl_tpu.utils.compile_cache import enable_compile_cache
@@ -2205,6 +2292,12 @@ def main() -> None:
             n_requests=args.requests or 16,
             gen_tokens=args.gen_tokens or 32,
             n_slots=args.slots or 8)))
+        return
+    if args.scenario == "sampler":
+        shapes = [tuple(int(d) for d in sh.split("x"))
+                  for sh in args.shapes.split(",")] if args.shapes \
+            else SAMPLER_SHAPES
+        print(json.dumps(run_sampler(shapes)))
         return
     if args.scenario == "admission":
         print(json.dumps(run_admission(

@@ -233,7 +233,7 @@ class DecoderServing:
                 final_logits(cfg, params, x[:, 0]), axis=-1)
             tok, chosen, new_keys, new_counts = sample_rows(
                 logp, carry["rng"], knobs, carry["tok_counts"],
-                carry["prompt_mask"])
+                carry["prompt_mask"], active)
             new_carry = dict(
                 carry, **leaves, pos=pos + active.astype(jnp.int32),
                 rng=jnp.where(active[:, None], new_keys, carry["rng"]),

@@ -565,7 +565,7 @@ class FalconH1Serving:
             new_carry["pos"] = pos + active.astype(jnp.int32)
             tok, chosen, new_keys, new_counts = sample_rows(
                 logp, carry["rng"], knobs, carry["tok_counts"],
-                carry["prompt_mask"])
+                carry["prompt_mask"], active)
             new_carry["rng"] = jnp.where(active[:, None], new_keys,
                                          carry["rng"])
             new_carry["tok_counts"] = jnp.where(

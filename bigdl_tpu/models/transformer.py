@@ -1656,7 +1656,7 @@ def make_batch_decode_step(model: Sequential, compute_dtype=None,
                                adapter_ids, bank)
         tok, chosen, new_keys, new_counts = sample_rows(
             logp, carry["rng"], knobs, carry["tok_counts"],
-            carry["prompt_mask"])
+            carry["prompt_mask"], active)
         # inactive rows: rng/counts bitwise untouched, same contract as
         # the K/V scatter
         new_carry["rng"] = jnp.where(active[:, None], new_keys,
@@ -1816,9 +1816,10 @@ def make_batch_verify_step(model: Sequential, compute_dtype=None,
         keys, counts = carry["rng"], carry["tok_counts"]
         pmask = carry["prompt_mask"]
         toks_out, lps_out, key_hist = [], [], []
+        active = lengths > 0
         for j in range(S):
             t_j, lp_j, keys, counts = sample_rows(
-                logp[:, j], keys, knobs, counts, pmask)
+                logp[:, j], keys, knobs, counts, pmask, active)
             toks_out.append(t_j)
             lps_out.append(lp_j)
             key_hist.append(keys)
@@ -1836,7 +1837,6 @@ def make_batch_verify_step(model: Sequential, compute_dtype=None,
             n_acc = jnp.sum(acc, axis=1)
         else:
             n_acc = jnp.zeros((N,), jnp.int32)
-        active = lengths > 0
         n_emit = jnp.where(active, n_acc + 1, 0).astype(jnp.int32)
         if kv_quant:
             # the DEFERRED accepted-only int8 commit: amax over emitted

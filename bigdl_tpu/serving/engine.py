@@ -83,7 +83,7 @@ from bigdl_tpu.serving.kv_pool import KVPool
 from bigdl_tpu.serving.metrics import ServingMetrics
 from bigdl_tpu.serving.sampling import (
     SamplingParams, advance_lane, knob_row_values, make_knob_rows,
-    match_stop_sequences,
+    match_stop_sequences, wide_rows,
 )
 from bigdl_tpu.serving.scheduler import (
     FINISHED, SHED, WAITING, Request, Scheduler,
@@ -1463,6 +1463,15 @@ class ServingEngine:
                           if req.logprobs else None),
             met_deadline=met)
 
+    def _sampler_wide(self, slots) -> bool:
+        """Whether a decode step of these slots sorts the vocabulary:
+        the device's own rule (``sampling.wide_rows``) over the knob
+        rows the step reads, for ``serving/sampler_wide``."""
+        k = self._knobs
+        _, wide = wide_rows(k["temperature"][slots], k["top_k"][slots],
+                            k["top_p"][slots])
+        return bool(wide.any())
+
     def _maybe_flip_ban(self, slot: int, req: Request) -> None:
         """min-tokens ban lifts the step the floor is met — a runtime
         VALUE change, never a recompile."""
@@ -1739,8 +1748,9 @@ class ServingEngine:
                     kv_fetched_bytes=self._kv_fetched_bytes())
                 if extra:
                     self.metrics.on_expert_counts(extra[0])
-                self.metrics.on_sample_rows(n_sampled,
-                                            len(rows) - n_sampled)
+                self.metrics.on_sample_rows(
+                    n_sampled, len(rows) - n_sampled,
+                    self._sampler_wide(list(rows)))
             for slot, req in list(rows.items()):
                 tok0 = int(nxt[slot])
                 reason = self._account_token(slot, req, tok0,

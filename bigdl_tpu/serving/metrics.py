@@ -122,6 +122,11 @@ Sampling counters (``serving/sampling.py``):
 * ``rows_sampled`` / ``rows_greedy`` — active rows per decode step that
   drew from a sampled distribution (temperature > 0) vs took argmax;
   ``summary()`` derives ``sampled_row_frac``
+* ``sampler_wide``        — 1.0 for a decode step in which a running
+  row is wide by ``sampling.wide_rows`` (a nucleus with no ``top_k``,
+  or a ``top_k`` over ``sampling.K_CAP``), so that the step's sampler
+  sorted the whole vocabulary, else 0.0; the mean in ``summary()`` is
+  the share of decode steps that sorted
 * ``mean_logprob``        — per-request mean chosen-token raw model
   log-prob (recorded at finish; a cheap generation-quality signal)
 
@@ -434,11 +439,14 @@ class ServingMetrics:
         host behind (``serving/decode_chained``)."""
         self.metrics.add("serving/decode_chained", float(chained))
 
-    def on_sample_rows(self, n_sampled: int, n_greedy: int) -> None:
+    def on_sample_rows(self, n_sampled: int, n_greedy: int,
+                       wide: bool) -> None:
         """Per decode step: how many active rows drew from a sampled
-        distribution (temperature > 0) vs took the argmax."""
+        distribution (temperature > 0) vs took the argmax, and whether
+        one of them made the step sort the whole vocabulary."""
         self.metrics.add("serving/rows_sampled", float(n_sampled))
         self.metrics.add("serving/rows_greedy", float(n_greedy))
+        self.metrics.add("serving/sampler_wide", float(wide))
 
     def on_spec_step(self, n_drafted: int, n_accepted: int,
                      n_rows: int) -> None:

@@ -62,7 +62,19 @@ Knob semantics (order of application, matching common practice):
    the key — trace the exact pre-constraint program);
 5. temperature scaling, then top-k (ties at the k-th value are kept,
    like ``lax.top_k``-threshold filtering), then top-p over the
-   top-k-filtered distribution (the top token is always kept);
+   top-k-filtered distribution (the top token is always kept;
+   ``top_p == 1`` is no restriction). Both reduce to ONE threshold
+   value a row, and the selection that finds it is as wide as the
+   row's knobs need (:func:`wide_rows`): a row with ``0 < top_k <=
+   K_CAP`` takes it from its ``K_CAP`` largest values
+   (:func:`largest_values`), and the ``(N, V)`` sort runs only in
+   a step where a DECODING row asks a nucleus with no ``top_k`` or a
+   ``top_k`` over the cap. With ``top_p == 1`` the threshold is the
+   same float as the sort's k-th value, so the tokens are bit-equal
+   to the whole sort's; with ``top_p < 1`` on a capped row the
+   softmax's normaliser is summed over the unsorted row and not the
+   sorted one, so a token can differ only where a cumulative mass
+   lies within rounding of ``top_p``;
 6. the draw: Gumbel argmax via ``jax.random.categorical`` for
    ``temperature > 0`` rows, plain argmax for ``temperature == 0``.
 
@@ -73,6 +85,7 @@ so greedy and sampled rows report comparable numbers.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Optional, Sequence, Tuple
 
@@ -80,6 +93,27 @@ from typing import Optional, Sequence, Tuple
 #: subject to the min-tokens ban). Fixed so the compiled step's shape
 #: never depends on a request's stop-set size.
 MAX_BAN_IDS = 8
+
+#: The widest ``top_k`` whose cut :func:`sample_rows` takes from the
+#: row's largest values without sorting the vocabulary. Fixed, so that
+#: the one compiled program is the same whatever the rows ask; 50 is the
+#: common default of serving front ends, and a request over the cap is
+#: served by the whole sort.
+K_CAP = 128
+
+
+def wide_rows(temperature, top_k, top_p):
+    """THE rule for which rows need the whole-vocabulary sort, from the
+    knobs alone: ``(filters, wide)`` bool arrays. A row ``filters``
+    when it samples under a ``top_k`` or a nucleus; it is ``wide`` when
+    it filters and its ``top_k`` does not bound the selection by
+    :data:`K_CAP` (a nucleus with no ``top_k``, or a ``top_k`` over
+    the cap). Operators only, so ``numpy`` rows (the engine's host
+    counter ``serving/sampler_wide``) and traced ``jax.numpy`` rows
+    (:func:`sample_rows`) evaluate the same expression."""
+    filters = (temperature > 0) & ((top_k > 0) | (top_p < 1))
+    narrow = (top_k > 0) & (top_k <= K_CAP)
+    return filters, filters & ~narrow
 
 
 def _as_tuple_of_ints(x) -> Tuple[int, ...]:
@@ -281,7 +315,97 @@ def knob_row_values(params: "SamplingParams", eos_id: int):
     }, ban_row
 
 
-def sample_rows(logp, keys, knobs, counts, prompt_mask):
+def largest_values(x, k: int):
+    """``(N, k)``: each row's ``k`` largest values, descending: the
+    values of ``lax.top_k(x, k)`` exactly, found in two stages. (On the
+    chip ``top_k`` of a whole row is no shortcut: alone it costs in
+    proportion to ``k x V``, 0.76 ms for 128 of 32 x 50,257 against the
+    sort's 1.88, and inside a decode program XLA compiles it to the
+    whole sort; PERF.md section 6, PR 35.) The row is folded into
+    ``fold`` rows over ``ceil(V / fold)`` columns. Every one of the
+    ``k`` largest lies in a column whose maximum ranks in the first
+    ``k``: were it in another, ``k`` column maxima, so ``k`` other
+    elements, would be at least as large, and it would tie the k-th
+    value. So the ``k`` largest of those columns' ``fold x k`` elements
+    are the row's. Ties cannot matter: only values are returned. The
+    two selections are over ``V / fold`` and ``fold x k`` elements,
+    least together near ``fold = sqrt(V / k)``."""
+    import jax
+    import jax.numpy as jnp
+
+    n, v = x.shape
+    fold = 2 ** round(math.log2(v / k) / 2) if v > k else 1
+    cols = -(-v // fold)
+    if cols <= k:
+        return jax.lax.top_k(x, k)[0]
+    folded = jnp.pad(x, ((0, 0), (0, fold * cols - v)),
+                     constant_values=-jnp.inf).reshape(n, fold, cols)
+    _, held = jax.lax.top_k(folded.max(axis=1), k)        # (N, k) columns
+    cand = jnp.take_along_axis(folded, held[:, None, :], axis=2)
+    return jax.lax.top_k(cand.reshape(n, fold * k), k)[0]
+
+
+def row_thresholds(ls, temperature, top_k, top_p, active=None):
+    """``(N, 1)``: the value below which top-k and top-p mask a row of
+    the temperature-scaled ``(N, V)`` log-probs ``ls`` (``-inf`` for a
+    row that does not filter). The selection is as wide as the row's
+    knobs need, by :func:`wide_rows`:
+
+    * a narrow row finds it among its ``K_CAP`` largest values
+      (:func:`largest_values`: the sorted row's head). The k-th of them
+      is the sort's k-th value itself, so with ``top_p == 1`` the
+      threshold is the same float as the whole sort's. Its nucleus runs
+      inside those candidates, normalised by a sum over the UNSORTED row
+      (exact also when ties at the k-th value run past the cap: they
+      carry that value, and the mask is by value), so with ``top_p < 1``
+      only the order of that sum differs from the sort's, and the
+      threshold can differ only where a cumulative mass lies within
+      rounding of ``top_p``;
+    * the sort, its softmax and its cumulative sum over ``(N, V)`` run
+      under a ``lax.cond``, in a step where a row of ``active`` (the
+      rows that decode; ``None``: all) is wide, and only wide rows take
+      their threshold from it: a row's arithmetic follows its own
+      knobs, never its neighbours'.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    N, V = ls.shape
+    filters, wide = wide_rows(temperature, top_k, top_p)
+    top_p = top_p[:, None]
+    cap = min(K_CAP, V)
+    vals = largest_values(ls, cap)                # (N, cap) descending
+    kth = jnp.take_along_axis(
+        vals, (jnp.clip(top_k, 1, cap) - 1)[:, None], axis=-1)    # (N,1)
+    in_k = vals >= kth
+    z = jnp.sum(jnp.where(ls >= kth, jnp.exp(ls - vals[:, :1]), 0.0),
+                axis=-1, keepdims=True)           # one pass, no sort
+    ps = jnp.where(in_k, jnp.exp(vals - vals[:, :1]), 0.0) / z
+    # keep a token while the mass BEFORE it is < top_p (top token always
+    # kept: its "mass before" is 0)
+    keep = in_k & ((jnp.cumsum(ps, axis=-1) - ps) < top_p)
+    cut = jnp.min(jnp.where(keep, vals, jnp.inf), axis=-1, keepdims=True)
+    thr = jnp.where(top_p < 1.0, jnp.maximum(kth, cut), kth)
+    thr = jnp.where(filters[:, None], thr, -jnp.inf)
+
+    def sorted_thresholds(ls):
+        sl = -jnp.sort(-ls, axis=-1)              # (N, V) descending
+        k_eff = jnp.clip(jnp.where(top_k > 0, top_k, V), 1, V)
+        kth = jnp.take_along_axis(sl, (k_eff - 1)[:, None], axis=-1)
+        slm = jnp.where(sl < kth, -1e30, sl)      # top-k in sorted space
+        ps = jax.nn.softmax(slm, axis=-1)
+        keep = (jnp.cumsum(ps, axis=-1) - ps) < top_p
+        cut = jnp.min(jnp.where(keep, slm, jnp.inf), axis=-1)[:, None]
+        return jnp.where(top_p < 1.0, jnp.maximum(kth, cut), kth)
+
+    decoding = wide if active is None else wide & active
+    thr_wide = jax.lax.cond(
+        jnp.any(decoding), sorted_thresholds,
+        lambda ls: jnp.full((N, 1), -jnp.inf, ls.dtype), ls)
+    return jnp.where(wide[:, None], thr_wide, thr)
+
+
+def sample_rows(logp, keys, knobs, counts, prompt_mask, active=None):
     """The per-row sampler (pure; traced inside the decode step and
     jitted standalone by :func:`get_sampler`).
 
@@ -292,13 +416,22 @@ def sample_rows(logp, keys, knobs, counts, prompt_mask):
       constraint mask when the caller runs constrained decoding);
     * ``counts`` — ``(N, V)`` int32 per-row generated-token counts;
     * ``prompt_mask`` — ``(N, V)`` bool, tokens present in the row's
-      prompt (repetition penalty covers prompt + output).
+      prompt (repetition penalty covers prompt + output);
+    * ``active`` — ``(N,)`` bool, the rows that decode this step
+      (``None``: all). It decides only WHETHER the step sorts: a freed
+      slot keeps its last occupant's knobs, and must not ask for it.
 
     Returns ``(tokens (N,) int32, chosen_logp (N,) fp32, new_keys,
     new_counts)`` — ``new_counts`` includes the sampled token; the
     CALLER masks inactive rows (the step's ``active`` convention).
     Every row's draw reads only its own key/knobs/state, so per-row
     results are independent of batch composition.
+
+    Top-k and top-p come to one threshold a row
+    (:func:`row_thresholds`): with ``top_p == 1`` the result is
+    bit-equal to a whole sort's, with ``top_p < 1`` on a row whose
+    ``top_k`` is at most :data:`K_CAP` a token can differ from it only
+    where a cumulative mass lies within rounding of ``top_p``.
     """
     import jax
     import jax.numpy as jnp
@@ -322,21 +455,11 @@ def sample_rows(logp, keys, knobs, counts, prompt_mask):
     if allow is not None:
         l = jnp.where(allow, l, -1e30)
     greedy_tok = jnp.argmax(l, axis=-1)
-    # 5: temperature, then top-k / top-p in one descending sort
+    # 5: temperature, then top-k / top-p as one threshold a row
     temp = knobs["temperature"]
     ls = l / jnp.maximum(temp, 1e-6)[:, None]
-    sl = -jnp.sort(-ls, axis=-1)                  # (N, V) descending
-    k_eff = jnp.clip(jnp.where(knobs["top_k"] > 0, knobs["top_k"], V),
-                     1, V)
-    kth = jnp.take_along_axis(sl, (k_eff - 1)[:, None], axis=-1)  # (N,1)
-    slm = jnp.where(sl < kth, -1e30, sl)          # top-k in sorted space
-    ps = jax.nn.softmax(slm, axis=-1)
-    cum = jnp.cumsum(ps, axis=-1)
-    # keep a token while the mass BEFORE it is < top_p (top token always
-    # kept: its "mass before" is 0)
-    keep = (cum - ps) < knobs["top_p"][:, None]
-    cut = jnp.min(jnp.where(keep, slm, jnp.inf), axis=-1)[:, None]
-    ls = jnp.where((ls < kth) | (ls < cut), -1e30, ls)
+    thr = row_thresholds(ls, temp, knobs["top_k"], knobs["top_p"], active)
+    ls = jnp.where(ls < thr, -1e30, ls)
     # 6: the draw — per-row key split + Gumbel argmax; vmap keeps each
     # row's stream a pure function of its own key (bitwise equal to a
     # B=1 call with that key, which is the reproducibility contract)

@@ -414,7 +414,8 @@ class Speculator:
         eng.metrics.on_step(eng.scheduler.queue_depth,
                             eng.pool.occupancy(), int(active.sum()),
                             kv_used_share=eng._kv_used_share())
-        eng.metrics.on_sample_rows(n_sampled, len(running) - n_sampled)
+        eng.metrics.on_sample_rows(n_sampled, len(running) - n_sampled,
+                                   eng._sampler_wide(active))
 
         # emission: the baseline per-token accounting, applied to each
         # chunk token IN ORDER and truncated at the first stop — a stop

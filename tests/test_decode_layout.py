@@ -107,8 +107,8 @@ def test_data_mesh_decode_step_runs_the_kernel_on_each_chips_rows(
     """A data-only plane (four chips, the slots sharded over them, the
     program a plain jit that XLA partitions): the decode kernel, which
     XLA cannot partition, runs per chip over the rows the chip holds
-    (``_token_view``'s ``shard_map`` by rows); nothing is gathered or
-    reduced across chips."""
+    (``_token_view``'s ``shard_map`` by rows); nothing of the pool or
+    the rows is gathered or reduced across chips."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -139,9 +139,14 @@ def test_data_mesh_decode_step_runs_the_kernel_on_each_chips_rows(
         knobs).compile().as_text()
     # each chip's kernel sees its own two of the eight rows
     assert re.search(r"pooled_decode_attention\S* = bf16\[2,", text)
-    for collective in ("all-gather", "all-reduce", "all-to-all",
-                       "collective-permute"):
+    for collective in ("all-gather", "all-to-all", "collective-permute"):
         assert f" {collective}(" not in text, collective
+    # ONE scalar crosses the chips: whether a decoding row anywhere
+    # makes this step's sampler sort the vocabulary (sampling.py's
+    # ``any`` over rows that XLA partitions)
+    reduced = [line for line in text.splitlines() if " all-reduce(" in line]
+    assert len(reduced) == 1 and "/reduce_or" in reduced[0], reduced
+    assert re.search(r"= \w+\[\]\S* all-reduce\(", reduced[0]), reduced[0]
 
 
 @pytest.mark.parametrize("shape", [(4, 1), (2, 2)], ids=["4x1", "2x2"])

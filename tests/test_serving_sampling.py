@@ -8,6 +8,8 @@ metrics + bench smoke."""
 import numpy as np
 import pytest
 
+from bigdl_tpu.serving.sampling import K_CAP
+
 
 def _make_lm(V=29, hidden=32, heads=4, layers=2, max_len=48, seed=9):
     from bigdl_tpu.models.transformer import TransformerLM
@@ -268,6 +270,261 @@ def test_sampling_metrics_counters(lm):
     assert np.isfinite(s["serving/mean_logprob"])
     _, n_fin = eng.metrics.metrics.get("serving/mean_logprob")
     assert n_fin == 2                          # one per finished request
+    # plain temperature sampling filters nothing, and a top_k under the
+    # cap is narrow: no step of this traffic sorted the vocabulary
+    assert eng.metrics.metrics.get("serving/sampler_wide") == (0.0, 4)
+    eng.submit([3, 7], max_new_tokens=4, sampling=SamplingParams(
+        temperature=0.8, top_k=20, seed=5))
+    eng.drain()
+    assert eng.metrics.metrics.get("serving/sampler_wide") == (0.0, 8)
+    eng.submit([5, 1], max_new_tokens=2, sampling=SamplingParams(
+        temperature=1.0, top_p=0.9, seed=3))     # a nucleus-only row
+    eng.drain()
+    assert eng.metrics.metrics.get("serving/sampler_wide") == (2.0, 10)
+
+
+# -- the selection behind top-k / top-p (PR 35) -----------------------------
+
+def _sorted_reference(ls, top_k, top_p):
+    """The plain reference: the whole-vocabulary sort that
+    ``sample_rows`` ran for every row until PR 35, kept here as it was
+    but for its last line: ``top_p == 1`` is no restriction. (The
+    float32 cumulative sum can reach 1.0 before the row's end, and the
+    sort then dropped a tail of ~1e-7 of the mass from such a row.)
+    Returns the kept mask of the scaled log-probs ``ls``."""
+    import jax
+    import jax.numpy as jnp
+
+    V = ls.shape[1]
+    sl = -jnp.sort(-ls, axis=-1)
+    k_eff = jnp.clip(jnp.where(top_k > 0, top_k, V), 1, V)
+    kth = jnp.take_along_axis(sl, (k_eff - 1)[:, None], axis=-1)
+    slm = jnp.where(sl < kth, -1e30, sl)
+    ps = jax.nn.softmax(slm, axis=-1)
+    cum = jnp.cumsum(ps, axis=-1)
+    keep = (cum - ps) < top_p[:, None]
+    cut = jnp.min(jnp.where(keep, slm, jnp.inf), axis=-1)[:, None]
+    return ~((ls < kth) | ((ls < cut) & (top_p[:, None] < 1.0)))
+
+
+def _reference_draw(ls, temp, kept, keys):
+    """The draw of ``sample_rows`` over a given kept mask."""
+    import jax
+    import jax.numpy as jnp
+
+    split = jax.vmap(jax.random.split)(keys)
+    sampled = jax.vmap(jax.random.categorical)(
+        split[:, 1], jnp.where(kept, ls, -1e30))
+    return jnp.where(temp > 0.0, sampled, jnp.argmax(ls, axis=-1)), \
+        split[:, 0]
+
+
+def _tied_logp(shape, seed, k):
+    """Random log-probs with exact ties planted where a selection could
+    go wrong: row 0 at its k-th largest value (ranks k-1 .. k+2), row 1
+    across the cap's edge (ranks K_CAP-3 .. K_CAP+5), row 2 at both."""
+    import jax
+
+    x = np.random.RandomState(seed).randn(*shape).astype(np.float32) * 2
+    order = np.argsort(-x, axis=-1)
+
+    def tie(row, lo, hi):
+        x[row, order[row, lo:hi]] = x[row, order[row, lo]]
+
+    if k:
+        tie(0, k - 1, k + 3)
+        tie(2, k - 1, k + 3)
+    tie(1, K_CAP - 3, K_CAP + 6)
+    tie(2, K_CAP - 3, K_CAP + 6)
+    # NOT renormalised: a log_softmax would round the planted ties apart
+    return jax.numpy.asarray(x)
+
+
+def _knob_arrays(n, vocab=None, **rows):
+    import jax.numpy as jnp
+
+    from bigdl_tpu.serving.sampling import make_knob_rows
+
+    knobs = make_knob_rows(n, vocab=vocab)
+    for name, vals in rows.items():
+        knobs[name][:] = vals
+    return {k: jnp.asarray(v) for k, v in knobs.items()}
+
+
+@pytest.mark.parametrize("shape,top_k,top_p", [
+    (shape, top_k, top_p)
+    for shape in [(4, 1000), (3, 50257)]
+    for top_k in [1, 50, K_CAP, K_CAP + 1, 0]
+    for top_p in [1.0, 0.9, 0.3]
+] + [((32, 50257), 50, 1.0)])      # a serving cell's shape and knobs
+def test_selection_keeps_what_the_whole_sort_keeps(shape, top_k, top_p):
+    """For every sampled row the kept set equals the plain reference's,
+    so the token and the returned key are bit-equal to its draw. The
+    last row is greedy (the argmax whatever the selection says)."""
+    import jax
+    import jax.numpy as jnp
+
+    from bigdl_tpu.serving.sampling import (
+        get_sampler, row_thresholds, wide_rows,
+    )
+
+    k, n = top_k, shape[0]
+    logp = _tied_logp(shape, seed=100 * k + n, k=k)
+    temp = np.full((n,), 0.8, np.float32)
+    temp[-1] = 0.0
+    knobs = _knob_arrays(n, temperature=temp, top_k=k, top_p=top_p)
+    t, tk, tp = knobs["temperature"], knobs["top_k"], knobs["top_p"]
+    ls = logp / jnp.maximum(t, 1e-6)[:, None]
+    kept_ref = np.asarray(_sorted_reference(ls, tk, tp))
+    thr = jax.jit(row_thresholds)(ls, t, tk, tp)
+    kept = np.asarray(ls >= thr)
+    sampled = np.asarray(t) > 0
+    _, wide = wide_rows(np.asarray(t), np.asarray(tk), np.asarray(tp))
+    assert wide[:-1].all() == (k == 0 and top_p < 1 or k > K_CAP)
+    assert np.array_equal(kept[sampled], kept_ref[sampled])
+    if k and top_p == 1:
+        # the planted ties are in play: row 0 keeps its 3 ties past k
+        assert kept_ref[0].sum() == k + 3
+    keys = jax.vmap(jax.random.PRNGKey)(jnp.arange(n) + 5)
+    zeros = jnp.zeros(shape, jnp.int32)
+    tok, lp, new_keys, _ = get_sampler()(
+        logp, keys, knobs, zeros, zeros.astype(bool))
+    ref_tok, ref_keys = _reference_draw(ls, t, jnp.asarray(kept_ref), keys)
+    assert np.array_equal(np.asarray(new_keys), np.asarray(ref_keys))
+    assert np.array_equal(np.asarray(tok), np.asarray(ref_tok))
+    assert np.array_equal(np.asarray(lp),
+                          np.asarray(logp)[np.arange(n), np.asarray(tok)])
+    assert int(tok[-1]) == int(np.argmax(np.asarray(logp)[-1]))
+
+
+_KNOB_TABLE = [
+    # temperature, top_k, top_p -> filters, wide
+    (0.0, 0, 1.0, False, False),        # greedy
+    (0.0, 50, 0.9, False, False),       # greedy whatever else it says
+    (0.8, 0, 1.0, False, False),        # plain temperature sampling
+    (0.8, 50, 1.0, True, False),        # the cells' sampled rows
+    (0.8, 50, 0.9, True, False),
+    (1.3, K_CAP, 0.5, True, False),     # at the cap: still narrow
+    (1.3, K_CAP + 1, 1.0, True, True),  # over the cap
+    (0.8, 0, 0.9, True, True),          # a nucleus with no top_k
+]
+
+
+@pytest.mark.parametrize("row", range(len(_KNOB_TABLE)))
+def test_wide_rule_same_under_numpy_and_jax(row):
+    """The ONE rule: the engine's host counter (numpy) and the traced
+    sampler (jax.numpy) evaluate the same expression to the same
+    answer."""
+    import jax.numpy as jnp
+
+    from bigdl_tpu.serving.sampling import wide_rows
+
+    temp, k, p, filters, wide = _KNOB_TABLE[row]
+    for xp in (np, jnp):
+        f, w = wide_rows(xp.asarray([temp], xp.float32),
+                         xp.asarray([k], xp.int32),
+                         xp.asarray([p], xp.float32))
+        assert (bool(f[0]), bool(w[0])) == (filters, wide), xp.__name__
+
+
+@pytest.mark.parametrize("neighbour", ["alone", "wide", "stale_wide_slot"])
+def test_narrow_row_stream_independent_of_neighbours(neighbour):
+    """A narrow row draws the same stream alone, beside a nucleus-only
+    row (the step sorts, the row does not read the sort), and beside an
+    INACTIVE slot whose stale knobs are wide (the step must not sort
+    for it: the rule reads the rows that decode)."""
+    import jax
+    import jax.numpy as jnp
+
+    from bigdl_tpu.serving.sampling import get_sampler, lane_key, wide_rows
+
+    v, steps = 1000, 6
+    rng = np.random.RandomState(3)
+    logps = jnp.asarray(rng.randn(steps, 1, v).astype(np.float32) * 2)
+    other = jnp.asarray(rng.randn(steps, 1, v).astype(np.float32) * 2)
+
+    def stream(n, knobs, active):
+        keys = jnp.stack([lane_key(7)] * n)
+        counts = jnp.zeros((n, v), jnp.int32)
+        pmask = jnp.zeros((n, v), bool)
+        out = []
+        for i in range(steps):
+            logp = jnp.concatenate([logps[i]] + [other[i]] * (n - 1))
+            tok, _, keys, counts = get_sampler()(
+                logp, keys, knobs, counts, pmask, active)
+            out.append(int(tok[0]))
+        return out
+
+    narrow = dict(temperature=0.8, top_k=50, top_p=0.9)
+    alone = stream(1, _knob_arrays(1, **narrow), None)
+    if neighbour == "alone":
+        assert len(set(alone)) > 1
+        return
+    knobs = _knob_arrays(2, temperature=[0.8, 0.8], top_k=[50, 0],
+                         top_p=[0.9, 0.9])
+    active = jnp.asarray([True, neighbour == "wide"])
+    _, wide = wide_rows(*(np.asarray(knobs[k]) for k in
+                          ("temperature", "top_k", "top_p")))
+    assert list(wide) == [False, True]
+    assert bool((wide & np.asarray(active)).any()) == (neighbour == "wide")
+    assert stream(2, knobs, active) == alone
+
+
+@pytest.mark.parametrize("top_p", [1.0, 0.5])
+def test_constraint_leaving_fewer_than_top_k(top_p):
+    """A constrained row whose ``allow`` leaves 3 tokens under
+    ``top_k=50``: the k-th value is a disallowed token's, nothing
+    allowed is lost, and the draw is the reference's."""
+    import jax
+    import jax.numpy as jnp
+
+    from bigdl_tpu.serving.sampling import get_sampler
+
+    n, v = 2, 1000
+    logp = jax.nn.log_softmax(jnp.asarray(
+        np.random.RandomState(11).randn(n, v).astype(np.float32)), -1)
+    knobs = _knob_arrays(n, vocab=v, temperature=0.8, top_k=50,
+                         top_p=top_p)
+    allowed = np.zeros((n, v), bool)
+    allowed[0, [5, 17, 400]] = True
+    allowed[1] = True
+    knobs["allow"] = jnp.asarray(allowed)
+    zeros = jnp.zeros((n, v), jnp.int32)
+    t = knobs["temperature"]
+    ls = jnp.where(knobs["allow"], logp, -1e30) / t[:, None]
+    kept_ref = _sorted_reference(ls, knobs["top_k"], knobs["top_p"])
+    seen = set()
+    for seed in range(8):
+        keys = jax.vmap(jax.random.PRNGKey)(jnp.arange(n) + 10 * seed)
+        tok, _, new_keys, _ = get_sampler()(
+            logp, keys, knobs, zeros, zeros.astype(bool))
+        ref_tok, ref_keys = _reference_draw(ls, t, kept_ref, keys)
+        assert np.array_equal(np.asarray(tok), np.asarray(ref_tok))
+        assert np.array_equal(np.asarray(new_keys), np.asarray(ref_keys))
+        seen.add(int(tok[0]))
+    assert seen <= {5, 17, 400}
+    assert top_p < 1 or len(seen) > 1
+
+
+def test_freed_slot_with_stale_wide_knobs_is_not_counted(lm):
+    """The engine writes a slot's knob row at admission only, so a
+    finished nucleus-only request leaves its wide knobs behind:
+    ``serving/sampler_wide`` reads 1 while it runs and 0 once its slot
+    stands free beside a narrow row."""
+    from bigdl_tpu.serving import SamplingParams, ServingEngine
+
+    eng = ServingEngine(lm, n_slots=2)
+    eng.submit([3, 7], max_new_tokens=12, sampling=SamplingParams(
+        temperature=0.8, top_k=5, seed=1))
+    eng.submit([5, 1], max_new_tokens=3, sampling=SamplingParams(
+        temperature=0.8, top_p=0.9, seed=2))
+    eng.drain()
+    series = eng.metrics.metrics.values("serving/sampler_wide")
+    assert series[:3] == [1.0, 1.0, 1.0]
+    assert len(series) == 12 and not any(series[3:])
+    assert (eng._knobs["top_p"] < 1).any()      # the stale row is there
+    assert eng.metrics.summary()["serving/sampler_wide"] \
+        == pytest.approx(3 / 12)
 
 
 # -- bench registration smoke (tier-1, small/CPU) --------------------------
@@ -295,3 +552,17 @@ def test_sampling_bench_smoke():
     assert out["greedy"]["tokens_per_sec"] > 0
     assert out["mixed"]["tokens_per_sec"] > 0
     assert out["sampled_row_frac"] == pytest.approx(0.5)
+
+
+def test_sampler_bench_smoke():
+    """benchmarks/serving_bench.py --scenario sampler times
+    ``sample_rows`` alone under its three knob mixes; off the chip the
+    profiler's trace holds no device plane and only the wall time is
+    reported."""
+    from benchmarks import serving_bench
+
+    out = serving_bench.run_sampler(shapes=[(4, 300)], reps=2)
+    assert sorted(out["wall_ms"]) == [
+        "4x300/greedy", "4x300/half_top_k", "4x300/one_nucleus"]
+    assert all(ms > 0 for ms in out["wall_ms"].values())
+    assert out["device_ms"] == {} and out["platform"] == "cpu"
