@@ -108,13 +108,16 @@ class _InFlight:
     at dispatch time that its bookkeeping needs (the row snapshot, the
     pre-dispatch clock read the watchdog's elapsed is measured from,
     and whether rows were already in flight — the decode-gap
-    anchor)."""
+    anchor), and what identifies and describes the dispatch on every
+    span and series it causes: its number, whether it was chained, the
+    prefill launches since the dispatch before it, and the host-state
+    samples of the rows it reads (``on_step``'s, taken at dispatch)."""
 
     __slots__ = ("tok", "chosen", "active", "active_dev", "rows", "t0",
-                 "had_running", "extra")
+                 "had_running", "extra", "seq", "chained", "waves", "host")
 
     def __init__(self, tok, chosen, active, active_dev, rows, t0,
-                 had_running, extra=()):
+                 had_running, extra, seq, chained, waves, host):
         self.tok = tok                  # device handle: next 0-based ids
         self.chosen = chosen            # device handle: chosen logprobs
         # device handles a family's step returns after the carry (an
@@ -126,6 +129,10 @@ class _InFlight:
         self.rows = rows                # {slot: Request} at dispatch
         self.t0 = t0                    # clock at dispatch (pre-launch)
         self.had_running = had_running  # decode-gap anchor flag
+        self.seq = seq                  # the dispatch's number
+        self.chained = chained          # launched on the in-flight token
+        self.waves = waves              # prefill launches since seq - 1
+        self.host = host                # _host_state() at dispatch
 
 
 class ServingEngine:
@@ -491,6 +498,11 @@ class ServingEngine:
         # serving.step / serving.admit spans' arguments
         self._n_steps = 0
         self._bound: List[int] = []
+        # decode (or verify) dispatch counter, the ``seq`` every span a
+        # dispatch causes carries, and the prefill launch count as of
+        # the newest dispatch (see _next_dispatch)
+        self._n_dispatches = 0
+        self._launches_seen = 0
         if self.tier is not None:
             self.tier.attach_metrics(self.metrics, clock=self._clock)
         if self._plane is not None:
@@ -1108,46 +1120,60 @@ class ServingEngine:
         self._bound.append(req.req_id)
         return req
 
-    def _kv_used_share(self) -> float:
-        """Resident K/V positions over the ``n_slots x max_len`` the
-        pool reserves, from host state alone (no readback): a decoding
-        row holds its prompt and everything emitted but the token it
-        feeds next; a mid-prefill row what the chunk pump has landed."""
-        sched = self.scheduler
-        used = sum(len(r.prompt) + len(r.output)
-                   for r in sched.running.values())
-        used += sum(int(self.pool.chunk_done[slot])
-                    for slot in sched.partial)
-        return used / (self.pool.n_slots * self.pool.max_len)
+    def _resident_positions(self, rows, ahead: int = 0) -> List[int]:
+        """K/V positions resident once the decode dispatch about to
+        launch has run, a row each, from host state alone (no
+        readback): a decoding row of ``rows`` holds its prompt and
+        everything emitted (the token it feeds included, once this
+        program has written it), ``ahead`` more where that many
+        dispatches of the same rows are in flight and not yet consumed;
+        then the mid-prefill rows, each what the chunk pump has landed."""
+        return [len(r.prompt) + len(r.output) + ahead
+                for r in rows.values()] \
+            + [int(self.pool.chunk_done[slot])
+               for slot in self.scheduler.partial]
 
-    def _kv_held_bytes(self) -> int:
-        """Bytes of K/V the running rows really hold, from host state
-        alone: per row and layer ``min(pos, len_i)`` positions (a ring
-        holds at most its window, whatever the row's position)."""
-        sched, held = self.scheduler, self.pool.kv_held_bytes
-        return sum(held(len(r.prompt) + len(r.output))
-                   for r in sched.running.values()) \
-            + sum(held(int(self.pool.chunk_done[slot]))
-                  for slot in sched.partial)
+    def _kv_used_share(self, resident: List[int]) -> float:
+        """Those positions over the ``n_slots x max_len`` the pool
+        reserves."""
+        return sum(resident) / (self.pool.n_slots * self.pool.max_len)
 
-    def _kv_fetched_bytes(self) -> int:
-        """Bytes of K/V the decode program's attention fetches for the
-        running rows (a mid-prefill row does not decode: none), from
-        host state alone: a row feeds its last token at position
-        ``prompt + output - 1`` and attends up to it. (Sampled by the
-        plain decode step only: a speculative engine's super-step,
-        whose verify program reads the whole window, takes its own
-        step sample without it.)"""
-        return self.pool.kv_fetched_bytes(
-            [len(r.prompt) + len(r.output) - 1
-             for r in self.scheduler.running.values()])
+    def _host_state(self, rows, ahead: int) -> dict:
+        """``on_step``'s host-state samples for ONE plain decode
+        dispatch, taken when it is launched, over the rows it decodes
+        as the PROGRAM reads them (:meth:`_resident_positions`).
 
-    def _state_in_use(self) -> Optional[int]:
-        """Bytes of per-slot ``state`` leaves the in-use slots hold
-        (each holds all of its own, whatever its position), from host
-        state alone; None for a family that keeps none."""
-        per_slot = self.pool.state_bytes_per_slot
-        return per_slot * self.pool.used_slots if per_slot else None
+        * ``kv_used_share``: :meth:`_kv_used_share`;
+        * ``kv_held_bytes``: the same positions per row and layer as
+          ``min(pos, len_i)`` (a ring holds at most its window);
+        * ``kv_fetched_bytes``: what the program's attention fetches: a
+          row feeds its last token at position ``held - 1`` and attends
+          up to it (a mid-prefill row does not decode: none). Not taken
+          by a speculative engine's super-step, whose verify program
+          reads the whole window;
+        * ``state_in_use_bytes``: the per-slot ``state`` leaves of the
+          in-use slots (each holds all of its own), None for a family
+          that keeps none."""
+        pool = self.pool
+        resident = self._resident_positions(rows, ahead)
+        per_slot = pool.state_bytes_per_slot
+        return dict(
+            kv_used_share=self._kv_used_share(resident),
+            kv_held_bytes=sum(map(pool.kv_held_bytes, resident)),
+            kv_fetched_bytes=pool.kv_fetched_bytes(
+                [held - 1 for held in resident[:len(rows)]]),
+            state_in_use_bytes=per_slot * pool.used_slots
+            if per_slot else None)
+
+    def _next_dispatch(self):
+        """Number the decode (or verify) dispatch about to launch:
+        ``(seq, waves)``, ``waves`` the prefill launches (a batched
+        wave, a prefix suffix, a chunk) since the dispatch before it."""
+        self._n_dispatches += 1
+        launches = self.metrics.prefill_launch_count
+        waves, self._launches_seen = \
+            launches - self._launches_seen, launches
+        return self._n_dispatches, waves
 
     def _admitted_prefill_tokens(self, req: Request) -> List[int]:
         """0-based tokens whose K/V must be resident before ``req``
@@ -1561,16 +1587,20 @@ class ServingEngine:
         for _ in range(max(0, int(n_samples) - 1)):
             self.metrics.add_phase("host_step", 0.0)
 
-    def _note_decode_gap(self, had_running: bool) -> None:
+    def _note_decode_gap(self, had_running: bool, rows: int, waves: int,
+                         chained: bool) -> None:
         """Record the wall gap between consecutive decode (or verify)
         dispatch completions while rows stayed in flight across it —
-        the decode-stall sample. Admission work between the two
-        dispatches (a batched prefill wave, a chunk budget) is exactly
-        what stretches the gap, which is the phenomenon
-        ``serving_bench --scenario chunked`` measures."""
+        the decode-stall sample — beside what the dispatch just read
+        back was (its rows, the prefill launches before it, chained or
+        not). Admission work between the two dispatches (a batched
+        prefill wave, a chunk budget) is exactly what stretches the
+        gap, which is the phenomenon ``serving_bench --scenario
+        chunked`` measures."""
         now = self._clock()
         if had_running and self._last_decode_end is not None:
-            self.metrics.on_decode_gap(now - self._last_decode_end)
+            self.metrics.on_decode_gap(now - self._last_decode_end,
+                                       rows, waves, chained)
         self._last_decode_end = now
 
     def step(self) -> Dict[int, int]:
@@ -1684,11 +1714,16 @@ class ServingEngine:
         # chosen log-probs do). The span's bracket is the fenced-wait
         # sample: the time the host was genuinely BLOCKED here, the
         # DEVICE_PHASES half of the host_step split.
-        with self.metrics.span("consume"):
-            with self.metrics.span("fence", phase="fence_wait"):
+        with self.metrics.span("consume", seq=entry.seq) as consume:
+            with self.metrics.span("fence", phase="fence_wait",
+                                   seq=entry.seq):
                 nxt, lps, *extra = fence("decode", entry.tok, entry.chosen,
                                          *entry.extra)
             now = self._clock()
+            # the load this dispatch read, as of its launch, for a
+            # reader of the profile (free when none runs)
+            consume.note(kv_held=entry.host["kv_held_bytes"],
+                         kv_fetched=entry.host["kv_fetched_bytes"])
             # the watchdog's elapsed spans dispatch → readback landed; at
             # W>0 that window covers host work on other in-flight steps
             # too, and a stall fault's clock advance at dispatch time is
@@ -1727,7 +1762,8 @@ class ServingEngine:
             self._warm = True                  # arms the watchdog timeout
             # HEALTHY steps only: the decode-stall histogram measures gaps
             # between dispatches that actually served the batch
-            self._note_decode_gap(entry.had_running)
+            self._note_decode_gap(entry.had_running, len(entry.rows),
+                                  entry.waves, entry.chained)
             # recency stamps feed the tier's cold-first victim selection:
             # a row decoded this step is never the LRU preemption victim
             self.scheduler.note_decoded(list(rows))
@@ -1736,18 +1772,18 @@ class ServingEngine:
             # its token is thrown away (the overshoot), so it is no
             # emitted token of ``serving/batch_active`` and no sampled
             # row; an entry none of whose rows still runs served nothing
-            # and leaves no step sample at all
+            # and leaves no step sample at all. The host-state samples
+            # are the entry's own, taken at its dispatch: what the
+            # program read, not what runs one dispatch later
             if rows:
                 n_sampled = sum(not req.sampling.is_greedy
                                 for req in rows.values())
                 self.metrics.on_step(
                     self.scheduler.queue_depth, self.pool.occupancy(),
-                    len(rows), kv_used_share=self._kv_used_share(),
-                    state_in_use_bytes=self._state_in_use(),
-                    kv_held_bytes=self._kv_held_bytes(),
-                    kv_fetched_bytes=self._kv_fetched_bytes())
+                    len(rows), **entry.host)
                 if extra:
-                    self.metrics.on_expert_counts(extra[0])
+                    consume.note(
+                        experts_hit=self.metrics.on_expert_counts(extra[0]))
                 self.metrics.on_sample_rows(
                     n_sampled, len(rows) - n_sampled,
                     self._sampler_wide(list(rows)))
@@ -1815,13 +1851,13 @@ class ServingEngine:
                 return emitted
             if self._spec is not None:
                 slots = list(running)
-                out = self._spec.step(running)
-                # a healthy super-step emits for every running row; an
-                # empty dict here means the step faulted and recovery
-                # evicted the batch — no dispatch completed, so there is
-                # no gap sample and no live batch to anchor the next one
+                out = self._spec.step(running, had_running)
+                # a healthy super-step emits for every running row (and
+                # has left its gap sample); an empty dict here means the
+                # step faulted and recovery evicted the batch — no
+                # dispatch completed, so there is no gap sample and no
+                # live batch to anchor the next one
                 if out:
-                    self._note_decode_gap(had_running)
                     self.scheduler.note_decoded(slots)
                 else:
                     self._last_decode_end = None
@@ -1880,11 +1916,15 @@ class ServingEngine:
                     active_dev = self._place_rows(jnp.asarray(active))
                     rows = {slot: req for slot, req in running.items()
                             if active[slot]}
+            seq, waves = self._next_dispatch()
+            host = self._host_state(rows, ahead=len(self._window))
             t0 = self._clock()
             try:
                 # the LAUNCH of the knob upload and the decode dispatch; the
                 # program's device time is the trace's (and, fenced, t0's)
-                with self.metrics.span("decode.launch"):
+                with self.metrics.span("decode.launch", seq=seq,
+                                       rows=len(rows), chained=int(chained),
+                                       waves=waves):
                     if self._knobs_device is None:
                         self._knobs_device = {
                             k: self._place_rows(jnp.asarray(v))
@@ -1915,7 +1955,8 @@ class ServingEngine:
             # site, serving/fences.py). t0 rides the entry so the
             # watchdog's elapsed covers the device work, not the launch
             self._window.append(_InFlight(tok, chosen, active, active_dev,
-                                          rows, t0, had_running, extra))
+                                          rows, t0, had_running, extra,
+                                          seq, chained, waves, host))
             # delayed consumer: fence the oldest entry once the window
             # exceeds its DECLARED depth knob (fences.WINDOW_KNOBS —
             # ASY308 rejects any other bound). dispatch_ahead=0 consumes

@@ -26,21 +26,24 @@ Counters (all under the ``serving/`` prefix in the backing Metrics):
 * ``queue_wait_s``      — per slot binding, the engine clock at the
   binding minus ``submit_time`` (re-admissions included): what a request
   waited for a slot, apart from the prefill that follows
-* ``kv_used_share``     — sampled every engine step: resident K/V
+* ``kv_used_share``     — one sample a decode step: resident K/V
   positions (sum of ``pos`` over in-use slots, from host state — no
-  readback) over the ``n_slots x max_len`` the pool reserves
-* ``state_in_use_bytes`` — sampled every engine step, only where the
+  readback) over the ``n_slots x max_len`` the pool reserves. This and
+  the next three are computed when the step is DISPATCHED, for the rows
+  it decodes at the positions the program reads, and ride the in-flight
+  entry to its read-back (``ServingEngine._host_state``)
+* ``state_in_use_bytes`` — one sample a decode step, only where the
   model's family keeps per-slot state beside K/V (a recurrent scan
   state, a convolution window): in-use slots x
   ``state_bytes_per_slot``, host state, no readback. A slot holds all
   of its state whatever its position
-* ``kv_held_bytes``     — sampled every engine step: bytes of K/V the
-  running rows really hold, per row and layer ``min(pos, len_i)``
+* ``kv_held_bytes``     — one sample a decode step: bytes of K/V the
+  step's rows really hold, per row and layer ``min(pos, len_i)``
   positions (a sliding-window layer's ring holds at most its window),
   host state, no readback; ``kv_used_share`` keeps its meaning (``pos``
   over ``n_slots x max_len``)
-* ``kv_fetched_bytes``  — sampled every engine step: bytes of K/V the
-  decode program's attention FETCHES for the running rows, per row and
+* ``kv_fetched_bytes``  — one sample a decode step: bytes of K/V the
+  decode program's attention FETCHES for the step's rows, per row and
   K/V leaf the whole kernel blocks up to its position
   (``KVPool.kv_fetched_bytes``). COMPUTED from shapes and host state,
   not measured: what the Pallas kernel fetches on a TPU (off it the
@@ -72,10 +75,23 @@ it, from the same bracket, on the engine's clock:
         serving.pool.write
       serving.decode.build             (also holds serving.pool.write:
                                         write_sampling at configuration)
-      serving.decode.launch
-      serving.consume                  (the delayed consumer; a finished
-        serving.fence                  -> fence_wait_s   row's slot reset is
-                                        a serving.pool.write in it)
+      serving.decode.launch (seq=, rows=, chained=, waves=)
+      serving.consume (seq=; kv_held=, kv_fetched=, experts_hit= noted
+        serving.fence (seq=)            once read back) -> fence_wait_s
+                                       (the delayed consumer; a finished
+                                        row's slot reset is a
+                                        serving.pool.write in it)
+
+``seq`` numbers the engine's decode (or verify) dispatches: the launch,
+the fence and the consume of ONE dispatch carry one number, the fence a
+step after the launch under the default window. ``rows`` is what the
+dispatch decodes, ``chained`` whether it was launched on the in-flight
+token, ``waves`` the prefill launches (``on_prefill_batch`` calls: a
+batched wave, a prefix suffix, a chunk) since the dispatch before it;
+the notes on ``consume`` are the dispatch's ``kv_held_bytes`` /
+``kv_fetched_bytes`` samples and the held experts that got a token.
+``benchmark/step_join.py`` joins them to the program's execution on the
+device through the runtime's ``run_id``.
 
 The profile ``stop_trace`` writes is the span record; there is no
 in-memory span log. ``benchmark/span_reduce.py`` puts every device idle
@@ -88,11 +104,22 @@ Chunked-admission counters (``serving/chunked.py``):
   ``chunk_tokens``/``chunks`` mean = effective chunk width)
 * ``partial_rows``     — mid-prefill PARTIAL rows, sampled per pump
 * ``decode_gap_s``     — wall gap between consecutive decode (or
-  verify) dispatches while rows were in flight across the gap: the
+  verify) read-backs while rows were in flight across the gap: the
   DECODE-STALL signal chunked admission exists to shrink (a batched
   admission burst shows up as one huge gap; chunked bounds it by the
   chunk budget). ``decode_gap_percentiles()`` summarizes;
   ``summary()`` reports the p99
+* ``step_rows`` / ``step_waves`` / ``step_chained`` — beside every
+  ``decode_gap_s`` sample, from the same hook (``on_decode_gap``), so
+  the four are equal in length and aligned sample for sample: the rows
+  the dispatch just read back decoded, the prefill launches since the
+  dispatch before it, and whether it was chained. ``decode_gap_s``
+  where ``step_chained`` is 1, ``step_waves`` 0 and the NEXT sample's
+  ``step_waves`` 0 too is the plain step; where ``step_waves`` > 0,
+  less that, it is the device side of the stall one admission imposes
+  on every running row (the host side, the wave's launch, lands on the
+  sample before, the dispatch then in flight); weighted by
+  ``step_rows`` it is the gap between tokens the users see
 * ``host_step_s``      — per-super-step HOST time: step wall minus the
   fenced device phase windows (decode/verify dispatch, draft chain)
   timed inside it — the Python the device pipeline waits on between
@@ -305,6 +332,10 @@ class ServingMetrics:
         # set_kv_format's constant, repeated beside every step's
         # kv_held_bytes sample
         self._kv_position_bytes: Optional[int] = None
+        # lifetime count of prefill launches (on_prefill_batch calls):
+        # the engine differences it across two decode dispatches to
+        # say how many waves were launched in between
+        self._n_prefill_launches = 0
 
     # -- engine hooks ------------------------------------------------------
 
@@ -348,12 +379,15 @@ class ServingMetrics:
             self.metrics.add("serving/kv_fetched_bytes",
                              float(kv_fetched_bytes))
 
-    def on_expert_counts(self, counts) -> None:
+    def on_expert_counts(self, counts) -> int:
         """One decode step's ``(n_expert_layers, held)`` token counts,
-        as the fence read them back."""
+        as the fence read them back. Returns the held experts that got
+        a token (the ``experts_hit`` sample)."""
+        hit = int((counts > 0).sum())
         self.metrics.add("serving/expert_pairs", float(counts.sum()))
-        self.metrics.add("serving/experts_hit", float((counts > 0).sum()))
+        self.metrics.add("serving/experts_hit", float(hit))
         self.metrics.add("serving/expert_load_max", float(counts.max()))
+        return hit
 
     def on_first_token(self, ttft_s: float) -> None:
         self.metrics.add("serving/ttft_s", float(ttft_s))
@@ -517,11 +551,19 @@ class ServingMetrics:
         """Mid-prefill PARTIAL rows after one pump pass."""
         self.metrics.add("serving/partial_rows", float(n))
 
-    def on_decode_gap(self, gap_s: float) -> None:
-        """Wall gap between consecutive decode dispatches while rows
+    def on_decode_gap(self, gap_s: float, rows: int, waves: int,
+                      chained: bool) -> None:
+        """Wall gap between consecutive decode read-backs while rows
         stayed in flight — the decode-stall sample (admission work in
-        the gap is what stretches it)."""
+        the gap is what stretches it) — with what the dispatch just
+        read back was: the rows it decoded, the prefill launches since
+        the decode dispatch before it, and whether it was chained.
+        THE one place the four series gain a sample, so they stay
+        equal in length and aligned sample for sample."""
         self.metrics.add("serving/decode_gap_s", float(gap_s))
+        self.metrics.add("serving/step_rows", float(rows))
+        self.metrics.add("serving/step_waves", float(waves))
+        self.metrics.add("serving/step_chained", float(chained))
 
     def on_infeasible(self) -> None:
         """A waiting request dropped by feasibility admission control:
@@ -694,6 +736,13 @@ class ServingMetrics:
     def on_prefill_batch(self, n_rows: int, n_padded: int) -> None:
         self.metrics.add("serving/prefill_batch", float(n_rows))
         self.metrics.add("serving/prefill_batch_padded", float(n_padded))
+        self._n_prefill_launches += 1
+
+    @property
+    def prefill_launch_count(self) -> int:
+        """Lifetime count of prefill launches — a batched wave, a
+        prefix suffix, a chunk: every ``on_prefill_batch`` call."""
+        return self._n_prefill_launches
 
     def on_bucket_compile(self) -> None:
         self.metrics.add("serving/prefill_bucket_compiles", 1.0)

@@ -270,7 +270,7 @@ class Speculator:
             return "garbage"
         return None
 
-    def step(self, running) -> Dict[int, int]:
+    def step(self, running, had_running: bool) -> Dict[int, int]:
         """One draft-and-verify super-step over every active row:
         propose (``k + 1`` draft-decode dispatches), verify (ONE target
         dispatch), roll the draft cache back to the accepted prefix,
@@ -279,7 +279,11 @@ class Speculator:
         at its first stop condition). Returns ``{req_id: last emitted
         1-based token}`` — multi-token emissions land in
         ``Request.output``; the dict mirrors the baseline ``step()``
-        shape for callers that only poll liveness.
+        shape for callers that only poll liveness. ``had_running`` is
+        the engine's decode-gap anchor (rows were in flight before this
+        step's admission): a healthy super-step leaves its gap sample
+        itself, beside the rows it verified, and its verify launch and
+        fence carry the dispatch's ``seq`` like a plain decode's.
 
         Resilience: both dispatch sites route through the engine's
         fault hook (``draft``/``verify`` — serving/faults.py). A raised
@@ -364,12 +368,17 @@ class Speculator:
         vtoks = eng._place_rows(jnp.concatenate(
             [jnp.asarray(tokens)[:, None]] + [d[:, None] for d in drafts],
             axis=1))
+        seq, waves = eng._next_dispatch()
+        n_rows = int(active.sum())
         t0 = eng._clock()
         try:
-            vt, vlp, n_emit, carry = eng._dispatch(
-                "verify", self.verify_fn,
-                eng.params, vtoks, eng._place_rows(jnp.asarray(lengths)),
-                eng.pool.carry, knobs, *eng._adapter_args())
+            with eng.metrics.span("decode.launch", seq=seq, rows=n_rows,
+                                  chained=0, waves=waves):
+                vt, vlp, n_emit, carry = eng._dispatch(
+                    "verify", self.verify_fn,
+                    eng.params, vtoks,
+                    eng._place_rows(jnp.asarray(lengths)),
+                    eng.pool.carry, knobs, *eng._adapter_args())
         except FaultError:
             eng.pool.draft_carry = dcarry     # target carry never donated
             eng._recover_step(running, "fail")
@@ -384,7 +393,7 @@ class Speculator:
         # THIS readback, so there is nothing to dispatch ahead of it.
         # The span's bracket is the fenced-wait sample — the blocked
         # half of the host_step split (metrics.DEVICE_PHASES)
-        with eng.metrics.span("fence", phase="fence_wait"):
+        with eng.metrics.span("fence", phase="fence_wait", seq=seq):
             nxt, lps, nem = fence("verify", vt, vlp, n_emit)
         eng.metrics.add_phase("decode_step", eng._clock() - t0)
         bad = self._chunk_unhealthy(nxt, lps, nem, lengths, active)
@@ -411,9 +420,10 @@ class Speculator:
             dcarry["pos"])
         eng.pool.draft_carry = dcarry
 
-        eng.metrics.on_step(eng.scheduler.queue_depth,
-                            eng.pool.occupancy(), int(active.sum()),
-                            kv_used_share=eng._kv_used_share())
+        eng.metrics.on_step(
+            eng.scheduler.queue_depth, eng.pool.occupancy(), n_rows,
+            kv_used_share=eng._kv_used_share(
+                eng._resident_positions(running)))
         eng.metrics.on_sample_rows(n_sampled, len(running) - n_sampled,
                                    eng._sampler_wide(active))
 
@@ -449,7 +459,8 @@ class Speculator:
         # non-draft draw per row, so accept_rate/tokens_per_step report
         # what the engine actually emitted, not what the verify step
         # confirmed before a mid-chunk stop discarded the tail
-        n_rows = int(active.sum())
         eng.metrics.on_spec_step(int(k_r[active].sum()),
                                  n_landed - n_rows, n_rows)
+        # the super-step's gap sample, beside what it verified
+        eng._note_decode_gap(had_running, n_rows, waves, False)
         return emitted

@@ -706,6 +706,43 @@ def test_the_expert_series_and_held_bytes_ride_the_step(lm):
     assert fetched <= {3 * 128 * row, 2 * 3 * 128 * row} and fetched
 
 
+def test_the_consume_span_notes_the_experts_hit_of_its_dispatch(
+        lm, monkeypatch):
+    """``serving.consume(seq=)`` notes what its dispatch read: the held
+    experts that got a token, beside the bytes held and fetched, the
+    values the three series gained at the same read-back."""
+    import bigdl_tpu.optim.metrics as metrics_mod
+
+    notes = []
+
+    class _Ann:
+        def __init__(self, name, **ids):
+            self.ids = dict(ids)
+            if name == "serving.consume":
+                notes.append(self.ids)
+
+        def set_metadata(self, **more):
+            self.ids.update(more)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(metrics_mod, "TraceAnnotation", _Ann)
+    monkeypatch.setattr(metrics_mod, "StepTraceAnnotation", _Ann)
+    jobs = [(_tokens(27, 30), 6, None), (_tokens(28, 7), 6, None)]
+    eng, _ = _served(lm, jobs, n_slots=4)
+    m = eng.metrics.metrics
+    kept = [n for n in notes if "experts_hit" in n]     # not pure overshoot
+    assert [n["seq"] for n in notes] == list(range(1, len(notes) + 1))
+    for key, series in (("experts_hit", "experts_hit"),
+                        ("kv_held", "kv_held_bytes"),
+                        ("kv_fetched", "kv_fetched_bytes")):
+        assert [float(n[key]) for n in kept] == m.values(f"serving/{series}")
+
+
 REFUSED = {"prefix_cache": True, "speculative": object(),
            "adapters": object(), "kv_dtype": "int8", "mesh": object(),
            "parallelism": {"data": 2}, "tier": True}

@@ -216,9 +216,9 @@ def test_queue_wait_one_sample_per_admission_on_the_engine_clock():
 
 
 def test_kv_used_share_is_sum_pos_over_reserved():
-    """Sampled from host state alone at each consume, it equals what
-    the device holds once nothing is in flight (the device runs one
-    decode ahead of the host otherwise): sum of ``pos`` over the in-use
+    """Computed from host state alone at each dispatch, for the
+    positions the program reads and writes, it equals what the device
+    holds once that dispatch has run: sum of ``pos`` over the in-use
     slots / (slots x max_len)."""
     from bigdl_tpu.serving import ServingEngine, VirtualClock
 
@@ -235,6 +235,228 @@ def test_kv_used_share_is_sum_pos_over_reserved():
             held / (eng.pool.n_slots * eng.pool.max_len))
     # the prompts less each one's fed token, plus three steps of three rows
     assert held == 5 + 11 + 2 - 3 + 3 * 3
+
+
+# -- one record per decode dispatch -------------------------------------------
+
+ALIGNED = ("serving/decode_gap_s", "serving/step_rows", "serving/step_waves",
+           "serving/step_chained")
+
+
+def _aligned(eng) -> int:
+    """The four series gain their samples in one hook: one length."""
+    lengths = {len(eng.metrics.metrics.values(n)) for n in ALIGNED}
+    assert len(lengths) == 1, lengths
+    return lengths.pop()
+
+
+def _seqs(recorder, name):
+    return [ids["seq"] for n, ids, _ in recorder.events
+            if n == f"serving.{name}"]
+
+
+def _check_record(recorder):
+    """Launches are numbered 1, 2, ... in order; every consume holds the
+    fence of the SAME dispatch; what was read back is a subset of what
+    was launched, in launch order."""
+    launches = _seqs(recorder, "decode.launch")
+    assert launches == list(range(1, len(launches) + 1))
+    consumes, fences = _seqs(recorder, "consume"), _seqs(recorder, "fence")
+    assert consumes == fences == sorted(set(consumes))
+    assert set(consumes) <= set(launches)
+    for name, ids, _ in recorder.events:
+        if name == "serving.decode.launch":
+            assert set(ids) == {"seq", "rows", "chained", "waves"}
+            assert ids["rows"] >= 1 and ids["chained"] in (0, 1)
+    return launches, consumes
+
+
+@pytest.mark.parametrize("ahead", [0, 1, 2])
+def test_a_dispatch_carries_one_seq_from_launch_to_consume(recorder, ahead):
+    """Across an admission (a flush; at depth 2 one of several entries
+    in one step), a finish and the drain: launch, fence and consume of
+    one dispatch carry one number, the consume notes the load as of the
+    dispatch, and the four series stay of one length after every step."""
+    from bigdl_tpu.serving import ServingEngine, VirtualClock
+
+    eng = ServingEngine(_make_lm(), n_slots=3, clock=VirtualClock(),
+                        dispatch_ahead=ahead)
+    eng.submit([1, 2, 3, 4, 5], max_new_tokens=12)
+    eng.submit([6, 7, 8, 9], max_new_tokens=5)      # finishes mid-run
+    for _ in range(4):
+        eng.step()
+        _aligned(eng)
+    eng.submit([9, 10, 11, 12], max_new_tokens=6)   # admission: a flush
+    n_steps = eng._n_steps
+    while not eng.idle():
+        eng.step()
+        _aligned(eng)
+    eng.flush_window()
+    launches, consumes = _check_record(recorder)
+    assert consumes == launches         # nothing discarded: all read back
+    m = eng.metrics.metrics
+    # one gap sample a consumed dispatch but those that followed an idle
+    # engine (the very first one here)
+    assert _aligned(eng) == len(consumes) - 1
+    # the admission's wave is counted once, on the first dispatch after
+    # it, which was not chained; every other dispatch counts none
+    waves = [ids["waves"] for n, ids, _ in recorder.events
+             if n == "serving.decode.launch"]
+    assert waves[0] == 1 and sum(waves) == 2 == \
+        m.get("serving/prefill_batch")[1]
+    wave_step = waves.index(1, 1)
+    chained = [ids["chained"] for n, ids, _ in recorder.events
+               if n == "serving.decode.launch"]
+    assert chained[wave_step] == 0
+    assert any(chained) == (ahead > 0)
+    assert m.values("serving/step_waves")[wave_step - 1] == 1.0
+    assert m.values("serving/step_chained") == \
+        [float(c) for c in chained[1:]]
+    # the series' rows are the launches' rows (overshoot rows included)
+    rows = [ids["rows"] for n, ids, _ in recorder.events
+            if n == "serving.decode.launch"]
+    assert m.values("serving/step_rows") == [float(r) for r in rows[1:]]
+    if ahead == 2:       # a flush read several entries back in one step
+        per_step = {}
+        stack = []
+        for name, ids, depth in recorder.events:
+            del stack[depth:]
+            stack.append((name, ids))
+            if name == "serving.consume":
+                step = stack[0][1]["step"]
+                per_step[step] = per_step.get(step, 0) + 1
+        assert max(per_step.values()) >= 2 and n_steps in per_step
+    notes = [ids for n, ids, _ in recorder.events if n == "serving.consume"]
+    assert all(set(ids) == {"seq", "kv_held", "kv_fetched"} and
+               ids["kv_fetched"] >= ids["kv_held"] > 0 for ids in notes)
+
+
+@pytest.mark.faults
+@pytest.mark.parametrize("ahead", [0, 1, 2])
+def test_a_discarded_dispatch_keeps_its_seq_and_leaves_no_sample(
+        recorder, ahead):
+    """Garbage read back: the unhealthy entry's launch, fence and consume
+    still carry one number; it and the entries chained behind it leave
+    no gap sample, and the four series stay aligned through the replay."""
+    from bigdl_tpu.serving import (
+        FaultInjector, ServingEngine, VirtualClock, WatchdogConfig,
+    )
+
+    eng = ServingEngine(_make_lm(), n_slots=3, clock=VirtualClock(),
+                        dispatch_ahead=ahead,
+                        watchdog=WatchdogConfig(max_retries=None),
+                        faults=FaultInjector(seed=4, p_garbage=0.3))
+    for n in (5, 3, 7):
+        eng.submit(list(range(1, n + 1)), max_new_tokens=8)
+    while not eng.idle():
+        eng.step()
+        _aligned(eng)
+    eng.flush_window()
+    n_bad = eng._faults.counts["garbage"]
+    assert n_bad >= 1
+    launches, consumes = _check_record(recorder)
+    # a discarded entry leaves no sample and takes the newer ones chained
+    # behind it along, unread (launched, never fenced)
+    assert _aligned(eng) <= len(consumes) - 1 - n_bad
+    assert len(launches) >= len(consumes)
+    if ahead == 2:
+        assert len(launches) > len(consumes)
+    assert all(len(r.output) == 8 for r in eng._finished.values())
+
+
+def test_a_speculative_super_step_carries_its_seq_and_keeps_the_series_aligned(
+        recorder):
+    from bigdl_tpu.serving import (
+        ServingEngine, SpeculativeConfig, VirtualClock,
+    )
+
+    eng = ServingEngine(_make_lm(), n_slots=3, clock=VirtualClock(),
+                        speculative=SpeculativeConfig(_make_lm(seed=5), k=2))
+    eng.submit([1, 2, 3, 4, 5], max_new_tokens=9)
+    eng.submit([6, 7, 8, 9], max_new_tokens=4)      # one bucket: one wave
+    for _ in range(2):
+        eng.step()
+        _aligned(eng)
+    eng.submit([9, 10, 11], max_new_tokens=5)
+    while not eng.idle():
+        eng.step()
+        _aligned(eng)
+    launches = [ids for n, ids, _ in recorder.events
+                if n == "serving.decode.launch"]
+    assert [ids["seq"] for ids in launches] == \
+        list(range(1, len(launches) + 1)) == _seqs(recorder, "fence")
+    assert all(ids["chained"] == 0 for ids in launches)
+    assert [ids["waves"] for ids in launches].count(1) == 2
+    m = eng.metrics.metrics
+    assert _aligned(eng) == len(launches) - 1
+    assert m.values("serving/step_rows") == \
+        [float(ids["rows"]) for ids in launches[1:]]
+    assert set(m.values("serving/step_chained")) == {0.0}
+    assert sum(m.values("serving/step_waves")) == 1.0
+
+
+@pytest.mark.parametrize("kind", ["batched", "chunked", "prefix"])
+def test_step_waves_counts_every_prefill_launch(kind):
+    """A batched wave, each chunk of a streamed prompt and a prefix hit's
+    suffix are one launch each, counted on the decode dispatch that
+    follows them."""
+    from bigdl_tpu.serving import ServingEngine, VirtualClock
+
+    kw = {"batched": {}, "chunked": dict(admission="chunked", chunk_budget=4),
+          "prefix": dict(prefix_cache=True)}[kind]
+    eng = ServingEngine(_make_lm(), n_slots=3, clock=VirtualClock(), **kw)
+    shared = [3, 1, 4, 1, 5, 9, 2, 6]
+    eng.submit(shared + [5, 3], max_new_tokens=20)
+    for _ in range(3):
+        eng.step()
+    m = eng.metrics.metrics
+    before = m.get("serving/prefill_batch")[1]
+    # arrives while the first decodes: 11 tokens to prefill (3 chunks of
+    # <= 4; with the prefix cache the 8 shared ones are a hit and the
+    # rest ONE suffix launch)
+    eng.submit(shared + [8, 9, 7, 9], max_new_tokens=3)
+    eng.drain()
+    launched = m.get("serving/prefill_batch")[1] - before
+    assert launched == {"batched": 1, "chunked": 3, "prefix": 1}[kind]
+    if kind == "prefix":
+        assert m.get("serving/prefix_hit_tokens")[0] == len(shared)
+    waves = m.values("serving/step_waves")
+    assert _aligned(eng) == len(waves)
+    assert sum(waves) == launched and max(waves) >= 1.0
+    # a wave or a suffix seats a row, so the step behind it was not
+    # chained; a chunk leaves the running rows as they were, and the
+    # decode in flight is extended past it
+    behind = [c for c, w in zip(m.values("serving/step_chained"), waves) if w]
+    assert (1.0 in behind) == (kind == "chunked")
+
+
+def test_host_state_is_sampled_at_dispatch_for_the_rows_dispatched():
+    """``on_step``'s host-state samples describe the program they are
+    read beside: the rows it decoded at the positions it read, not the
+    rows that run one dispatch later (an admission in between)."""
+    from bigdl_tpu.serving import ServingEngine, VirtualClock
+
+    eng = ServingEngine(_make_lm(), n_slots=4, clock=VirtualClock())
+    eng.submit([1, 2, 3, 4, 5], max_new_tokens=10)
+    for _ in range(3):
+        eng.step()
+    m = eng.metrics.metrics
+    per_pos = eng.pool.kv_held_bytes(1)
+    assert m.values("serving/kv_held_bytes") == \
+        [per_pos * n for n in (5, 6)]
+    eng.submit(list(range(1, 12)), max_new_tokens=4)
+    eng.step()       # admits, then reads back the dispatch of ONE row
+    assert m.values("serving/kv_held_bytes")[-1] == per_pos * 7
+    assert m.values("serving/batch_active")[-1] == 1.0
+    eng.step()       # the first dispatch of both rows
+    assert m.values("serving/kv_held_bytes")[-1] == per_pos * (8 + 11)
+    eng.step()       # chained: one position further than ``output`` says
+    assert m.values("serving/kv_held_bytes")[-1] == per_pos * (9 + 12)
+    n_samples = len(m.values("serving/kv_held_bytes"))
+    assert n_samples == len(m.values("serving/kv_fetched_bytes")) == \
+        len(m.values("serving/kv_used_share")) == \
+        len(m.values("serving/batch_active"))
+    eng.drain()
 
 
 # -- the training loop --------------------------------------------------------
