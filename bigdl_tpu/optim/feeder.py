@@ -10,10 +10,14 @@ then costs the larger of the step and the build, not their sum.
 Three things decide what it may do:
 
 * **The end trigger.** Batch k+j is drawn only when ``end_when.peek`` of
-  the state j iterations ahead says the loop will run it, so a
-  count-based trigger never draws a batch that is not trained on. A peek
-  that says "stop" only PAUSES the producer: should the loop ask for a
-  batch all the same (its own ``end_when`` disagreed), one is drawn.
+  the state j iterations ahead (``advance``, the loop's own counter
+  arithmetic) says the loop will run it, so a count-based trigger never
+  draws a batch that is not trained on. A peek that says "stop" only
+  PAUSES the producer: should the loop ask for a batch all the same (its
+  own ``end_when`` disagreed), one is drawn. The loop asks the same peek
+  ONE step ahead, of the same arithmetic, before it launches step k+1 on
+  the outputs of a step k it has not read yet (``Trigger``'s docstring
+  says what a wrong peek costs either of them).
 * **The ring.** ``SampleToMiniBatch`` builds into the ring's arrays
   (``stack_samples(out=)``); a set of arrays is filled again only after
   the placement made from it is done (``block_until_ready``).
@@ -36,6 +40,19 @@ from bigdl_tpu.dataset.sample import MiniBatch, batch_buffers
 #: chip (PERF.md section 6, PR 29): one hides a build shorter than a
 #: step, the second takes up a build that ran long.
 DEPTH = 2
+
+
+def advance(counters: dict, seen: int, bsz: int, epoch_size: int):
+    """The loop's counter arithmetic, one trained batch of ``bsz``
+    records on: ``(counters, seen)`` after it, from the ``neval``,
+    ``epoch`` and records ``seen`` of the epoch before it. The loop books
+    a step with it, and peeks with it at the state a step not yet read
+    will leave; the feeder peeks with it further ahead."""
+    seen += bsz
+    finished = seen >= epoch_size
+    return {"neval": counters["neval"] + 1,
+            "epoch": counters["epoch"] + finished,
+            "epoch_finished": finished}, 0 if finished else seen
 
 
 class _Slot:
@@ -85,7 +102,10 @@ class BatchFeeder:
     stage where the optimizer added one, building in the ring);
     ``start()`` sets the producer going from the loop's ``state``,
     ``get()`` hands the loop its next placed batch, ``launched()`` says
-    the step on it is under way, ``close()`` stops and joins the producer.
+    the step on it is under way (the loop may call ``get()`` again at
+    once, with that step still running and unread: the protocol is per
+    launch, not per finished step), ``close()`` stops and joins the
+    producer.
     ``state`` is the loop's live state table: its counters seed the
     producer's own, the rest of it is what a trigger may look at beside
     them."""
@@ -199,15 +219,6 @@ class BatchFeeder:
             slot.placed = (inp, tgt)
         return inp, tgt, batch.size()
 
-    def _advance(self, bsz: int) -> None:
-        """The loop's own counter arithmetic, one iteration on."""
-        self._seen += bsz
-        self._spec["neval"] += 1
-        self._spec["epoch_finished"] = self._seen >= self._epoch_size
-        if self._spec["epoch_finished"]:
-            self._spec["epoch"] += 1
-            self._seen = 0
-
     def _produce(self) -> None:
         try:
             while self._wait_for_room():
@@ -216,7 +227,8 @@ class BatchFeeder:
                     item = self._build()
                 except StopIteration:
                     break
-                self._advance(bsz=item[2])
+                self._spec, self._seen = advance(
+                    self._spec, self._seen, item[2], self._epoch_size)
                 self._metrics.add("batch build time",
                                   time.perf_counter() - t0)
                 with self._cond:

@@ -26,7 +26,7 @@ import numpy as np
 from bigdl_tpu.dataset.dataset import AbstractDataSet, DataSet, DistributedDataSet
 from bigdl_tpu.dataset.sample import MiniBatch, Sample
 from bigdl_tpu.dataset.transformer import SampleToMiniBatch
-from bigdl_tpu.optim.feeder import BatchFeeder
+from bigdl_tpu.optim.feeder import BatchFeeder, advance
 from bigdl_tpu.optim.metrics import Metrics
 from bigdl_tpu.optim.optim_method import OptimMethod, SGD
 from bigdl_tpu.optim.train_step import make_eval_step, make_train_step
@@ -307,9 +307,15 @@ class Optimizer:
         loop's phases are in it as ``train.iteration`` (a profiler step)
         holding ``train.fetch``, ``train.dispatch`` and
         ``train.loss_sync``, on the device events' timebase; Python
-        frames are not recorded."""
+        frames are not recorded. The trace starts and stops with no step
+        in flight (the profiler truncates a program that is running when
+        it starts), so it holds ``n_iterations`` steps whole: its first
+        is launched onto an idle device, the others ahead of the read
+        before them. Called from inside a running loop (an end trigger's
+        ``fn``) with ``start_iteration`` already launched, the trace
+        starts one step later and still holds ``n_iterations`` steps."""
         self._profile = {"dir": trace_dir, "start": start_iteration,
-                         "stop": start_iteration + n_iterations}
+                         "n": n_iterations}
         return self
 
     def set_compute_dtype(self, dtype) -> "Optimizer":
@@ -761,6 +767,60 @@ class Optimizer:
     def _opt_state_to_device(self, opt_state):
         return opt_state
 
+    def _trace_turns(self, neval: int) -> bool:
+        """Whether ``set_profile``'s trace starts or stops before step
+        ``neval``. It does either with no step in flight, so that it
+        holds whole steps: such a step is not launched ahead. Asked for
+        from inside the loop (an end trigger's ``fn``) for the iteration
+        already in flight, it starts one step later."""
+        p = self._profile
+        if p is None:
+            return False
+        if "stop" in p:
+            return neval >= p["stop"]
+        return p["start"] <= neval <= p["start"] + 1
+
+    def _turn_trace(self, neval: int) -> None:
+        """Starts the trace before step ``neval``, to hold the
+        ``n_iterations`` steps from it on, or stops the one running."""
+        import jax
+
+        if "stop" in self._profile:
+            jax.profiler.stop_trace()
+            self._profile = None
+            return
+        # the loop's spans name its phases; Python frames on the same
+        # line would only rename them with every edit (file:line), at
+        # several times the trace's size and cost
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(self._profile["dir"],
+                                 profiler_options=options)
+        self._profile["stop"] = neval + self._profile["n"]
+
+    def _host_waits_for(self, state: dict, bsz: int, epoch_size: int) -> bool:
+        """Whether something on the host needs the step in flight (of
+        ``bsz`` records, the one after those ``state`` has booked) before
+        the next step may start: its RESULT (an end trigger that reads
+        the loss or the score, or whose peek says the loop ends there) or
+        the PARAMETERS as they stand after it, which a launch ahead
+        donates to the next step (validation, a checkpoint, the
+        ``Parameters`` summary, a preemption's snapshot, a trace's edge).
+        The triggers are asked, side-effect-free, of the counters the
+        step will leave (``feeder.advance``) beside the loss and score of
+        the steps before it; a guard of the parameters that no factory
+        built from the counters counts as firing (``Trigger.may_fire``)."""
+        counters, _ = advance(state, state["seen"], bsz, epoch_size)
+        after = {**state, **counters}
+        return bool(
+            self._preempt_flag
+            or self.end_when.reads_result or self.end_when.peek(after)
+            or any(t is not None and t.may_fire(after) for t in (
+                self.validation_trigger, self.checkpoint_trigger))
+            or (self.train_summary is not None
+                and self.train_summary.may_record("Parameters", after))
+            or self._trace_turns(after["neval"]))
+
     def _optimize_once(self, resume: bool = False):
         import jax
 
@@ -862,79 +922,115 @@ class Optimizer:
                         f"differently sized) than the one that wrote the "
                         f"checkpoint") from None
             seen_this_epoch = state.get("seen", 0)
+        state["seen"] = seen_this_epoch
         feeder.start(seen_this_epoch)
         epoch_start = time.time()
-        while not self.end_when(state):
-            if self._preempt_flag:
-                self._checkpoint(
-                    state, self._ckpt_params_to_host(params), model_state,
-                    self._ckpt_opt_state_to_host(opt_state),
-                )
-                if self._async_ckptr is not None:
-                    self._async_ckptr.wait_until_finished()
-                    self._flush_async_marker()
-                raise TrainingPreempted(
-                    f"evicted at iteration {state['neval']}; checkpoint "
-                    f"written to {self.checkpoint_path or '(no path set)'}")
-            state["epoch_finished"] = False
-            if self._profile is not None:
-                if state["neval"] == self._profile["start"]:
-                    # the loop's spans name its phases; Python frames on
-                    # the same line would only rename them with every
-                    # edit (file:line), at several times the trace's
-                    # size and cost
-                    options = jax.profiler.ProfileOptions()
-                    options.python_tracer_level = 0
-                    jax.profiler.start_trace(self._profile["dir"],
-                                             profiler_options=options)
-                    self._profile["active"] = True
-                elif state["neval"] == self._profile["stop"] and \
-                        self._profile.get("active"):
-                    jax.profiler.stop_trace()
-                    self._profile["active"] = False
-            # one iteration = one step of the profiler's step analysis;
-            # its phases below are series and profile events at once
-            # (Metrics.span). "computing time" is the whole of it: the wait
-            # for the batch, the dispatch, float(loss).
-            with self.metrics.span("train.iteration",
-                                   step_num=state["neval"]):
-                # the input path's share of an iteration AS THE LOOP SEES
-                # IT: the wait for the feeder, ~0 when the batch was ready
-                # (StopIteration passes through, leaving no sample)
-                t0 = time.perf_counter()
-                try:
-                    with self.metrics.span("train.fetch", "data fetch time"):
-                        inp, tgt, bsz = feeder.get()
-                except StopIteration:
-                    logger.warning(
-                        "data iterator exhausted before end_when fired; "
-                        "stopping. (Possible causes: the iterator yields "
-                        "fewer batches than dataset.size() implies, or a "
-                        "directly-constructed stateful Trigger without a "
-                        "side-effect-free peek_fn.)")
-                    break
-                # the LAUNCH of the step: host time, the program's device
-                # time is the trace's jit_step
-                with self.metrics.span("train.dispatch", "dispatch time"):
-                    rng = jax.random.fold_in(base_key, state["neval"])
-                    params, opt_state, model_state, loss = step(
-                        params, opt_state, model_state, rng, inp, tgt,
+
+        # The loop keeps ONE step in flight behind the host. A pass makes
+        # at most one launch and then at most one read: with step k
+        # launched and not read it launches step k+1 on step k's outputs
+        # (not ready yet; params and opt_state are donated and rebound, so
+        # this is program order only) and THEN blocks on float(loss_k) and
+        # books step k: the launch and the read-back go under the step on
+        # the device. Where the host needs step k first (_host_waits_for),
+        # the pass reads it with nothing launched, and the next pass
+        # launches and reads its own step, the synchronous order.
+        in_flight = None     # (loss, bsz) of the step launched, not read
+        stop = self.end_when(state)
+        while in_flight is not None or not stop:
+            reading = in_flight
+            if reading is None:
+                # nothing in flight: `params` are those `state` describes
+                if self._preempt_flag:
+                    self._checkpoint(
+                        state, self._ckpt_params_to_host(params), model_state,
+                        self._ckpt_opt_state_to_host(opt_state),
                     )
-                # nothing for the host to do until float(loss): the feeder
-                # builds the next batch now, under the step, not beside the
-                # launch
-                feeder.launched()
+                    if self._async_ckptr is not None:
+                        self._async_ckptr.wait_until_finished()
+                        self._flush_async_marker()
+                    raise TrainingPreempted(
+                        f"evicted at iteration {state['neval']}; checkpoint "
+                        f"written to {self.checkpoint_path or '(no path set)'}")
+                if self._trace_turns(state["neval"]):
+                    self._turn_trace(state["neval"])
+            launching = reading is None or not (
+                stop or self._host_waits_for(state, reading[1], epoch_size))
+            # the step this pass launches, else the one it reads
+            neval = state["neval"] + (launching and reading is not None)
+            fetch_error = None
+            # one pass = one step of the profiler's step analysis; its
+            # phases below are series and profile events at once
+            # (Metrics.span). "computing time" is the whole of a pass that
+            # reads a step: the wait for the batch, the dispatch,
+            # float(loss); pipelined, the dispatch is the NEXT step's.
+            with self.metrics.span("train.iteration", step_num=neval):
+                t0 = time.perf_counter()
+                in_flight = None
+                if launching:
+                    # the input path's share of an iteration AS THE LOOP
+                    # SEES IT: the wait for the feeder, ~0 when the batch
+                    # was ready (StopIteration passes through, leaving no
+                    # sample)
+                    try:
+                        with self.metrics.span("train.fetch",
+                                               "data fetch time"):
+                            inp, tgt, bsz = feeder.get()
+                    except Exception as e:
+                        if reading is not None:
+                            # the step in flight is read and booked
+                            # first, as it was before this fetch on the
+                            # synchronous order; the pass then ends on
+                            # what the input path raised
+                            fetch_error = e
+                        elif isinstance(e, StopIteration):
+                            logger.warning(
+                                "data iterator exhausted before end_when "
+                                "fired; stopping. (Possible causes: the "
+                                "iterator yields fewer batches than "
+                                "dataset.size() implies, or a "
+                                "directly-constructed stateful Trigger "
+                                "without a side-effect-free peek_fn.)")
+                            break
+                        else:
+                            raise
+                    else:
+                        # the LAUNCH of the step: host time, the program's
+                        # device time is the trace's jit_step. What it
+                        # reads of the host is its own step's: rng of ITS
+                        # neval
+                        with self.metrics.span("train.dispatch",
+                                               "dispatch time"):
+                            rng = jax.random.fold_in(base_key, neval)
+                            params, opt_state, model_state, loss = step(
+                                params, opt_state, model_state, rng, inp, tgt,
+                            )
+                        # nothing for the host to do until float(loss): the
+                        # feeder builds the next batch now, under the step,
+                        # not beside the launch
+                        feeder.launched()
+                        self.metrics.add("launched ahead",
+                                         float(reading is not None))
+                        if reading is not None or not self._host_waits_for(
+                                state, bsz, epoch_size):
+                            in_flight = (loss, bsz)   # read a launch later
+                        else:
+                            reading = (loss, bsz)     # the synchronous order
+                if reading is None:
+                    continue
+                loss, bsz = reading
                 # the loop's one sync: the host BLOCKED on the step
                 with self.metrics.span("train.loss_sync", "loss sync time"):
                     loss_f = float(loss)
                 dt = time.perf_counter() - t0
                 self.metrics.add("computing time", dt)
                 self.metrics.add("records/second", bsz / max(dt, 1e-9))
+                counters, _ = advance(state, state["seen"], bsz, epoch_size)
                 state["loss"] = loss_f
-                state["neval"] += 1
+                state["epoch_finished"] = False
+                state["neval"] = counters["neval"]
                 self.optim_method.state["neval"] = state["neval"]
-                seen_this_epoch += bsz
-                state["seen"] = seen_this_epoch
+                state["seen"] += bsz
 
                 if self.train_summary is not None:
                     self.train_summary.add_scalar("Loss", loss_f, state["neval"] - 1)
@@ -952,6 +1048,7 @@ class Optimizer:
                             state["neval"] - 1,
                         )
                     if self.train_summary.should_record("Parameters", state):
+                        assert in_flight is None, "params donated to a step"
                         host = self._ckpt_params_to_host(params)
                         for path, leaf in jax.tree_util.tree_flatten_with_path(
                                 host)[0]:
@@ -960,19 +1057,21 @@ class Optimizer:
                             self.train_summary.add_histogram(
                                 tag, np.asarray(leaf), state["neval"] - 1)
 
-                if seen_this_epoch >= epoch_size:
-                    state["epoch_finished"] = True
+                if counters["epoch_finished"]:
                     logger.info(
                         "epoch %d done: %d records in %.1fs, last loss %.4f",
-                        state["epoch"], seen_this_epoch, time.time() - epoch_start, loss_f,
+                        state["epoch"], state["seen"], time.time() - epoch_start, loss_f,
                     )
-                    state["epoch"] += 1
+                    state.update(counters)
                     self.optim_method.state["epoch"] = state["epoch"]
-                    seen_this_epoch = 0
                     state["seen"] = 0
                     epoch_start = time.time()
 
+                # validation and a checkpoint read the parameters as they
+                # stand after the step `state` describes: _host_waits_for
+                # saw to it that none is in flight beyond it
                 if self.validation_trigger is not None and self.validation_trigger(state):
+                    assert in_flight is None, "params donated to a step"
                     # device-layout params: DistriOptimizer overrides
                     # _eval_forward to evaluate SHARDED over the mesh instead of
                     # gathering to host and wasting N-1 chips (SURVEY §3.3)
@@ -980,14 +1079,21 @@ class Optimizer:
                     if score is not None:
                         state["score"] = score
                 if self.checkpoint_trigger is not None and self.checkpoint_trigger(state):
+                    assert in_flight is None, "params donated to a step"
                     self._checkpoint(
                         state, self._ckpt_params_to_host(params), model_state,
                         self._ckpt_opt_state_to_host(opt_state),
                     )
+                # every booked iteration, in order. A step launched ahead
+                # of a "stop" the peek did not announce is booked and shown
+                # like any other; the loop ends whatever is said of it
+                stop = self.end_when(state) or stop
+                if fetch_error is not None and \
+                        not isinstance(fetch_error, StopIteration):
+                    raise fetch_error
 
-        if self._profile is not None and self._profile.get("active"):
-            jax.profiler.stop_trace()  # loop ended inside the trace window
-            self._profile["active"] = False
+        if self._profile is not None and "stop" in self._profile:
+            self._turn_trace(state["neval"])  # the loop ended inside it
         self._writeback(params, opt_state, model_state)
         return self.model
 

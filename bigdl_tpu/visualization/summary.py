@@ -57,6 +57,12 @@ class TrainSummary(Summary):
         trig = self._triggers.get(name)
         return trig is not None and trig(state)
 
+    def may_record(self, name: str, state) -> bool:
+        """``should_record`` asked ahead, side-effect-free, of a state
+        whose step has not been read yet (``Trigger.may_fire``)."""
+        trig = self._triggers.get(name)
+        return trig is not None and trig.may_fire(state)
+
 
 class ValidationSummary(Summary):
     def __init__(self, log_dir: str, app_name: str) -> None:

@@ -461,11 +461,11 @@ def test_host_state_is_sampled_at_dispatch_for_the_rows_dispatched():
 
 # -- the training loop --------------------------------------------------------
 
-def test_three_iterations_leave_equal_counts_in_every_phase(recorder):
+def _three_iterations(end_when):
     from bigdl_tpu.dataset import DataSet
     from bigdl_tpu.dataset.sample import Sample
     from bigdl_tpu.nn import ClassNLLCriterion, Linear, LogSoftMax, Sequential
-    from bigdl_tpu.optim import SGD, Optimizer, Trigger
+    from bigdl_tpu.optim import SGD, Optimizer
 
     rng = np.random.RandomState(1)
     samples = [Sample(rng.rand(8).astype(np.float32), np.int32(i % 3 + 1))
@@ -474,28 +474,57 @@ def test_three_iterations_leave_equal_counts_in_every_phase(recorder):
     opt = Optimizer(model=model, dataset=DataSet.array(samples),
                     criterion=ClassNLLCriterion(), batch_size=4)
     opt.set_optim_method(SGD(learning_rate=0.1))
-    opt.set_end_when(Trigger.max_iteration(3))
+    opt.set_end_when(end_when)
     opt.optimize()
+    return opt
+
+
+@pytest.mark.parametrize("ahead", [False, True],
+                         ids=["synchronous", "launched_ahead"])
+def test_three_iterations_leave_equal_counts_in_every_phase(recorder, ahead):
+    """One fetch, one dispatch, one sync and one sample of every series a
+    STEP, whichever order the loop runs them in. An end trigger built
+    without a peek reads results as far as the loop can tell, so every
+    step is read before the next is launched: a ``train.iteration`` is
+    fetch, dispatch, sync of ONE step. ``max_iteration`` is predictable
+    from the counters, so the loop keeps a step in flight: the first
+    pass launches step 1, the next two launch a step and read the one
+    before, the last reads step 3."""
+    from bigdl_tpu.optim import Trigger
+
+    opt = _three_iterations(Trigger.max_iteration(3) if ahead
+                            else Trigger(lambda s: s["neval"] > 3))
 
     counts = {n: opt.metrics.get(n)[1] for n in (
         "computing time", "data fetch time", "dispatch time",
-        "loss sync time")}
+        "loss sync time", "launched ahead")}
     assert set(counts.values()) == {3}, counts
-    # an iteration's wall holds its dispatch and its sync
-    for wall, disp, sync in zip(*(opt.metrics.values(n) for n in (
-            "computing time", "dispatch time", "loss sync time"))):
-        assert wall >= disp + sync > 0.0
-    its = [(ids, d) for n, ids, d in recorder.events
+    assert opt.metrics.values("launched ahead") == (
+        [0.0, 1.0, 1.0] if ahead else [0.0, 0.0, 0.0])
+    # the wall of a pass that reads a step holds its sync and the dispatch
+    # made in it: the step's own, or launched ahead the NEXT step's
+    wall, disp, sync = (opt.metrics.values(n) for n in (
+        "computing time", "dispatch time", "loss sync time"))
+    for i in range(3):
+        inside = sync[i] + (disp[i] if not ahead else
+                            disp[i + 1] if i < 2 else 0.0)
+        assert wall[i] >= inside > 0.0
+    its = [ids["step_num"] for n, ids, d in recorder.events
            if n == "train.iteration"]
-    assert [ids["step_num"] for ids, _ in its] == [1, 2, 3]
+    # a pass is named for the step it launches, else for the one it reads
+    assert its == ([1, 2, 3, 3] if ahead else [1, 2, 3])
     inside = {n for n, _, d in recorder.events if d == 1}
     assert inside == {"train.fetch", "train.dispatch", "train.loss_sync"}
+    phases = [n.split(".")[1] for n, _, d in recorder.events if d == 1]
+    assert phases == (
+        ["fetch", "dispatch", "fetch", "dispatch", "loss_sync",
+         "fetch", "dispatch", "loss_sync", "loss_sync"] if ahead
+        else ["fetch", "dispatch", "loss_sync"] * 3)
     # the feeder builds on a thread of its own and opens no span there:
-    # train.fetch, the loop's wait for it, is the one fetch span, once an
-    # iteration; the feeder's two series have a sample a batch handed over
-    # (max_iteration's peek lets it draw exactly the three)
-    assert recorder.names().count("train.fetch") == 3
-    assert len(recorder.events) == 3 * 4
+    # train.fetch, the loop's wait for it, is the one fetch span, once a
+    # step; the feeder's two series have a sample a batch handed over
+    # (both peeks let it draw exactly the three)
+    assert len(recorder.events) == len(its) + 3 * 3
     assert opt.metrics.get("batch build time")[1] == 3
     assert opt.metrics.get("input ready")[1] == 3
     assert set(opt.metrics.values("input ready")) <= {0.0, 1.0}
