@@ -21,9 +21,15 @@ serving cells bring (``ATTENTION_SHAPES``), by how full the pool is
 rows decode (``--active``). CPU runs execute the kernel in interpret
 mode and say so in the row; run on TPU for real numbers.
 
+``--ssm`` benches the hybrid decode step's scan-state update
+(``measure_ssm``): the Pallas kernel over the decoding rows against the
+jnp reference over every row, at Falcon-H1's state (32 slots x 32 heads
+x 128 x 256 float32), by how many rows decode (``--active``).
+
     python -m benchmarks.decode_bench
     ... --models 137m --batches 1 8 --variants bf16 int8   # subset
     ... --attention --shapes trinity-ring --fills 0.15 1.0 --active 0.33
+    ... --ssm --active 0.5 1.0
 """
 
 from __future__ import annotations
@@ -297,6 +303,76 @@ def measure_attention(shape: str, fill: float, active_share: float,
     }
 
 
+#: Falcon-H1's scan state per layer (BENCHMARK.json's configuration):
+#: slots, scan groups, heads a group, head width, state width
+SSM_SHAPE = dict(n=32, G=2, hg=16, d=128, N=256)
+
+
+def measure_ssm(active_share: float, reps: int = 3) -> dict:
+    """One scan-state decode step at ``SSM_SHAPE``: the Pallas kernel
+    (``ops/ssm_decode.py``) over the ``active_share`` of rows that
+    decode, against the jnp reference over every row. Each timing is
+    one program of ``CALLS`` calls whose state is the loop's carry
+    (updated in place, as the decode step's donated leaf is); the
+    kernel's includes the small operations that prepare its operands.
+    ``state_gb_per_s``: the decoding rows' state, read once and written
+    once, over that time. On a CPU host the kernel runs in Pallas
+    INTERPRET mode and the row says so."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from bigdl_tpu.ops.ssm_decode import ssm_decode
+    from bigdl_tpu.utils.compat import auto_interpret
+
+    CALLS = 16
+    n, G, hg, d, N = (SSM_SHAPE[key] for key in ("n", "G", "hg", "d", "N"))
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.standard_normal((n, G, hg, d)), jnp.bfloat16)
+    Bm, Cm = (jnp.asarray(rng.standard_normal((n, G, N)), jnp.bfloat16)
+              for _ in range(2))
+    dt = jnp.asarray(rng.uniform(0.01, 0.1, (n, G, hg)), jnp.float32)
+    A = -jnp.asarray(np.arange(1, G * hg + 1).reshape(G, hg), jnp.float32)
+    S = jnp.asarray(rng.standard_normal((n, G * hg, d, N)), jnp.float32)
+    stride = max(1, round(1 / active_share))
+    active = jnp.asarray(np.arange(n) % stride == 0)
+
+    def reference(S):
+        return ssm_decode(x, Bm, Cm, dt, A, S, active, impl="reference")
+
+    def kernel(S):
+        return ssm_decode(x, Bm, Cm, dt, A, S, active, impl="kernel")
+
+    def timed(fn) -> float:
+        many = jax.jit(lambda S: lax.fori_loop(
+            0, CALLS, lambda _, s: fn(s)[1], S), donate_argnums=(0,))
+        state = jax.block_until_ready(many(S + 0))  # compile + warm
+        best = float("inf")
+        for _ in range(reps):
+            t0 = time.perf_counter()
+            state = jax.block_until_ready(many(state))
+            best = min(best, time.perf_counter() - t0)
+        return best / CALLS
+
+    y_want, s_want = jax.jit(reference)(S)
+    y_got, s_got = jax.jit(kernel)(S)
+    ref_s = timed(reference)
+    kern_s = timed(kernel)
+    rows = int(np.asarray(active).sum())
+    state_bytes = 2 * rows * G * hg * d * N * 4
+    return {
+        "metric": "ssm_decode_call_ms", **SSM_SHAPE,
+        "rows_decoding": rows,
+        "interpret": bool(auto_interpret()),
+        "max_abs_diff_y": float(jnp.abs(y_got - y_want).max()),
+        "max_abs_diff_state": float(jnp.abs(s_got - s_want).max()),
+        "reference_ms": round(1e3 * ref_s, 4),
+        "kernel_ms": round(1e3 * kern_s, 4),
+        "kernel_vs_reference": round(ref_s / max(kern_s, 1e-9), 3),
+        "state_gb_per_s": round(1e-9 * state_bytes / max(kern_s, 1e-9), 1),
+    }
+
+
 def main(argv=None) -> None:
     p = argparse.ArgumentParser()
     p.add_argument("--models", nargs="+", default=["137m", "371m"],
@@ -317,6 +393,9 @@ def main(argv=None) -> None:
     p.add_argument("--blocks", nargs="+", type=int, default=[None],
                    help="KV tile lengths to try (default: the kernel's "
                         "own choice)")
+    p.add_argument("--ssm", action="store_true",
+                   help="bench the hybrid decode step's scan-state update "
+                        "(Pallas kernel vs the jnp reference)")
     args = p.parse_args(argv)
 
     from bigdl_tpu.utils.compile_cache import enable_compile_cache
@@ -336,6 +415,10 @@ def main(argv=None) -> None:
                             print(json.dumps(measure_attention(
                                 shape, fill, share, v, block, args.reps)),
                                 flush=True)
+        return
+    if args.ssm:
+        for share in args.active:
+            print(json.dumps(measure_ssm(share, args.reps)), flush=True)
         return
 
     rows = []

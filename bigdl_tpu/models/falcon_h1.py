@@ -215,20 +215,6 @@ def _scan_chunked(cfg, x, Bm, Cm, dt, A):
     return y[:, :T], S
 
 
-def _scan_step(x, Bm, Cm, dt, A, S):
-    """One step of the recurrence itself: ``S' = e^{dt A} S + dt x (x)
-    B``, ``y = S' C``. Shapes as :func:`_scan_chunked` with T = 1."""
-    import jax.numpy as jnp
-
-    x0 = x[:, 0].astype(jnp.float32)                           # B,G,hg,d
-    B0 = Bm[:, 0].astype(jnp.float32)[:, :, None, None]        # B,G,1,1,N
-    C0 = Cm[:, 0].astype(jnp.float32)[:, :, None, None]
-    dt0 = dt[:, 0]                                             # B,G,hg
-    S = S * jnp.exp(dt0 * A)[..., None, None] \
-        + (dt0[..., None] * x0)[..., None] * B0
-    return jnp.sum(S * C0, axis=-1)[:, None], S
-
-
 def _mixer(cfg, p, h, valid, cache, decode):
     """The Mamba-2 mixer of the block's input ``h`` (B, T, H). From a
     cache it continues (``decode``: the convolution's window and the
@@ -239,6 +225,8 @@ def _mixer(cfg, p, h, valid, cache, decode):
     inputs."""
     import jax
     import jax.numpy as jnp
+
+    from bigdl_tpu.ops.ssm_decode import ssm_decode
 
     B, T, _ = h.shape
     G, nh, d, N = cfg.mamba_n_groups, cfg.mamba_n_heads, cfg.mamba_d_head, \
@@ -269,8 +257,11 @@ def _mixer(cfg, p, h, valid, cache, decode):
     dt = jnp.where(valid[..., None], dt, 0.0).reshape(B, T, G, hg)
     A = -jnp.exp(p["A_log"].astype(jnp.float32)).reshape(G, hg)
     if decode:
-        S_in = cache["ssm"].reshape(B, G, hg, d, N)
-        y, S = _scan_step(x, Bm, Cm, dt, A, S_in)
+        # the decoding rows' state, read once and written once in place
+        # (on a TPU; ops/ssm_decode.py); the others keep theirs bitwise
+        y, S = ssm_decode(x[:, 0], Bm[:, 0], Cm[:, 0], dt[:, 0], A,
+                          cache["ssm"], valid[:, 0])
+        y = y[:, None]
     else:
         y, S = _scan_chunked(cfg, x, Bm, Cm, dt, A)
     y = y + p["D"].astype(jnp.float32).reshape(G, hg)[..., None] \
@@ -284,12 +275,8 @@ def _mixer(cfg, p, h, valid, cache, decode):
     out = y.astype(h.dtype) @ p["out_proj"]
     if cache is None:
         return out, None
-    # a row with no real token keeps what it had, bitwise
     n_real = jnp.sum(valid, axis=1)
-    on = n_real > 0
     S = S.reshape(B, nh, d, N)
-    if decode:
-        S = jnp.where(on[:, None, None, None], S, cache["ssm"])
     # the last K-1 real inputs: ext[n_real : n_real + K-1] (a row of
     # length 0 reads its old window back)
     idx = n_real[:, None] + jnp.arange(K - 1)[None, :]

@@ -234,6 +234,15 @@ def test_recurrent_family_decode_step_copies_neither_cache_nor_state(
     assert "pooled_decode_attention" in text
     assert not _pool_sized_copies(
         text, {math.prod(carry[key].shape) for key in ("k0", "ssm0")})
+    # the state is updated by the kernel, over the decoding rows, in
+    # place: no XLA fusion makes a whole leaf's float32 array
+    assert text.count("ssm_decode_step") >= config["num_hidden_layers"]
+    leaf = "f32[%d,%d,%d,%d]" % (N_SLOTS, config["mamba_n_heads"],
+                                  config["mamba_d_head"],
+                                  config["mamba_d_state"])
+    made = [line.split(" fusion(")[0] for line in text.splitlines()
+            if " fusion(" in line]
+    assert not [out for out in made if leaf in out], leaf
     carry_in = compiled.input_formats[0][3]
     carry_out = compiled.output_formats[2]
     for key in ("k0", "v1", "ssm0", "conv1"):

@@ -122,6 +122,20 @@ def test_parameters_are_created_in_the_stated_dtype():
         assert mine is theirs
 
 
+def _scan_step(x, Bm, Cm, dt, A, S):
+    """One step of the recurrence itself, over every row: ``S' = e^{dt
+    A} S + dt x (x) B``, ``y = S' C``. Shapes as
+    :func:`falcon_h1._scan_chunked` with T = 1. Written apart from
+    ``ops/ssm_decode.py``, so that both of its paths are held to it."""
+    x0 = x[:, 0].astype(jnp.float32)                           # B,G,hg,d
+    B0 = Bm[:, 0].astype(jnp.float32)[:, :, None, None]        # B,G,1,1,N
+    C0 = Cm[:, 0].astype(jnp.float32)[:, :, None, None]
+    dt0 = dt[:, 0]                                             # B,G,hg
+    S = S * jnp.exp(dt0 * A)[..., None, None] \
+        + (dt0[..., None] * x0)[..., None] * B0
+    return jnp.sum(S * C0, axis=-1)[:, None], S
+
+
 @pytest.mark.parametrize("length", [1, 7, 8, 13, 21])
 def test_chunked_scan_matches_the_recurrence(length):
     """Chunks of 8 against the token-by-token recurrence, at lengths that
@@ -139,7 +153,7 @@ def test_chunked_scan_matches_the_recurrence(length):
     S_ref = jnp.zeros((B, G, hg, d, N), jnp.float32)
     ys = []
     for t in range(length):
-        y_t, S_ref = falcon_h1._scan_step(
+        y_t, S_ref = _scan_step(
             x[:, t:t + 1], Bm[:, t:t + 1], Cm[:, t:t + 1], dt[:, t:t + 1],
             A, S_ref)
         ys.append(y_t)
@@ -149,6 +163,80 @@ def test_chunked_scan_matches_the_recurrence(length):
         1e-5 * np.abs(np.asarray(y_ref)).max()
     assert np.abs(np.asarray(S - S_ref)).max() <= \
         1e-5 * np.abs(np.asarray(S_ref)).max()
+
+
+# ------------------------------------------------- the decode step's state
+
+
+#: which of 8 slots decode
+MASKS = {"all": [1] * 8, "none": [0] * 8, "one": [0, 0, 1, 0, 0, 0, 0, 0],
+         "last": [0] * 7 + [1], "scattered": [1, 0, 0, 1, 1, 0, 1, 0]}
+
+
+def _state_step_inputs(seed, G=2, hg=2, d=128, N=256, n=8):
+    """One decode step's scan inputs for ``n`` slots at the published
+    head and state widths (128 x 256), bfloat16 projections as the
+    served program has them, a float32 state with negative zeros in it
+    (adding 0.0 would flip their sign)."""
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.standard_normal((n, G, hg, d)), jnp.bfloat16)
+    Bm, Cm = (jnp.asarray(rng.standard_normal((n, G, N)), jnp.bfloat16)
+              for _ in range(2))
+    dt = jnp.asarray(rng.uniform(0.05, 1.5, (n, G, hg)), jnp.float32)
+    A = -jnp.asarray(rng.uniform(0.5, 4.0, (G, hg)), jnp.float32)
+    S = rng.standard_normal((n, G * hg, d, N)).astype(np.float32)
+    S[..., 0] = -0.0
+    return x, Bm, Cm, dt, A, jnp.asarray(S)
+
+
+@pytest.mark.parametrize("groups,heads", [(2, 2), (1, 4)],
+                         ids=["2x2-heads", "1x4-heads"])
+@pytest.mark.parametrize("impl", ["kernel", "reference"])
+@pytest.mark.parametrize("mask", sorted(MASKS))
+def test_state_decode_step_matches_the_recurrence(mask, impl, groups, heads):
+    """``ops/ssm_decode.py`` (the kernel in interpret mode, and the jnp
+    path the CPU serves with) against the recurrence over every row, in
+    two scan groups and in one: a decoding row's ``y`` and new state to
+    float32 round-off, every other row's state bitwise as it was and its
+    ``y`` zeros."""
+    from bigdl_tpu.ops.ssm_decode import ssm_decode
+
+    x, Bm, Cm, dt, A, S = _state_step_inputs(seed=len(mask), G=groups,
+                                             hg=heads)
+    n, G, hg, d = x.shape
+    active = np.asarray(MASKS[mask], bool)
+    kw = {} if impl == "reference" else dict(interpret=True)
+    y, S_new = jax.jit(lambda *a: ssm_decode(*a, impl=impl, **kw))(
+        x, Bm, Cm, dt, A, S, jnp.asarray(active))
+    y_ref, S_ref = _scan_step(x[:, None], Bm[:, None], Cm[:, None],
+                              dt[:, None], A, S.reshape(n, G, hg, d, -1))
+    y, S_new, S_before = (np.asarray(a) for a in (y, S_new, S))
+    y_ref = np.asarray(y_ref[:, 0])
+    S_ref = np.asarray(S_ref).reshape(S_before.shape)
+    assert y.shape == y_ref.shape and S_new.shape == S_before.shape
+    for r in range(n):
+        if active[r]:
+            # float32: one rounding a term, sums of 256 terms in y
+            assert np.abs(S_new[r] - S_ref[r]).max() <= \
+                1e-6 * np.abs(S_ref[r]).max()
+            assert np.abs(y[r] - y_ref[r]).max() <= \
+                1e-5 * np.abs(y_ref[r]).max()
+        else:
+            assert S_new[r].tobytes() == S_before[r].tobytes()
+            assert not y[r].any() and not np.signbit(y[r]).any()
+
+
+def test_state_decode_step_refuses_a_state_it_cannot_update():
+    from bigdl_tpu.ops.ssm_decode import ssm_decode
+
+    x, Bm, Cm, dt, A, S = _state_step_inputs(seed=0, d=8, N=8, n=2)
+    active = jnp.asarray([True, False])
+    for impl in ("kernel", "reference"):
+        with pytest.raises(ValueError, match="float32"):
+            ssm_decode(x, Bm, Cm, dt, A, S.astype(jnp.bfloat16), active,
+                       impl=impl)
+        with pytest.raises(ValueError, match="^S "):
+            ssm_decode(x, Bm, Cm, dt, A, S[:, :2], active, impl=impl)
 
 
 # ----------------------------------------------------------- padded prefill
@@ -240,6 +328,26 @@ def test_prefill_then_decode_reproduces_the_reference_logits(lm):
     assert got.shape == want.shape
     assert np.abs(got - want).max() <= F32_OF_STD * want.std()
     # the ballast row never moved
+    for key, leaf in carry.items():
+        if leaf_kind(key) in ("kv", "state", "pos"):
+            assert not np.asarray(leaf[1]).any(), key
+
+
+def test_decode_through_the_state_kernel_reproduces_the_reference_logits(
+        lm, monkeypatch):
+    """The decode step with its state update in the Pallas kernel (the
+    path a TPU takes; interpret mode here) serves the reference's logits
+    to float32 round-off, and the ballast row never moves."""
+    import functools
+
+    from bigdl_tpu.ops import ssm_decode as module
+
+    monkeypatch.setattr(module, "ssm_decode", functools.partial(
+        module.ssm_decode, impl="kernel", interpret=True))
+    seq = _tokens(4, 30)
+    got, carry = _teacher_forced_decode(lm, seq[:11], seq[11:-1])
+    want = _ref_logits(lm, seq[:-1])[10:]
+    assert np.abs(got - want).max() <= F32_OF_STD * want.std()
     for key, leaf in carry.items():
         if leaf_kind(key) in ("kv", "state", "pos"):
             assert not np.asarray(leaf[1]).any(), key
